@@ -45,17 +45,15 @@ VERIFY = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp
-    from jax.sharding import PartitionSpec as P
-    from repro import compat
+    from jax.sharding import AxisType, PartitionSpec as P
     from repro.core import decentralized as dec
     from repro.roofline import parse_collectives
 
-    mesh = compat.make_mesh((8,), ("data",),
-                            axis_types=compat.auto_axis_types(1))
+    mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
     x = jnp.zeros((8, 1024), jnp.float32)   # 4 KiB payload per node
     for s in %r:
         spec = dec.parse_sync(s)
-        f = jax.jit(compat.shard_map(
+        f = jax.jit(jax.shard_map(
             lambda v: dec.sync_tree_mesh(v, spec, ("data",), (8,)),
             mesh=mesh, in_specs=P("data"), out_specs=P("data")))
         hlo = f.lower(x).compile().as_text()
@@ -167,8 +165,9 @@ def main(argv=None):
         arch_table(args.chips)
 
     if args.verify_hlo:
-        env = dict(os.environ)
-        env["PYTHONPATH"] = "src"
+        # the child wants 8 virtual CPU devices, and it must never reach
+        # for the accelerator this process already holds
+        env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
         r = subprocess.run([sys.executable, "-c", VERIFY], env=env,
                            capture_output=True, text=True, timeout=600)
         print("\n" + r.stdout + r.stderr[-500:])

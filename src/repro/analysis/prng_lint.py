@@ -29,6 +29,9 @@ import functools
 import math
 from typing import Any
 
+import jax
+from jax.extend import core as jcore
+
 # primitives that CONSUME a key operand (derivation or sampling); a key
 # hitting two of these is used twice
 KEY_CONSUMERS = frozenset({
@@ -53,7 +56,6 @@ class KeyFinding:
 
 
 def _is_key_var(v) -> bool:
-    import jax
     aval = getattr(v, "aval", None)
     dtype = getattr(aval, "dtype", None)
     if dtype is None:
@@ -64,20 +66,7 @@ def _is_key_var(v) -> bool:
         return False
 
 
-def _core():
-    # jaxpr datatypes moved to jax.extend.core in newer jax; fall back for
-    # the versions that predate it
-    try:
-        import jax.extend.core as jcore
-        jcore.Literal, jcore.Jaxpr, jcore.ClosedJaxpr
-        return jcore
-    except (ImportError, AttributeError):
-        import jax.core as jcore
-        return jcore
-
-
 def _sub_jaxprs(params: dict) -> list[Any]:
-    jcore = _core()
     found = []
     kinds = (jcore.Jaxpr, jcore.ClosedJaxpr)
     for val in params.values():
@@ -108,13 +97,11 @@ class _Walker:
         return v
 
     def _consume(self, v, prim: str):
-        jcore = _core()
         if isinstance(v, jcore.Literal):
             return
         self.consumers.setdefault(self.root(v), []).append(prim)
 
     def walk(self, jaxpr):
-        jcore = _core()
         for eqn in jaxpr.eqns:
             prim = eqn.primitive.name
             subs = _sub_jaxprs(eqn.params)
@@ -194,7 +181,6 @@ def lint_fn(fn, *args, **kwargs) -> list[KeyFinding]:
 
     Keyword arguments are bound via ``functools.partial`` before tracing
     (so static/config kwargs work unchanged)."""
-    import jax
     if kwargs:
         fn = functools.partial(fn, **kwargs)
     return lint_jaxpr(jax.make_jaxpr(fn)(*args))

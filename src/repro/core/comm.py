@@ -51,9 +51,8 @@ from typing import Protocol, runtime_checkable
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
-from repro import compat
 from repro.core import gossip
 from repro.core.graph import Graph
 
@@ -285,8 +284,8 @@ class PallasSimComm:
 def make_grid_mesh(n_node_devices: int, n_vocab_devices: int,
                    axis_names: tuple[str, str] = ("data", "vocab")):
     """A 2-D node x vocab device grid for vocab-sharded MeshComm gossip."""
-    return compat.make_mesh((n_node_devices, n_vocab_devices), axis_names,
-                            axis_types=compat.auto_axis_types(2))
+    return jax.make_mesh((n_node_devices, n_vocab_devices), axis_names,
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def _route_matching(partners: np.ndarray, n_dev: int):
@@ -377,8 +376,8 @@ class MeshComm:
                  vocab_axis: str | None = None):
         if mesh is None:
             n = len(jax.devices())
-            mesh = compat.make_mesh((n,), (axis_name,),
-                                    axis_types=compat.auto_axis_types(1))
+            mesh = jax.make_mesh((n,), (axis_name,),
+                                 axis_types=(AxisType.Auto,))
         self.mesh = mesh
         self.axis_name = axis_name
         self.vocab_axis = vocab_axis
@@ -416,7 +415,7 @@ class MeshComm:
                 return jnp.where(keep, mixed, stats)
 
             stats_spec = self._stats_spec(ndim)
-            fn = jax.jit(compat.shard_map(
+            fn = jax.jit(jax.shard_map(
                 local_mix, mesh=self.mesh,
                 in_specs=(stats_spec, node, node), out_specs=stats_spec))
             self._local_fns[ndim] = fn
@@ -436,7 +435,7 @@ class MeshComm:
                 return jnp.where(keep, mixed, stats)
 
             stats_spec = self._stats_spec(ndim)
-            fn = jax.jit(compat.shard_map(
+            fn = jax.jit(jax.shard_map(
                 exchange, mesh=self.mesh,
                 in_specs=(stats_spec, node, node), out_specs=stats_spec))
             self._pass_fns[(perm, ndim)] = fn

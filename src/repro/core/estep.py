@@ -21,8 +21,8 @@ all now share — the compute-side twin of :mod:`repro.core.comm`:
   :class:`PallasEStep` (the lda_gibbs kernel; ``interpret=None``
   auto-detects, compiled on TPU), selected via
   ``DeledaConfig.estep_backend`` (the old ``use_pallas`` bool is a
-  deprecated alias). ``rao_blackwell=False`` falls back to the dense
-  backend with a warning — the kernel is Rao-Blackwellized only.
+  deprecated alias). The kernel is Rao-Blackwellized only, so it refuses
+  ``rao_blackwell=False``.
 
 * the **fused batch path** — :func:`estep_batch` gathers all awake nodes'
   minibatches into ONE ``[A*B, L]`` sweep call (one Pallas grid over
@@ -56,7 +56,6 @@ all now share — the compute-side twin of :mod:`repro.core.comm`:
 
 from __future__ import annotations
 
-import warnings
 from typing import NamedTuple
 
 import jax
@@ -65,8 +64,8 @@ import jax.numpy as jnp
 from repro.core.lda import LDAConfig
 
 __all__ = [
-    "GibbsResult", "SparseGibbsResult", "sample_from_unnormalized",
-    "sample_from_unnormalized_seq", "gibbs_position_update",
+    "GibbsResult", "SparseGibbsResult", "sample_keepdims",
+    "sample_from_unnormalized", "mean_seq", "gibbs_position_update",
     "gibbs_sweeps_dense", "gibbs_sweeps_sparse", "draw_gibbs_randoms",
     "stats_from_per_pos", "stats_from_unique", "dense_to_unique",
     "unique_view",
@@ -111,41 +110,52 @@ def _one_hot(z: jax.Array, k: int, dtype) -> jax.Array:
     return (z[..., None] == jnp.arange(k, dtype=z.dtype)).astype(dtype)
 
 
-def sample_from_unnormalized(probs: jax.Array, u: jax.Array) -> jax.Array:
-    """Inverse-CDF sample from an unnormalized probability vector [..., K]."""
-    cum = jnp.cumsum(probs, axis=-1)
-    return jnp.sum(cum < u[..., None] * cum[..., -1:], axis=-1).astype(
-        jnp.int32)
+def sample_keepdims(probs: jax.Array, u: jax.Array) -> jax.Array:
+    """Inverse-CDF sample from an unnormalized probability vector [..., K].
 
-
-def sample_from_unnormalized_seq(probs: jax.Array,
-                                 u: jax.Array) -> jax.Array:
-    """Inverse-CDF draw with a FIXED sequential cumsum association.
-
-    Same draw as :func:`sample_from_unnormalized`, but the running sums
-    are built as ``((p0 + p1) + p2) + ...`` by explicit unrolled adds
-    instead of ``jnp.cumsum``. XLA lowers ``cumsum`` to a reduce-window
-    whose float-add association varies with shape and fusion context, so
-    two call sites computing "the same" cumsum can disagree in the last
-    ulp — which flips a ``cum < u * total`` comparison on measure-zero
-    ties. The unrolled form pins one association everywhere (XLA never
-    reassociates explicit float adds), making the fused evaluator, the
-    lda_l2r Pallas kernel and any future call site bit-identical to each
-    other by construction. K is a static trailing dim (unrolled K-1
-    adds + K compares — cheaper than reduce-window for the K <= 16 of
-    every LDA config here).
+    ``u`` [..., 1] uniforms; returns [..., 1] int32 draws. The running
+    sums are built as ``((p0 + p1) + p2) + ...`` by explicit unrolled
+    adds, not ``jnp.cumsum``: XLA lowers ``cumsum`` to a reduce-window
+    whose float-add association varies with shape, fusion context and
+    backend, so two call sites computing "the same" cumsum can disagree
+    in the last ulp and flip a ``cum < u * total`` comparison. Explicit
+    adds pin one association everywhere (XLA never reassociates them),
+    and Pallas TPU has no cumsum lowering, so every kernel, the jnp
+    sweeps and both evaluators draw through this one function and agree
+    bit for bit. The trailing singleton axis is the column-vector layout
+    of the Pallas kernels (documents on sublanes). K is a static trailing
+    dim (K-1 adds and K compares, unrolled).
     """
     k = probs.shape[-1]
-    c = probs[..., 0]
+    c = probs[..., 0:1]
     cums = [c]
     for j in range(1, k):
-        c = c + probs[..., j]
+        c = c + probs[..., j:j + 1]
         cums.append(c)
     thresh = u * cums[-1]
-    z = jnp.zeros(probs.shape[:-1], jnp.int32)
+    z = jnp.zeros(thresh.shape, jnp.int32)
     for cj in cums:
         z = z + (cj < thresh).astype(jnp.int32)
     return z
+
+
+def sample_from_unnormalized(probs: jax.Array, u: jax.Array) -> jax.Array:
+    """:func:`sample_keepdims` with ``u`` [...] and draws [...]."""
+    return sample_keepdims(probs, u[..., None])[..., 0]
+
+
+def mean_seq(parts) -> jax.Array:
+    """Mean of equal-shape arrays, summed left to right.
+
+    The particle mean of the left-to-right evaluators: like
+    :func:`sample_keepdims`, explicit adds fix one association, so the
+    serial and fused evaluators and the lda_l2r kernel (which keeps
+    particles on a different axis) agree bit for bit.
+    """
+    total = parts[0]
+    for x in parts[1:]:
+        total = total + x
+    return total / len(parts)
 
 
 def gibbs_position_update(n_dk, zi, bw, mf, u, alpha):
@@ -208,8 +218,10 @@ def gibbs_sweeps_dense(beta_w: jax.Array, maskf: jax.Array,
         keep = jnp.asarray(s >= burnin, n_dk.dtype)
         return (z, n_dk, acc, ndk_acc + keep * n_dk), None
 
-    acc0 = jnp.zeros((b, l, k), beta_w.dtype)
-    ndk0 = jnp.zeros((b, k), beta_w.dtype)
+    # carries built from the inputs, not from constants: under shard_map
+    # they then vary over the same mesh axes as the loop's outputs
+    acc0 = jnp.zeros_like(beta_w)
+    ndk0 = jnp.zeros_like(n_dk0)
     (z, _n_dk, acc, ndk_acc), _ = jax.lax.scan(
         sweep, (z0, n_dk0, acc0, ndk0), jnp.arange(n_sweeps))
 
@@ -270,8 +282,8 @@ def gibbs_sweeps_sparse(beta_w: jax.Array, countf: jax.Array,
         keep = jnp.asarray(s >= burnin, n_dk.dtype)
         return (m, n_dk, acc, ndk_acc + keep * n_dk), None
 
-    acc0 = jnp.zeros((b, u_dim, k), beta_w.dtype)
-    ndk0 = jnp.zeros((b, k), beta_w.dtype)
+    acc0 = jnp.zeros_like(beta_w)
+    ndk0 = jnp.zeros_like(n_dk0)
     (m, _n_dk, acc, ndk_acc), _ = jax.lax.scan(
         sweep, (m0, n_dk0, acc0, ndk0), jnp.arange(n_sweeps))
 
@@ -521,8 +533,7 @@ class PallasEStep(_EStepBase):
 
     ``interpret=None`` auto-detects: compiled on TPU, interpreter elsewhere
     (kernels/common.resolve_interpret — the same dispatch gossip_mix uses).
-    The kernel is Rao-Blackwellized only; ``rao_blackwell=False`` falls back
-    to the dense backend with a warning instead of crashing a config sweep.
+    The kernel is Rao-Blackwellized only; ``rao_blackwell=False`` raises.
     """
 
     name = "pallas"
@@ -534,13 +545,9 @@ class PallasEStep(_EStepBase):
     def sweeps(self, beta_w, maskf, uniforms, z0, *, alpha, n_sweeps,
                burnin, rao_blackwell=True):
         if not rao_blackwell:
-            warnings.warn(
-                "the lda_gibbs kernel is Rao-Blackwellized only; "
-                "falling back to the dense E-step for rao_blackwell=False",
-                stacklevel=2)
-            return gibbs_sweeps_dense(beta_w, maskf, uniforms, z0,
-                                      alpha=alpha, n_sweeps=n_sweeps,
-                                      burnin=burnin, rao_blackwell=False)
+            raise ValueError("the lda_gibbs kernel is Rao-Blackwellized "
+                             "only; use the dense E-step for "
+                             "rao_blackwell=False")
         from repro.kernels.lda_gibbs import ops as lda_gibbs_ops
         return lda_gibbs_ops.gibbs_sweeps(
             beta_w, maskf, uniforms, z0, alpha=alpha, n_sweeps=n_sweeps,
@@ -614,7 +621,7 @@ class PallasSparseEStep(_SparseEStepBase):
     """The kernels/lda_sparse TPU kernel (grid over doc blocks, the
     count-split segment state resident in VMEM). ``interpret=None``
     auto-detects like every other kernel backend; Rao-Blackwellized only,
-    with the same warn-and-fall-back for ``rao_blackwell=False``."""
+    so ``rao_blackwell=False`` raises."""
 
     name = "pallas"
 
@@ -625,13 +632,9 @@ class PallasSparseEStep(_SparseEStepBase):
     def sweeps(self, beta_w, countf, uniforms, z0, *, alpha, n_sweeps,
                burnin, rao_blackwell=True):
         if not rao_blackwell:
-            warnings.warn(
-                "the lda_sparse kernel is Rao-Blackwellized only; "
-                "falling back to the dense sparse E-step for "
-                "rao_blackwell=False", stacklevel=2)
-            return gibbs_sweeps_sparse(beta_w, countf, uniforms, z0,
-                                       alpha=alpha, n_sweeps=n_sweeps,
-                                       burnin=burnin, rao_blackwell=False)
+            raise ValueError("the lda_sparse kernel is Rao-Blackwellized "
+                             "only; use the dense E-step for "
+                             "rao_blackwell=False")
         from repro.kernels.lda_sparse import ops as lda_sparse_ops
         return lda_sparse_ops.sparse_sweeps(
             beta_w, countf, uniforms, z0, alpha=alpha, n_sweeps=n_sweeps,

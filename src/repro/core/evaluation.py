@@ -156,7 +156,8 @@ def left_to_right_from_beta_w(key: jax.Array, doc_ids: jax.Array,
         n_lt = n_k.sum(-1, keepdims=True)                      # [B, P, 1]
         theta_hat = (n_k + alpha) / (n_lt + alpha_sum)         # [B, P, K]
         p_w = (theta_hat * bw_n[:, None, :]).sum(-1)           # [B, P]
-        log_p = jnp.log(jnp.maximum(p_w.mean(axis=1), 1e-30))  # [B]
+        log_p = jnp.log(jnp.maximum(
+            estep_mod.mean_seq([p_w[:, j] for j in range(p)]), 1e-30))
         log_p = jnp.where(mask[:, n_idx], log_p, 0.0)
 
         # draw z_n for each particle and add to counts
@@ -227,8 +228,8 @@ def left_to_right_unique_from_beta_w(key: jax.Array, doc_ids: jax.Array,
         n_lt = n_k.sum(-1, keepdims=True)                      # [B, P, 1]
         theta_hat = (n_k + alpha) / (n_lt + alpha_sum)         # [B, P, K]
         p_w = (theta_hat * bw_n[:, None, :]).sum(-1)           # [B, P]
-        log_p = countf[:, n_idx] * jnp.log(
-            jnp.maximum(p_w.mean(axis=1), 1e-30))              # [B]
+        log_p = countf[:, n_idx] * jnp.log(jnp.maximum(
+            estep_mod.mean_seq([p_w[:, j] for j in range(p)]), 1e-30))
         log_p = jnp.where(counts[:, n_idx] > 0, log_p, 0.0)
 
         probs_n = (n_k + alpha) * bw_n[:, None, :]             # [B, P, K]
@@ -267,11 +268,11 @@ def _z_packing(n_particles: int, k_dim: int) -> tuple[int, int, int]:
     return bits, ppw, -(-n_particles // ppw)
 
 
-def _l2r_fused_core(keys_kd, beta_w, weights, alpha, n_particles,
+def _l2r_fused_core(keys, beta_w, weights, alpha, n_particles,
                     count_weighted):
     """Shared fused left-to-right scan over [B] docs at once.
 
-    keys_kd [B, 2] uint32 per-document key data (already doc-folded);
+    keys [B] per-document PRNG keys (already doc-folded);
     beta_w [B, L, K]; weights [B, L] float — the dense layout passes the
     0/1 mask, the unique layout the token counts (the two estimators
     differ ONLY in whether slot n's score is multiplied by its count,
@@ -287,8 +288,8 @@ def _l2r_fused_core(keys_kd, beta_w, weights, alpha, n_particles,
     * per-step uniforms computed IN the resample loop via
       ``tf3.uniform_column`` (one threefry cipher per consumed value,
       instead of materializing the [B, P, L] block each position);
-    * the draw uses ``estep.sample_from_unnormalized_seq`` — fixed
-      sequential cumsum association, shape- and context-independent bits;
+    * the draw is ``estep.sample_from_unnormalized`` — fixed sequential
+      cumsum association, shape- and context-independent bits;
     * the inner loop runs ``fori_loop(0, n)`` — the serial paths loop
       over all L positions and mask the tail to no-ops; dropping those
       identity steps halves the sequential work without touching any
@@ -298,6 +299,7 @@ def _l2r_fused_core(keys_kd, beta_w, weights, alpha, n_particles,
     p = n_particles
     dt = beta_w.dtype
     alpha_sum = alpha * k_dim
+    k1, k2 = tf3.key_pair(keys)                     # [B, 1] uint32 each
     bits, ppw, n_words = _z_packing(p, k_dim)
     lane = jnp.arange(ppw, dtype=jnp.uint32) * jnp.uint32(bits)
     vmask = jnp.uint32((1 << bits) - 1)
@@ -319,20 +321,18 @@ def _l2r_fused_core(keys_kd, beta_w, weights, alpha, n_particles,
 
     def position(carry, n_idx):
         z_prev, n_k = carry        # z [L, B, W] u32, n_k [B, P, K]
-        kd_n = tf3.fold_in_data(keys_kd,
-                                jnp.full((b,), n_idx, jnp.uint32))
-        rs_d, dr_d = tf3.split2_data(kd_n)          # [B, 2] each
-        u_dr_n = tf3.uniform_halves(dr_d, p)        # [B, P]
+        rs, dr = tf3.split2(*tf3.fold_in(k1, k2, n_idx))  # [B, 1] pairs
+        u_dr_n = tf3.uniform(*dr, p)                # [B, P]
 
         def resample(i, st):
             z, n_k = st
             zi = unpack(z[i])                       # [B, P]
-            u = tf3.uniform_column(rs_d, p, l, i)   # [B, P]
+            u = tf3.uniform_column(*rs, p, l, i)    # [B, P]
             wf = w_t[i][:, None]                    # [B, 1]
             bw = beta_w_t[i][:, None, :]            # [B, 1, K]
             n_k = n_k - wf[..., None] * estep_mod._one_hot(zi, k_dim, dt)
             probs = (n_k + alpha) * bw
-            new_z = estep_mod.sample_from_unnormalized_seq(probs, u)
+            new_z = estep_mod.sample_from_unnormalized(probs, u)
             new_z = jnp.where(wf > 0, new_z, zi)
             n_k = n_k + wf[..., None] * estep_mod._one_hot(new_z, k_dim,
                                                            dt)
@@ -345,7 +345,8 @@ def _l2r_fused_core(keys_kd, beta_w, weights, alpha, n_particles,
         n_lt = n_k.sum(-1, keepdims=True)
         theta_hat = (n_k + alpha) / (n_lt + alpha_sum)
         p_w = (theta_hat * bw_n[:, None, :]).sum(-1)
-        raw = jnp.log(jnp.maximum(p_w.mean(axis=1), 1e-30))
+        raw = jnp.log(jnp.maximum(
+            estep_mod.mean_seq([p_w[:, j] for j in range(p)]), 1e-30))
         if count_weighted:
             raw = w_t[n_idx] * raw
         log_p = jnp.where(w_t[n_idx] > 0, raw, 0.0)
@@ -372,14 +373,12 @@ def left_to_right_fused(key: jax.Array, doc_ids: jax.Array,
     Same signature, same ``fold_in(key, doc_id)`` / ``fold_in(doc_key,
     position)`` stream derivation (so chunk/batch invariance is
     untouched), restructured for wall time — see :func:`_l2r_fused_core`.
-    Bit-identical to the serial estimator on every tested input; the two
-    can differ only where a resample draw lands exactly on the one-ulp
-    reassociation gap of XLA's cumsum lowering (a measure-zero tie that
-    is a correct draw either way), asserted equal in
-    tests/test_evaluation.py and by the byte-identical eval goldens.
+    Bit-identical to the serial estimator: both draw through
+    ``estep.sample_from_unnormalized`` from the same streams, asserted
+    equal in tests/test_evaluation.py and by the eval goldens.
     """
-    keys_kd = tf3.key_data(_doc_keys(key, doc_ids))
-    return _l2r_fused_core(keys_kd, beta_w, mask.astype(beta_w.dtype),
+    return _l2r_fused_core(_doc_keys(key, doc_ids), beta_w,
+                           mask.astype(beta_w.dtype),
                            alpha, n_particles, count_weighted=False)
 
 
@@ -392,8 +391,8 @@ def left_to_right_unique_fused(key: jax.Array, doc_ids: jax.Array,
     The count-weighted (CSR unique-slot) layout through the same fused
     core: weights are the token counts, slot n scores ``c * log p``.
     """
-    keys_kd = tf3.key_data(_doc_keys(key, doc_ids))
-    return _l2r_fused_core(keys_kd, beta_w, counts.astype(beta_w.dtype),
+    return _l2r_fused_core(_doc_keys(key, doc_ids), beta_w,
+                           counts.astype(beta_w.dtype),
                            alpha, n_particles, count_weighted=True)
 
 

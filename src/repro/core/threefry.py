@@ -1,4 +1,4 @@
-"""Bit-exact Threefry-2x32 replica of jax.random's non-partitionable mode.
+"""Bit-exact Threefry-2x32 replica of jax.random's partitionable mode.
 
 The streaming evaluator pins its PRNG contract to jax.random's
 ``fold_in(key, doc_id)`` / ``fold_in(doc_key, position)`` streams: golden
@@ -9,27 +9,25 @@ a single column, and nothing in its API can run *inside* a Pallas kernel.
 
 This module re-implements the three derivations the evaluator uses —
 ``fold_in``, ``split(key, 2)`` and ``uniform`` — as plain uint32/float32
-jnp arithmetic that produces the SAME BITS as jax.random under the
-default (non-partitionable) threefry implementation, while letting the
+jnp arithmetic that produces the SAME BITS as jax.random under jax's
+default (partitionable) threefry implementation, while letting the
 caller generate exactly the values it needs, where it needs them:
 
 * :func:`uniform_column` yields column ``i`` of ``uniform(key, (P, L))``
   without touching the other L-1 columns — the fused left-to-right
-  resample loop draws its per-step uniforms on the fly, halving the
-  drawn-value count (only columns ``i < n`` are ever consumed) and
-  keeping generation inside the fused loop body;
-* every function is expressible with ops Pallas supports (add/xor/shift
-  on uint32 plus a same-width bitcast), so the ``kernels/lda_l2r``
+  resample loop draws its per-step uniforms on the fly;
+* every function is expressible with ops Pallas TPU lowers (add/xor/shift
+  on uint32, iota, one same-width bitcast), so the ``kernels/lda_l2r``
   kernel derives the identical streams on-chip with no uniform inputs.
 
-Layout note (jax _src/prng.py, ``threefry_2x32``): a size-n draw ciphers
-counts ``iota(n)`` split into halves ``x1 = counts[:ceil(n/2)]``,
-``x2 = counts[ceil(n/2):]`` (odd n pads one zero count), and the output
-is ``concat(o1, o2)[:n]``. All functions below reproduce that halves
-pairing. Everything is asserted bitwise against jax.random in
-tests/test_threefry.py; if jax flips its default to the partitionable
-implementation these tests fail loudly rather than silently changing
-golden streams.
+Layout (jax _src/prng.py, ``_threefry_random_bits_partitionable`` and
+``_threefry_split_foldlike``): element f of a draw ciphers the 64-bit
+counter f as the word pair ``(0, f)`` and outputs ``o1 ^ o2``; child j of
+``split`` is the raw pair ``(o1, o2)`` of counter j; ``fold_in(key, d)``
+ciphers ``(0, d)``. Keys travel as two uint32 arrays ``(k1, k2)`` with a
+trailing singleton axis, so in-kernel code never slices or stacks the key
+pair. Everything is asserted bitwise against jax.random in
+tests/test_threefry.py.
 """
 
 from __future__ import annotations
@@ -39,15 +37,16 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = [
-    "cipher", "key_data", "fold_in_data", "split2_data",
-    "uniform_from_bits", "uniform_halves", "uniform_column",
+    "cipher", "key_data", "key_pair", "fold_in", "split2",
+    "uniform_from_bits", "uniform_at", "uniform", "uniform_column",
 ]
 
 _U32 = jnp.uint32
-# a numpy scalar, NOT jnp: module-level jax arrays are committed device
+# numpy scalars, NOT jnp: module-level jax arrays are committed device
 # constants, which a Pallas kernel closure cannot capture (np scalars
 # inline as jaxpr literals; same bits either way)
 _PARITY = np.uint32(0x1BD11BDA)
+_ZERO = np.uint32(0)
 _ROTATIONS = ((13, 15, 26, 6), (17, 29, 16, 24))
 
 
@@ -63,10 +62,11 @@ def cipher(k1, k2, x1, x2):
     key schedule ``[k1, k2, k1 ^ k2 ^ PARITY]`` rotating one slot per
     4-round group, with the group index folded into the second lane.
     """
-    k1 = k1.astype(_U32)
-    k2 = k2.astype(_U32)
+    k1 = jnp.asarray(k1).astype(_U32)
+    k2 = jnp.asarray(k2).astype(_U32)
     ks = [k1, k2, k1 ^ k2 ^ _PARITY]
-    x = [x1.astype(_U32) + ks[0], x2.astype(_U32) + ks[1]]
+    x = [jnp.asarray(x1).astype(_U32) + ks[0],
+         jnp.asarray(x2).astype(_U32) + ks[1]]
     rots = list(_ROTATIONS)
     ks = ks[1:] + ks[:1]
     for group in range(5):
@@ -87,32 +87,20 @@ def key_data(key: jax.Array) -> jax.Array:
     return key.astype(_U32)
 
 
-def fold_in_data(kd: jax.Array, data: jax.Array) -> jax.Array:
-    """``key_data(fold_in(key, data))`` — kd [..., 2], data broadcastable.
-
-    fold_in ciphers the single count ``data``: halves are ``x1 = [0]``,
-    ``x2 = [data]``, giving the new key ``(o1, o2)``.
-    """
-    data = jnp.asarray(data)
-    o1, o2 = cipher(kd[..., 0], kd[..., 1],
-                    jnp.zeros(data.shape, _U32), data.astype(_U32))
-    return jnp.stack([o1, o2], axis=-1)
+def key_pair(key: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """``(k1, k2)``, each [..., 1] uint32, of a [...] PRNG key array."""
+    kd = key_data(key)
+    return kd[..., 0:1], kd[..., 1:2]
 
 
-def split2_data(kd: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """``(key_data(k0), key_data(k1))`` for ``k0, k1 = split(key)``.
+def fold_in(k1, k2, data):
+    """Key pair of ``fold_in(key, data)``; ``data`` broadcasts with k."""
+    return cipher(k1, k2, _ZERO, data)
 
-    split(key, 2) ciphers counts ``iota(4)`` — halves ``x1 = [0, 1]``,
-    ``x2 = [2, 3]`` — and reshapes the concatenated output to [2, 2]:
-    the first child is ``(o1[0], o1[1])``, the second ``(o2[0], o2[1])``.
-    """
-    k1 = kd[..., 0:1]
-    k2 = kd[..., 1:2]
-    c01 = jnp.arange(2, dtype=_U32)
-    o1, o2 = cipher(k1, k2, jnp.broadcast_to(c01, k1.shape[:-1] + (2,)),
-                    jnp.broadcast_to(c01 + _U32(2),
-                                     k1.shape[:-1] + (2,)))
-    return o1, o2
+
+def split2(k1, k2):
+    """``((a1, a2), (b1, b2))``: the key pairs of ``k0, k1 = split(key)``."""
+    return cipher(k1, k2, _ZERO, _ZERO), cipher(k1, k2, _ZERO, np.uint32(1))
 
 
 def uniform_from_bits(bits: jax.Array) -> jax.Array:
@@ -125,42 +113,33 @@ def uniform_from_bits(bits: jax.Array) -> jax.Array:
     return jax.lax.bitcast_convert_type(fb, jnp.float32) - jnp.float32(1.0)
 
 
-def _halves_bits(kd: jax.Array, flat: jax.Array, n: int) -> jax.Array:
-    """Random bits at flat counter positions ``flat`` of a size-``n`` draw.
-
-    For a total draw of n values the counts iota(n) are ciphered as
-    halves of size h = ceil(n/2) (odd n pads one zero count): the value
-    at flat index f is ``o1`` of block f when f < h, else ``o2`` of
-    block f - h. Computes ONE cipher per requested value.
-    """
-    h = (n + 1) // 2
-    f = flat.astype(_U32)
-    in1 = jnp.where(f < h, f, f - _U32(h))
-    in2 = in1 + _U32(h)
-    if 2 * h != n:                       # odd n: the pad count is zero
-        in2 = jnp.where(in2 < n, in2, _U32(0))
-    o1, o2 = cipher(kd[..., 0], kd[..., 1], in1, in2)
-    return jnp.where(f < h, o1, o2)
+def uniform_at(k1, k2, flat) -> jax.Array:
+    """Uniforms at flat counter positions ``flat`` of one draw; the key
+    pair broadcasts against ``flat``."""
+    o1, o2 = cipher(k1, k2, _ZERO, flat)
+    return uniform_from_bits(o1 ^ o2)
 
 
-def uniform_halves(kd: jax.Array, n: int) -> jax.Array:
-    """``uniform(key, (n,))`` bits-exact, batched over leading kd dims.
-
-    kd [..., 2] -> [..., n] float32.
-    """
-    flat = jnp.broadcast_to(jnp.arange(n, dtype=_U32),
-                            kd.shape[:-1] + (n,))
-    return uniform_from_bits(_halves_bits(kd[..., None, :], flat, n))
+def _iota(k1, n: int) -> jax.Array:
+    """uint32 counters 0..n-1 along a new last axis replacing k1's
+    trailing singleton (a 2-D-or-more iota, which Pallas TPU lowers)."""
+    shape = k1.shape[:-1] + (n,)
+    return jax.lax.broadcasted_iota(jnp.int32, shape,
+                                    len(shape) - 1).astype(_U32)
 
 
-def uniform_column(kd: jax.Array, p: int, l: int, i: jax.Array
-                   ) -> jax.Array:
+def uniform(k1, k2, n: int) -> jax.Array:
+    """``uniform(key, (n,))`` bit-exact: keys [..., 1] -> [..., n]."""
+    return uniform_at(k1, k2, _iota(k1, n))
+
+
+def uniform_column(k1, k2, p: int, l: int, i: jax.Array) -> jax.Array:
     """Column ``i`` of ``uniform(key, (p, l))`` without drawing the rest.
 
-    kd [..., 2], i scalar (traced ok) -> [..., p] float32 equal to
-    ``jax.random.uniform(key, (p, l))[..., :, i]`` bitwise. The fused
-    left-to-right inner loop calls this once per resample step.
+    Keys [..., 1], i scalar (traced ok) -> [..., p] float32 equal to
+    ``jax.random.uniform(key, (p, l))[..., :, i]`` bitwise: the elements
+    at flat counters ``r * l + i``. The fused left-to-right inner loop
+    calls this once per resample step.
     """
-    rows = jnp.arange(p, dtype=_U32) * _U32(l)
-    flat = jnp.broadcast_to(rows, kd.shape[:-1] + (p,)) + i.astype(_U32)
-    return uniform_from_bits(_halves_bits(kd[..., None, :], flat, p * l))
+    flat = _iota(k1, p) * _U32(l) + jnp.asarray(i).astype(_U32)
+    return uniform_at(k1, k2, flat)

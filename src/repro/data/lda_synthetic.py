@@ -196,6 +196,20 @@ def _train_alpha_vecs(config: LDAConfig, spec: CorpusSpec,
     return node_weights, jnp.repeat(node_alphas, spec.docs_per_node, axis=0)
 
 
+# each document's draw materializes [L, V] word logits; chunks of
+# documents keep that block under this many bytes (V=50,000, L=16 would
+# otherwise need 26 GB for 8,192 documents at once)
+_DRAW_CHUNK_BYTES = 1 << 28
+
+
+def _draw_documents(config: LDAConfig, draw, *per_doc):
+    """``vmap(draw)(*per_doc)`` in chunks of bounded memory: the same
+    per-document values, whatever the chunk size."""
+    per_doc_bytes = config.doc_len_max * config.vocab_size * 4
+    batch = max(1, _DRAW_CHUNK_BYTES // per_doc_bytes)
+    return jax.lax.map(lambda xs: draw(*xs), per_doc, batch_size=batch)
+
+
 def _draw_training_shards(config: LDAConfig, spec: CorpusSpec,
                           beta_star: jax.Array, alpha_vecs: jax.Array,
                           k_len: jax.Array, k_doc: jax.Array):
@@ -208,10 +222,11 @@ def _draw_training_shards(config: LDAConfig, spec: CorpusSpec,
     n_train = spec.n_nodes * spec.docs_per_node
     lengths, trunc = _clip_lengths(
         _raw_lengths(k_len, spec, (n_train,)), config.doc_len_max)
-    words, mask = jax.vmap(
+    words, mask = _draw_documents(
+        config,
         lambda k, ln, av: sample_document(config, k, beta_star, ln,
-                                          alpha_vec=av)
-    )(jax.random.split(k_doc, n_train), lengths, alpha_vecs)
+                                          alpha_vec=av),
+        jax.random.split(k_doc, n_train), lengths, alpha_vecs)
     words = words.reshape(spec.n_nodes, spec.docs_per_node, -1)
     mask = mask.reshape(spec.n_nodes, spec.docs_per_node, -1)
     return words, mask, trunc
@@ -237,9 +252,9 @@ def make_corpus(config: LDAConfig, key: jax.Array,
     # GLOBAL model, whichever way the training shards were biased
     t_lengths, t_trunc = _clip_lengths(
         _raw_lengths(k_tlen, spec, (spec.n_test,)), config.doc_len_max)
-    t_words, t_mask = jax.vmap(
-        lambda k, ln: sample_document(config, k, beta_star, ln)
-    )(jax.random.split(k_tdoc, spec.n_test), t_lengths)
+    t_words, t_mask = _draw_documents(
+        config, lambda k, ln: sample_document(config, k, beta_star, ln),
+        jax.random.split(k_tdoc, spec.n_test), t_lengths)
 
     trunc_frac = float(jnp.concatenate([trunc, t_trunc])
                        .astype(jnp.float32).mean())

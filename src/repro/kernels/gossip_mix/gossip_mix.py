@@ -55,4 +55,5 @@ def mix_matching_pallas(stats: jax.Array, partners: jax.Array, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, k, v), stats.dtype),
         interpret=interpret,
+        name="gossip_mix",
     )(partners, stats, stats)

@@ -14,8 +14,10 @@ __all__ = ["mix_matching", "resolve_interpret"]
 
 
 def _v_block(v: int, requested: int) -> int:
-    """Largest divisor of v not exceeding `requested` (prefer 128-multiples)."""
-    for cand in range(min(requested, v), 0, -1):
+    """Largest multiple of 128 lanes that divides v and does not exceed
+    `requested`, else the full v: a TPU block's last dim must be a
+    128-multiple or the whole array dim."""
+    for cand in range(min(requested, v) // 128 * 128, 0, -128):
         if v % cand == 0:
             return cand
     return v

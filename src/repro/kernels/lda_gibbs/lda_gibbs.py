@@ -15,12 +15,13 @@ over documents (sublane axis) and topics (lane axis). TPU adaptation:
     with the document block, so the kernel is deterministic and bit-exact
     against the pure-jnp oracle (ref.py);
   * the grid is 1-D over document blocks; each step keeps the whole
-    [B_blk, L, K] working set (beta_w, uniforms, the Rao-Blackwell
-    accumulator) resident in VMEM. For the paper scale (L=32..64, K<=128
-    lanes) that is ~1 MB per block — far under the ~16 MB VMEM budget, so
-    B_blk can grow until the VPU is saturated.
+    [L, B_blk, K] working set (beta_w, uniforms, the Rao-Blackwell
+    accumulator) resident in VMEM, position-major so the sequential
+    position loop indexes the untiled leading axis. For the paper scale
+    (L=32..64, K<=128 lanes) that is ~1 MB per block — far under the
+    ~16 MB VMEM budget, so B_blk can grow until the VPU is saturated.
 
-Sampling uses the same inverse-CDF-on-unnormalized-cumsum as the oracle.
+Sampling is the oracle's own inverse-CDF draw (`estep.sample_keepdims`).
 """
 
 from __future__ import annotations
@@ -31,17 +32,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-
-def _one_hot(z: jax.Array, k: int, dtype) -> jax.Array:
-    """[..., ] int32 -> [..., k] one-hot (iota+compare; MXU-free)."""
-    iota = jax.lax.broadcasted_iota(jnp.int32, (*z.shape, k), len(z.shape))
-    return (z[..., None] == iota).astype(dtype)
-
-
-def _sample_cat(probs: jax.Array, u: jax.Array) -> jax.Array:
-    """Inverse-CDF draw from unnormalized probs [B, K] with u [B]."""
-    cum = jnp.cumsum(probs, axis=-1)
-    return jnp.sum(cum < u[:, None] * cum[:, -1:], axis=-1).astype(jnp.int32)
+from repro.core.estep import sample_keepdims
+from repro.kernels.common import one_hot, out_struct, resolve_interpret
 
 
 def gibbs_block_kernel(beta_w_ref, mask_ref, u_ref, z0_ref,
@@ -49,79 +41,75 @@ def gibbs_block_kernel(beta_w_ref, mask_ref, u_ref, z0_ref,
                        *, alpha: float, n_sweeps: int, burnin: int):
     """One grid step: all Gibbs sweeps for a [B_blk] block of documents.
 
-    beta_w_ref: [B_blk, L, K] f32    per-position topic likelihood rows
-    mask_ref:   [B_blk, L]    f32    1.0 for real tokens
-    u_ref:      [S, B_blk, L] f32    pre-drawn uniforms
-    z0_ref:     [B_blk, L]    i32    initial topic assignments
-    per_pos_ref:[B_blk, L, K] f32    OUT mean Rao-Blackwell posterior
-    z_ref:      [B_blk, L]    i32    OUT final assignments
-    ndk_ref:    [B_blk, K]    f32    OUT mean doc-topic counts (kept sweeps)
+    Position-major blocks: the per-position index is the untiled leading
+    axis, so every loop step reads and writes whole [B_blk, *] tiles of
+    a ref (documents on sublanes) — Mosaic lowers no dynamic slice of a
+    loaded value.
+
+    beta_w_ref: [L, B_blk, K]    f32  per-position topic likelihood rows
+    mask_ref:   [L, B_blk, 1]    f32  1.0 for real tokens
+    u_ref:      [S, L, B_blk, 1] f32  pre-drawn uniforms
+    z0_ref:     [L, B_blk, 1]    i32  initial topic assignments
+    per_pos_ref:[L, B_blk, K]    f32  OUT mean Rao-Blackwell posterior
+                                      (the accumulator while sweeping)
+    z_ref:      [L, B_blk, 1]    i32  OUT final assignments (the live
+                                      state while sweeping)
+    ndk_ref:    [B_blk, K]       f32  OUT mean doc-topic counts (kept sweeps)
     """
-    beta_w = beta_w_ref[...]
-    maskf = mask_ref[...]
-    z = z0_ref[...]
-    b_blk, l, k = beta_w.shape
+    l, b_blk, k = beta_w_ref.shape
+    dt = per_pos_ref.dtype
     n_keep = n_sweeps - burnin
 
-    n_dk = jnp.sum(_one_hot(z, k, beta_w.dtype) * maskf[..., None], axis=1)
+    z_ref[...] = z0_ref[...]
+    per_pos_ref[...] = jnp.zeros(per_pos_ref.shape, dt)
 
-    def position(i, carry, *, s):
-        z, n_dk, acc = carry
-        m = jax.lax.dynamic_slice_in_dim(maskf, i, 1, axis=1)[:, 0]   # [B]
-        zi = jax.lax.dynamic_slice_in_dim(z, i, 1, axis=1)[:, 0]      # [B]
-        bw = jax.lax.dynamic_slice_in_dim(beta_w, i, 1, axis=1)[:, 0]  # [B,K]
-        u = jax.lax.dynamic_slice_in_dim(
-            jax.lax.dynamic_slice_in_dim(u_ref[...], s, 1, axis=0)[0],
-            i, 1, axis=1)[:, 0]                                        # [B]
+    def count(i, n_dk):
+        return n_dk + mask_ref[i] * one_hot(z_ref[i], k, dt)
 
-        n_dk = n_dk - m[:, None] * _one_hot(zi, k, n_dk.dtype)
-        probs = (n_dk + alpha) * bw                                    # [B,K]
-        new_z = _sample_cat(probs, u)
+    n_dk = jax.lax.fori_loop(0, l, count, jnp.zeros((b_blk, k), dt))
+
+    def position(i, n_dk, *, s):
+        m = mask_ref[i]                                          # [B, 1]
+        zi = z_ref[i]                                            # [B, 1]
+        n_dk = n_dk - m * one_hot(zi, k, dt)
+        probs = (n_dk + alpha) * beta_w_ref[i]                   # [B, K]
+        new_z = sample_keepdims(probs, u_ref[s, i])
         new_z = jnp.where(m > 0, new_z, zi)
-        n_dk = n_dk + m[:, None] * _one_hot(new_z, k, n_dk.dtype)
+        n_dk = n_dk + m * one_hot(new_z, k, dt)
 
         post = probs / jnp.maximum(probs.sum(-1, keepdims=True), 1e-30)
-        collect = jnp.asarray(s >= burnin, post.dtype)
-        acc = jax.lax.dynamic_update_slice_in_dim(
-            acc,
-            (jax.lax.dynamic_slice_in_dim(acc, i, 1, axis=1)[:, 0]
-             + collect * m[:, None] * post)[:, None, :],
-            i, axis=1)
-        z = jax.lax.dynamic_update_slice_in_dim(
-            z, new_z[:, None], i, axis=1)
-        return z, n_dk, acc
+        collect = jnp.asarray(s >= burnin, dt)
+        per_pos_ref[i] = per_pos_ref[i] + collect * m * post
+        z_ref[i] = new_z
+        return n_dk
 
     def sweep(s, carry):
-        z, n_dk, acc, ndk_acc = carry
-        z, n_dk, acc = jax.lax.fori_loop(
-            0, l, functools.partial(position, s=s), (z, n_dk, acc))
-        keep = jnp.asarray(s >= burnin, n_dk.dtype)
-        return z, n_dk, acc + 0.0, ndk_acc + keep * n_dk
+        n_dk, ndk_acc = carry
+        n_dk = jax.lax.fori_loop(0, l, functools.partial(position, s=s),
+                                 n_dk)
+        keep = jnp.asarray(s >= burnin, dt)
+        return n_dk, ndk_acc + keep * n_dk
 
-    acc0 = jnp.zeros((b_blk, l, k), beta_w.dtype)
-    ndk_acc0 = jnp.zeros((b_blk, k), beta_w.dtype)
+    _, ndk_acc = jax.lax.fori_loop(
+        0, n_sweeps, sweep, (n_dk, jnp.zeros((b_blk, k), dt)))
 
-    # NOTE: python loop over sweeps (n_sweeps is static & small) would also
-    # work, but fori_loop keeps the unrolled program size independent of S.
-    def sweep_loop(s, carry):
-        return sweep(s, carry)
+    def finish(i, c):
+        per_pos_ref[i] = per_pos_ref[i] / n_keep * mask_ref[i]
+        return c
 
-    z, n_dk, acc, ndk_acc = jax.lax.fori_loop(
-        0, n_sweeps, sweep_loop, (z, n_dk, acc0, ndk_acc0))
-
-    per_pos_ref[...] = acc / n_keep * maskf[..., None]
-    z_ref[...] = z
+    jax.lax.fori_loop(0, l, finish, 0)
     ndk_ref[...] = ndk_acc / n_keep
 
 
 def gibbs_sweeps_pallas(beta_w: jax.Array, maskf: jax.Array,
                         uniforms: jax.Array, z0: jax.Array, *,
                         alpha: float, n_sweeps: int, burnin: int,
-                        block_docs: int = 8, interpret: bool = True
+                        block_docs: int = 8, interpret: bool | None = None
                         ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """pallas_call wrapper. beta_w [B,L,K]; B must divide by block_docs.
 
-    Returns (per_pos [B,L,K], z [B,L], ndk_mean [B,K]).
+    Returns (per_pos [B,L,K], z [B,L], ndk_mean [B,K]). The transposes to
+    and from the kernel's position-major layout happen here.
     """
     b, l, k = beta_w.shape
     s = uniforms.shape[0]
@@ -131,24 +119,28 @@ def gibbs_sweeps_pallas(beta_w: jax.Array, maskf: jax.Array,
 
     kernel = functools.partial(gibbs_block_kernel, alpha=alpha,
                                n_sweeps=n_sweeps, burnin=burnin)
-    return pl.pallas_call(
+    ins = (jnp.swapaxes(beta_w, 0, 1), maskf.T[..., None],
+           jnp.swapaxes(uniforms, 1, 2)[..., None], z0.T[..., None])
+    per_pos, z, ndk = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_docs, l, k), lambda i: (i, 0, 0)),
-            pl.BlockSpec((block_docs, l), lambda i: (i, 0)),
-            pl.BlockSpec((s, block_docs, l), lambda i: (0, i, 0)),
-            pl.BlockSpec((block_docs, l), lambda i: (i, 0)),
+            pl.BlockSpec((l, block_docs, k), lambda i: (0, i, 0)),
+            pl.BlockSpec((l, block_docs, 1), lambda i: (0, i, 0)),
+            pl.BlockSpec((s, l, block_docs, 1), lambda i: (0, 0, i, 0)),
+            pl.BlockSpec((l, block_docs, 1), lambda i: (0, i, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((block_docs, l, k), lambda i: (i, 0, 0)),
-            pl.BlockSpec((block_docs, l), lambda i: (i, 0)),
+            pl.BlockSpec((l, block_docs, k), lambda i: (0, i, 0)),
+            pl.BlockSpec((l, block_docs, 1), lambda i: (0, i, 0)),
             pl.BlockSpec((block_docs, k), lambda i: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, l, k), beta_w.dtype),
-            jax.ShapeDtypeStruct((b, l), jnp.int32),
-            jax.ShapeDtypeStruct((b, k), beta_w.dtype),
+            out_struct((l, b, k), beta_w.dtype, *ins),
+            out_struct((l, b, 1), jnp.int32, *ins),
+            out_struct((b, k), beta_w.dtype, *ins),
         ],
-        interpret=interpret,
-    )(beta_w, maskf, uniforms, z0)
+        interpret=resolve_interpret(interpret),
+        name="lda_gibbs",
+    )(*ins)
+    return jnp.swapaxes(per_pos, 0, 1), z[..., 0].T, ndk

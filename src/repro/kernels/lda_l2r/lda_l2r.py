@@ -17,8 +17,9 @@ to consume. Stream derivation (``fold_in(doc_key, n)`` then
 per-document results are bitwise chunk- and batch-invariant like theirs.
 
 Grid and residency follow the house layout: a 1-D grid over document
-blocks, with the [B_blk, L, K] likelihood rows, the weights and the
-position-major assignment buffer resident in VMEM for the whole scan.
+blocks, with the position-major [L, B_blk, K] likelihood rows, the
+weights and the [L, P, B_blk] assignment scratch resident in VMEM for
+the whole scan.
 ``weights`` carries the dense layout's 0/1 mask or the unique (CSR)
 layout's token counts — ``count_weighted`` picks whether slot n's score
 is multiplied by its count, the ONLY difference between the two
@@ -32,107 +33,91 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import estep as estep_mod
 from repro.core import threefry as tf3
+from repro.kernels.common import one_hot, out_struct, resolve_interpret
 
 
-def _one_hot(z: jax.Array, k: int, dtype) -> jax.Array:
-    """[..., ] int32 -> [..., k] one-hot (broadcasted iota; MXU-free)."""
-    iota = jax.lax.broadcasted_iota(jnp.int32, (*z.shape, k), len(z.shape))
-    return (z[..., None] == iota).astype(dtype)
-
-
-def l2r_block_kernel(kd_ref, beta_w_ref, w_ref, alpha_ref, ll_ref,
+def l2r_block_kernel(kd_ref, beta_w_ref, w_ref, alpha_ref, ll_ref, z_ref,
                      *, n_particles: int, count_weighted: bool):
     """One grid step: full left-to-right estimate for a doc block.
 
+    Position-major blocks with particles on the leading axis of the live
+    state, so every per-position access indexes an untiled leading axis
+    and each particle is a [B_blk, *] tile (documents on sublanes).
+
     kd_ref:     [B_blk, 2]    u32  per-document key data (doc-folded)
-    beta_w_ref: [B_blk, L, K] f32  per-position likelihood rows beta[:, w]
-    w_ref:      [B_blk, L]    f32  mask (dense) or counts (unique);
+    beta_w_ref: [L, B_blk, K] f32  per-position likelihood rows beta[:, w]
+    w_ref:      [L, B_blk, 1] f32  mask (dense) or counts (unique);
                                    0 = padding position/slot
     alpha_ref:  [1, 1]        f32  symmetric Dirichlet hyperparameter
                                    (an input, not a static, so traced
                                    alphas flow through the jitted chunk)
-    ll_ref:     [L, B_blk]    f32  OUT per-POSITION scores; the caller
+    ll_ref:     [L, B_blk, 1] f32  OUT per-POSITION scores; the caller
                                    reduces over L at the full [L, B]
                                    shape — summing inside the kernel
                                    would tie the reduction association
                                    to B_blk and drift ulps off the
                                    fused/serial oracles whenever
                                    block_docs != B
+    z_ref:      [L, P, B_blk, 1] i32  scratch: particle assignments
     """
-    kd = kd_ref[...]
-    beta_w = beta_w_ref[...]
-    w = w_ref[...]
-    alpha = alpha_ref[0, 0]
-    b, l, k_dim = beta_w.shape
+    l, b, k_dim = beta_w_ref.shape
     p = n_particles
-    dt = beta_w.dtype
+    dt = beta_w_ref.dtype
+    alpha = alpha_ref[...]                              # [1, 1]
     alpha_sum = alpha * k_dim
+    k1 = kd_ref[:, 0:1][None]                           # [1, B, 1]
+    k2 = kd_ref[:, 1:2][None]
+    rows = jax.lax.broadcasted_iota(jnp.int32, (p, b, 1), 0).astype(
+        jnp.uint32)                                     # particle index
+    z_ref[...] = jnp.zeros(z_ref.shape, jnp.int32)
 
-    # position-major views: every loop slice is a leading-axis row
-    beta_w_t = jnp.moveaxis(beta_w, 1, 0)               # [L, B, K]
-    w_t = w.T                                           # [L, B]
+    def position(n_idx, n_k):                           # n_k [P, B, K]
+        rs, dr = tf3.split2(*tf3.fold_in(k1, k2, n_idx))
+        u_dr_n = tf3.uniform_at(*dr, rows)              # [P, B, 1]
 
-    def row(x, i):
-        return jax.lax.dynamic_slice_in_dim(x, i, 1, axis=0)[0]
-
-    def position(n_idx, carry):
-        z, n_k, ll = carry     # z [L,B,P] i32, n_k [B,P,K], ll [B]
-        kd_n = tf3.fold_in_data(kd, jnp.full((b,), n_idx, jnp.uint32))
-        rs_d, dr_d = tf3.split2_data(kd_n)              # [B, 2] each
-        u_dr_n = tf3.uniform_halves(dr_d, p)            # [B, P]
-
-        def resample(i, st):
-            z, n_k = st
-            zi = row(z, i)                              # [B, P]
-            u = tf3.uniform_column(rs_d, p, l, i)       # [B, P]
-            wf = row(w_t, i)[:, None]                   # [B, 1]
-            bw = row(beta_w_t, i)[:, None, :]           # [B, 1, K]
-            n_k = n_k - wf[..., None] * _one_hot(zi, k_dim, dt)
-            probs = (n_k + alpha) * bw
-            new_z = estep_mod.sample_from_unnormalized_seq(probs, u)
+        def resample(i, n_k):
+            zi = z_ref[i]                               # [P, B, 1]
+            u = tf3.uniform_at(*rs, rows * np.uint32(l)
+                               + i.astype(jnp.uint32))  # column i
+            wf = w_ref[i][None]                         # [1, B, 1]
+            n_k = n_k - wf * one_hot(zi, k_dim, dt)
+            probs = (n_k + alpha) * beta_w_ref[i][None]
+            new_z = estep_mod.sample_keepdims(probs, u)
             new_z = jnp.where(wf > 0, new_z, zi)
-            n_k = n_k + wf[..., None] * _one_hot(new_z, k_dim, dt)
-            z = jax.lax.dynamic_update_slice_in_dim(
-                z, new_z[None], i, axis=0)
-            return z, n_k
+            z_ref[i] = new_z
+            return n_k + wf * one_hot(new_z, k_dim, dt)
 
-        z, n_k = jax.lax.fori_loop(0, n_idx, resample, (z, n_k))
+        n_k = jax.lax.fori_loop(0, n_idx, resample, n_k)
 
-        bw_n = row(beta_w_t, n_idx)                     # [B, K]
-        w_n = row(w_t, n_idx)                           # [B]
+        bw_n = beta_w_ref[n_idx][None]                  # [1, B, K]
+        w_n = w_ref[n_idx]                              # [B, 1]
         n_lt = n_k.sum(-1, keepdims=True)
         theta_hat = (n_k + alpha) / (n_lt + alpha_sum)
-        p_w = (theta_hat * bw_n[:, None, :]).sum(-1)
-        raw = jnp.log(jnp.maximum(p_w.mean(axis=1), 1e-30))
+        p_w = (theta_hat * bw_n).sum(-1, keepdims=True)  # [P, B, 1]
+        raw = jnp.log(jnp.maximum(
+            estep_mod.mean_seq([p_w[j] for j in range(p)]), 1e-30))
         if count_weighted:
             raw = w_n * raw
-        log_p = jnp.where(w_n > 0, raw, 0.0)
+        ll_ref[n_idx] = jnp.where(w_n > 0, raw, 0.0)
 
-        probs_n = (n_k + alpha) * bw_n[:, None, :]
-        z_n = estep_mod.sample_from_unnormalized(probs_n, u_dr_n)
-        n_k = n_k + w_n[:, None, None] * _one_hot(z_n, k_dim, dt)
-        z = jax.lax.dynamic_update_slice_in_dim(
-            z, jnp.where((w_n > 0)[:, None], z_n, row(z, n_idx))[None],
-            n_idx, axis=0)
-        ll = jax.lax.dynamic_update_slice_in_dim(
-            ll, log_p[None], n_idx, axis=0)
-        return z, n_k, ll
+        probs_n = (n_k + alpha) * bw_n
+        z_n = estep_mod.sample_keepdims(probs_n, u_dr_n)
+        z_ref[n_idx] = jnp.where(w_n[None] > 0, z_n, z_ref[n_idx])
+        return n_k + w_n[None] * one_hot(z_n, k_dim, dt)
 
-    z0 = jnp.zeros((l, b, p), jnp.int32)
-    nk0 = jnp.zeros((b, p, k_dim), dt)
-    ll0 = jnp.zeros((l, b), dt)
-    _, _, ll = jax.lax.fori_loop(0, l, position, (z0, nk0, ll0))
-    ll_ref[...] = ll
+    jax.lax.fori_loop(0, l, position, jnp.zeros((p, b, k_dim), dt))
 
 
 def l2r_scores_pallas(kd: jax.Array, beta_w: jax.Array, weights: jax.Array,
                       alpha: jax.Array, *, n_particles: int,
                       count_weighted: bool, block_docs: int = 8,
-                      interpret: bool = True) -> jax.Array:
+                      interpret: bool | None = None) -> jax.Array:
     """pallas_call wrapper. beta_w [B,L,K]; B must divide by block_docs.
 
     Returns the [L, B] per-position score matrix; the caller owns the
@@ -145,16 +130,21 @@ def l2r_scores_pallas(kd: jax.Array, beta_w: jax.Array, weights: jax.Array,
 
     kernel = functools.partial(l2r_block_kernel, n_particles=n_particles,
                                count_weighted=count_weighted)
-    return pl.pallas_call(
+    ins = (kd, jnp.swapaxes(beta_w, 0, 1), weights.T[..., None], alpha)
+    ll = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_docs, 2), lambda i: (i, 0)),
-            pl.BlockSpec((block_docs, l, k), lambda i: (i, 0, 0)),
-            pl.BlockSpec((block_docs, l), lambda i: (i, 0)),
+            pl.BlockSpec((l, block_docs, k), lambda i: (0, i, 0)),
+            pl.BlockSpec((l, block_docs, 1), lambda i: (0, i, 0)),
             pl.BlockSpec((1, 1), lambda i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((l, block_docs), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((l, b), beta_w.dtype),
-        interpret=interpret,
-    )(kd, beta_w, weights, alpha)
+        out_specs=pl.BlockSpec((l, block_docs, 1), lambda i: (0, i, 0)),
+        out_shape=out_struct((l, b, 1), beta_w.dtype, *ins),
+        scratch_shapes=[pltpu.VMEM((l, n_particles, block_docs, 1),
+                                   jnp.int32)],
+        interpret=resolve_interpret(interpret),
+        name="lda_l2r",
+    )(*ins)
+    return ll[..., 0]

@@ -16,8 +16,9 @@ adaptation mirrors kernels/lda_gibbs:
   * randomness is pre-drawn as uniforms [S, B, U]; the kernel is
     deterministic and bit-exact against the pure-jnp oracle (ref.py =
     core.estep.gibbs_sweeps_sparse);
-  * the grid is 1-D over document blocks; each step keeps the whole
-    segment state on-chip: the [B_blk, U, K] count splits m (the
+  * the grid is 1-D over document blocks, slot-major inside a block;
+    each step keeps the whole segment state on-chip: the [U, B_blk, K]
+    count splits m (the
     segmented representation of this block's token->topic assignment),
     the likelihood rows, uniforms and the count-weighted Rao-Blackwell
     accumulator all live in VMEM — only the final per-unique statistics
@@ -37,17 +38,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-
-def _one_hot(z: jax.Array, k: int, dtype) -> jax.Array:
-    """[..., ] int32 -> [..., k] one-hot (iota+compare; MXU-free)."""
-    iota = jax.lax.broadcasted_iota(jnp.int32, (*z.shape, k), len(z.shape))
-    return (z[..., None] == iota).astype(dtype)
-
-
-def _sample_cat(probs: jax.Array, u: jax.Array) -> jax.Array:
-    """Inverse-CDF draw from unnormalized probs [B, K] with u [B]."""
-    cum = jnp.cumsum(probs, axis=-1)
-    return jnp.sum(cum < u[:, None] * cum[:, -1:], axis=-1).astype(jnp.int32)
+from repro.core.estep import sample_keepdims
+from repro.kernels.common import one_hot, out_struct, resolve_interpret
 
 
 def sparse_block_kernel(beta_w_ref, count_ref, u_ref, z0_ref,
@@ -55,76 +47,74 @@ def sparse_block_kernel(beta_w_ref, count_ref, u_ref, z0_ref,
                         *, alpha: float, n_sweeps: int, burnin: int):
     """One grid step: all count-weighted sweeps for a [B_blk] doc block.
 
-    beta_w_ref:    [B_blk, U, K] f32  per-unique-word likelihood rows
-    count_ref:     [B_blk, U]    f32  token multiplicities (0 = padding)
-    u_ref:         [S, B_blk, U] f32  pre-drawn uniforms
-    z0_ref:        [B_blk, U]    i32  initial topic assignments
-    per_unique_ref:[B_blk, U, K] f32  OUT count-weighted mean RB posterior
-    m_ref:         [B_blk, U, K] f32  OUT final count splits
-    ndk_ref:       [B_blk, K]    f32  OUT mean doc-topic counts (kept)
+    Slot-major blocks (the slot index is the untiled leading axis), as in
+    the lda_gibbs kernel.
+
+    beta_w_ref:    [U, B_blk, K]    f32  per-unique-word likelihood rows
+    count_ref:     [U, B_blk, 1]    f32  token multiplicities (0 = padding)
+    u_ref:         [S, U, B_blk, 1] f32  pre-drawn uniforms
+    z0_ref:        [U, B_blk, 1]    i32  initial topic assignments
+    per_unique_ref:[U, B_blk, K]    f32  OUT count-weighted mean RB
+                                         posterior (the accumulator)
+    m_ref:         [U, B_blk, K]    f32  OUT final count splits (the live
+                                         state while sweeping)
+    ndk_ref:       [B_blk, K]       f32  OUT mean doc-topic counts (kept)
     """
-    beta_w = beta_w_ref[...]
-    countf = count_ref[...]
-    z0 = z0_ref[...]
-    b_blk, u_dim, k = beta_w.shape
+    u_dim, b_blk, k = beta_w_ref.shape
+    dt = per_unique_ref.dtype
     n_keep = n_sweeps - burnin
 
-    m0 = countf[..., None] * _one_hot(z0, k, beta_w.dtype)
-    n_dk0 = jnp.sum(m0, axis=1)
+    per_unique_ref[...] = jnp.zeros(per_unique_ref.shape, dt)
 
-    def slot(i, carry, *, s):
-        m, n_dk, acc = carry
-        c = jax.lax.dynamic_slice_in_dim(countf, i, 1, axis=1)[:, 0]  # [B]
-        m_i = jax.lax.dynamic_slice_in_dim(m, i, 1, axis=1)[:, 0]   # [B,K]
-        bw = jax.lax.dynamic_slice_in_dim(beta_w, i, 1, axis=1)[:, 0]
-        u = jax.lax.dynamic_slice_in_dim(
-            jax.lax.dynamic_slice_in_dim(u_ref[...], s, 1, axis=0)[0],
-            i, 1, axis=1)[:, 0]                                       # [B]
+    def init(i, n_dk):
+        m_i = count_ref[i] * one_hot(z0_ref[i], k, dt)
+        m_ref[i] = m_i
+        return n_dk + m_i
 
-        n_dk = n_dk - m_i
-        probs = (n_dk + alpha) * bw                                 # [B,K]
-        new_z = _sample_cat(probs, u)
-        new_m = c[:, None] * _one_hot(new_z, k, n_dk.dtype)
+    n_dk = jax.lax.fori_loop(0, u_dim, init, jnp.zeros((b_blk, k), dt))
+
+    def slot(i, n_dk, *, s):
+        c = count_ref[i]                                        # [B, 1]
+        n_dk = n_dk - m_ref[i]
+        probs = (n_dk + alpha) * beta_w_ref[i]                  # [B, K]
+        new_z = sample_keepdims(probs, u_ref[s, i])
+        new_m = c * one_hot(new_z, k, dt)
         n_dk = n_dk + new_m
 
         post = probs / jnp.maximum(probs.sum(-1, keepdims=True), 1e-30)
-        collect = jnp.asarray(s >= burnin, post.dtype)
-        acc = jax.lax.dynamic_update_slice_in_dim(
-            acc,
-            (jax.lax.dynamic_slice_in_dim(acc, i, 1, axis=1)[:, 0]
-             + collect * c[:, None] * post)[:, None, :],
-            i, axis=1)
-        m = jax.lax.dynamic_update_slice_in_dim(
-            m, new_m[:, None, :], i, axis=1)
-        return m, n_dk, acc
+        collect = jnp.asarray(s >= burnin, dt)
+        per_unique_ref[i] = per_unique_ref[i] + collect * c * post
+        m_ref[i] = new_m
+        return n_dk
 
     def sweep(s, carry):
-        m, n_dk, acc, ndk_acc = carry
-        m, n_dk, acc = jax.lax.fori_loop(
-            0, u_dim, functools.partial(slot, s=s), (m, n_dk, acc))
-        keep = jnp.asarray(s >= burnin, n_dk.dtype)
-        return m, n_dk, acc, ndk_acc + keep * n_dk
+        n_dk, ndk_acc = carry
+        n_dk = jax.lax.fori_loop(0, u_dim, functools.partial(slot, s=s),
+                                 n_dk)
+        keep = jnp.asarray(s >= burnin, dt)
+        return n_dk, ndk_acc + keep * n_dk
 
-    acc0 = jnp.zeros((b_blk, u_dim, k), beta_w.dtype)
-    ndk_acc0 = jnp.zeros((b_blk, k), beta_w.dtype)
+    _, ndk_acc = jax.lax.fori_loop(
+        0, n_sweeps, sweep, (n_dk, jnp.zeros((b_blk, k), dt)))
 
-    m, n_dk, acc, ndk_acc = jax.lax.fori_loop(
-        0, n_sweeps, sweep, (m0, n_dk0, acc0, ndk_acc0))
+    def finish(i, c):
+        slotf = (count_ref[i] > 0).astype(dt)
+        per_unique_ref[i] = per_unique_ref[i] / n_keep * slotf
+        return c
 
-    slotf = (countf > 0).astype(beta_w.dtype)
-    per_unique_ref[...] = acc / n_keep * slotf[..., None]
-    m_ref[...] = m
+    jax.lax.fori_loop(0, u_dim, finish, 0)
     ndk_ref[...] = ndk_acc / n_keep
 
 
 def sparse_sweeps_pallas(beta_w: jax.Array, countf: jax.Array,
                          uniforms: jax.Array, z0: jax.Array, *,
                          alpha: float, n_sweeps: int, burnin: int,
-                         block_docs: int = 8, interpret: bool = True
+                         block_docs: int = 8, interpret: bool | None = None
                          ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """pallas_call wrapper. beta_w [B,U,K]; B must divide by block_docs.
 
-    Returns (per_unique [B,U,K], m [B,U,K], ndk_mean [B,K]).
+    Returns (per_unique [B,U,K], m [B,U,K], ndk_mean [B,K]). The
+    transposes to and from the kernel's slot-major layout happen here.
     """
     b, u_dim, k = beta_w.shape
     s = uniforms.shape[0]
@@ -134,24 +124,29 @@ def sparse_sweeps_pallas(beta_w: jax.Array, countf: jax.Array,
 
     kernel = functools.partial(sparse_block_kernel, alpha=alpha,
                                n_sweeps=n_sweeps, burnin=burnin)
-    return pl.pallas_call(
+    ins = (jnp.swapaxes(beta_w, 0, 1), countf.T[..., None],
+           jnp.swapaxes(uniforms, 1, 2)[..., None], z0.T[..., None])
+    per_unique, m, ndk = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_docs, u_dim, k), lambda i: (i, 0, 0)),
-            pl.BlockSpec((block_docs, u_dim), lambda i: (i, 0)),
-            pl.BlockSpec((s, block_docs, u_dim), lambda i: (0, i, 0)),
-            pl.BlockSpec((block_docs, u_dim), lambda i: (i, 0)),
+            pl.BlockSpec((u_dim, block_docs, k), lambda i: (0, i, 0)),
+            pl.BlockSpec((u_dim, block_docs, 1), lambda i: (0, i, 0)),
+            pl.BlockSpec((s, u_dim, block_docs, 1),
+                         lambda i: (0, 0, i, 0)),
+            pl.BlockSpec((u_dim, block_docs, 1), lambda i: (0, i, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((block_docs, u_dim, k), lambda i: (i, 0, 0)),
-            pl.BlockSpec((block_docs, u_dim, k), lambda i: (i, 0, 0)),
+            pl.BlockSpec((u_dim, block_docs, k), lambda i: (0, i, 0)),
+            pl.BlockSpec((u_dim, block_docs, k), lambda i: (0, i, 0)),
             pl.BlockSpec((block_docs, k), lambda i: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, u_dim, k), beta_w.dtype),
-            jax.ShapeDtypeStruct((b, u_dim, k), beta_w.dtype),
-            jax.ShapeDtypeStruct((b, k), beta_w.dtype),
+            out_struct((u_dim, b, k), beta_w.dtype, *ins),
+            out_struct((u_dim, b, k), beta_w.dtype, *ins),
+            out_struct((b, k), beta_w.dtype, *ins),
         ],
-        interpret=interpret,
-    )(beta_w, countf, uniforms, z0)
+        interpret=resolve_interpret(interpret),
+        name="lda_sparse",
+    )(*ins)
+    return jnp.swapaxes(per_unique, 0, 1), jnp.swapaxes(m, 0, 1), ndk

@@ -97,8 +97,6 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
         cost = compiled.cost_analysis() or {}
     except Exception:
         cost = {}
-    if isinstance(cost, (list, tuple)):   # jax<0.6 returns [per-device dict]
-        cost = cost[0] if cost else {}
     flops = float(cost.get("flops", 0.0))
     bytes_accessed = float(cost.get("bytes accessed", 0.0))
     hlo = compiled.as_text()

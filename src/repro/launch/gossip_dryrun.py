@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs import get_config, list_archs
 from repro.core import decentralized as dec
 from repro.launch.mesh import make_production_mesh
@@ -58,8 +57,8 @@ def measure(arch: str, out_path: str | None = None) -> dict:
         def sync(tree):
             return dec.sync_tree_mesh(tree, spec, ("data",), (n_data,))
 
-        shmap = compat.shard_map(sync, mesh=mesh, in_specs=node,
-                                 out_specs=node)
+        shmap = jax.shard_map(sync, mesh=mesh, in_specs=node,
+                              out_specs=node)
         # one-shot lower per spec: each iteration compiles a DIFFERENT
         # program for inspection, nothing is re-traced on a hot path
         compiled = jax.jit(shmap).lower(abs_grads).compile()   # lint: allow(jit-per-call)

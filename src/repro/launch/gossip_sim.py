@@ -30,7 +30,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.configs.lda_paper import CONFIG as PAPER
 from repro.core import comm as comm_mod
 from repro.core import evaluation
@@ -42,6 +41,7 @@ from repro.core.lda import LDAConfig, beta_distance, eta_star, init_stats
 from repro.core.oem import make_rho_schedule
 from repro.core import estep as estep_mod
 from repro.data.lda_synthetic import CorpusSpec, make_corpus
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 
 
@@ -140,7 +140,7 @@ def build_update_step(lda: LDAConfig, batch_size: int, mesh,
         return (jnp.where(al[:, None, None], new_stats, stats),
                 jnp.where(al, steps + 1, steps))
 
-    shmap = compat.shard_map(
+    shmap = jax.shard_map(
         update_fn, mesh=mesh,
         in_specs=(stats_spec, node, P(), node, node, node),
         out_specs=(stats_spec, node))
@@ -392,6 +392,7 @@ def main(argv=None):
                     help="resume from the latest committed checkpoint in "
                          "this directory (bitwise-identical trajectory)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     mesh_shape = None
     if args.mesh_shape:
         try:

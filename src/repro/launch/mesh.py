@@ -5,7 +5,7 @@ from __future__ import annotations
 
 import jax
 
-from repro.compat import auto_axis_types, make_mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -16,10 +16,11 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes, axis_types=auto_axis_types(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh():
     """Whatever devices exist locally, as a 1-D "data" mesh (smoke/tests)."""
     n = len(jax.devices())
-    return make_mesh((n,), ("data",), axis_types=auto_axis_types(1))
+    return jax.make_mesh((n,), ("data",), axis_types=(AxisType.Auto,))

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config, list_archs, smoke_variant
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import encdec as ed
 from repro.models import frontends as fe
 from repro.models import transformer as tf
@@ -96,6 +97,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--full", action="store_true")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if not args.full:

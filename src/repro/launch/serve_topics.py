@@ -31,6 +31,7 @@ from repro.core import serving
 from repro.core.lda import LDAConfig, LDAState, init_state
 from repro.core.oem import run_oem
 from repro.data.lda_synthetic import CorpusSpec, make_corpus
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _percentile(xs: list[float], q: float) -> float:
@@ -122,6 +123,7 @@ def main(argv=None):
     ap.add_argument("--gossip-every", type=int, default=0,
                     help="publish a fresh statistic every N slabs (0 = off)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     config = LDAConfig(n_topics=args.topics, vocab_size=args.vocab,
                        alpha=args.alpha, doc_len_max=args.doc_len,

@@ -33,12 +33,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.checkpoint import save_checkpoint
 from repro.configs import get_config, list_archs, smoke_variant
 from repro.core import decentralized as dec
 from repro.data.lm_pipeline import TokenPipeline
 from repro.launch import steps as steps_mod
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import transformer as tf
 from repro.optim import make_optimizer, make_lr_schedule
@@ -130,7 +130,7 @@ def train_decentralized(cfg, args, mesh):
 
     node = P("data")
     state_spec = jax.tree.map(lambda x: node if jnp.ndim(x) else P(), state)
-    shmap = compat.shard_map(
+    shmap = jax.shard_map(
         step_fn, mesh=mesh,
         in_specs=(state_spec, node, node, node),
         out_specs=(state_spec, P()))
@@ -178,6 +178,7 @@ def main(argv=None):
     ap.add_argument("--ckpt", default=None)
     ap.add_argument("--log-every", type=int, default=5)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if not args.full:

@@ -355,7 +355,6 @@ LEAK_SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp
-    from repro import compat
     from repro.analysis import trace_audit as ta
     from repro.launch.mesh import make_host_mesh
     from jax.sharding import PartitionSpec as P
@@ -368,8 +367,8 @@ LEAK_SCRIPT = textwrap.dedent("""
         # gathered across nodes
         return jax.lax.all_gather(docs, "data", tiled=True)
 
-    fn = jax.jit(compat.shard_map(leaky, mesh=mesh, in_specs=node,
-                                  out_specs=node))
+    fn = jax.jit(jax.shard_map(leaky, mesh=mesh, in_specs=node,
+                               out_specs=node))
     docs = jnp.zeros((8, 8), jnp.int32)             # [B, L] tokens
     report = ta.audit_compiled(
         fn.lower(docs).compile(),

@@ -128,3 +128,17 @@ def test_degree_correction_only_async(corpus, graph):
     # complete graph: correction factor == 1, results identical
     np.testing.assert_allclose(np.asarray(trace_on.stats),
                                np.asarray(trace_off.stats), atol=1e-6)
+
+
+def test_corpus_draw_is_chunk_invariant(corpus, monkeypatch):
+    """Documents are drawn in memory-bounded chunks (a large vocabulary
+    would not fit a device at once); the chunk size changes no value."""
+    from repro.data import lda_synthetic
+    per_doc = CFG.doc_len_max * CFG.vocab_size * 4
+    monkeypatch.setattr(lda_synthetic, "_DRAW_CHUNK_BYTES", 3 * per_doc)
+    chunked = make_corpus(CFG, jax.random.key(0),
+                          CorpusSpec(n_nodes=8, docs_per_node=8, n_test=10))
+    for name in ("words", "mask", "test_words", "test_mask"):
+        np.testing.assert_array_equal(np.asarray(getattr(chunked, name)),
+                                      np.asarray(getattr(corpus, name)),
+                                      err_msg=name)

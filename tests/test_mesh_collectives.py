@@ -13,19 +13,17 @@ SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp
-    from jax.sharding import PartitionSpec as P
-    from repro import compat
+    from jax.sharding import AxisType, PartitionSpec as P
     from repro.core import decentralized as dec
 
-    mesh = compat.make_mesh((8,), ("data",),
-                            axis_types=compat.auto_axis_types(1))
+    mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
     x = jnp.arange(8 * 4, dtype=jnp.float32).reshape(8, 4)
     for spec_str in ["allreduce", "gossip-hypercube",
                      "gossip-hypercube[1]", "gossip-ring[2]"]:
         spec = dec.parse_sync(spec_str)
         f = lambda v: dec.sync_tree_mesh(v, spec, ("data",), (8,))
-        y = jax.jit(compat.shard_map(f, mesh=mesh, in_specs=P("data"),
-                                     out_specs=P("data")))(x)
+        y = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("data"),
+                                  out_specs=P("data")))(x)
         ysim = dec.sync_tree_sim(x, spec, 8)
         err = float(jnp.abs(y - ysim).max())
         assert err < 1e-5, (spec_str, err)
@@ -49,13 +47,13 @@ DRYRUN_SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax
-    from repro import compat
+    from jax.sharding import AxisType
     from repro.configs import get_config, smoke_variant
     from repro.configs.base import InputShape
     from repro.launch import steps as steps_mod
 
-    mesh = compat.make_mesh((2, 2, 2), ("pod", "data", "model"),
-                            axis_types=compat.auto_axis_types(3))
+    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                         axis_types=(AxisType.Auto,) * 3)
     cfg = smoke_variant(get_config("granite_3_8b"))
     for shape in [InputShape("t", 32, 8, "train"),
                   InputShape("d", 32, 8, "decode")]:

@@ -4,9 +4,8 @@ import jax
 import numpy as np
 import pytest
 from hyputil import given, settings, st
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, AxisType, PartitionSpec as P
 
-from repro.compat import abstract_mesh
 from repro.sharding import (FSDP_RULES, LOGICAL_RULES, logical_to_spec,
                             spec_for_shape)
 
@@ -14,7 +13,8 @@ from repro.sharding import (FSDP_RULES, LOGICAL_RULES, logical_to_spec,
 @pytest.fixture(scope="module")
 def mesh():
     # abstract mesh: no devices needed for spec computation
-    return abstract_mesh((4, 2), ("data", "model"))
+    return AbstractMesh((4, 2), ("data", "model"),
+                        axis_types=(AxisType.Auto,) * 2)
 
 
 def test_basic_rules(mesh):
@@ -65,7 +65,8 @@ def test_cache_seq_fallback(mesh):
     st.lists(st.integers(1, 64), min_size=4, max_size=4))
 @settings(max_examples=50, deadline=None)
 def test_spec_always_valid(axes, dims):
-    mesh = abstract_mesh((4, 2), ("data", "model"))
+    mesh = AbstractMesh((4, 2), ("data", "model"),
+                        axis_types=(AxisType.Auto,) * 2)
     axes = tuple(axes)
     shape = tuple(dims[:len(axes)])
     spec = spec_for_shape(shape, axes, mesh, LOGICAL_RULES)

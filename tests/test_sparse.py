@@ -272,12 +272,14 @@ def test_pallas_sparse_backend_matches_dense(rao_blackwell):
     beta = jax.random.dirichlet(jax.random.key(17),
                                 jnp.ones(CFG.vocab_size), (CFG.n_topics,))
     key = jax.random.key(18)
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore")
-        r_pal = estep.get_sparse_estep("pallas")(
-            CFG, key, uw, counts, beta, rao_blackwell=rao_blackwell)
-    r_den = estep.get_sparse_estep("dense")(
-        CFG, key, uw, counts, beta, rao_blackwell=rao_blackwell)
+    pallas = estep.get_sparse_estep("pallas")
+    if not rao_blackwell:
+        # Rao-Blackwellized only: refused, never swapped for jnp code
+        with pytest.raises(ValueError, match="Rao-Blackwell"):
+            pallas(CFG, key, uw, counts, beta, rao_blackwell=False)
+        return
+    r_pal = pallas(CFG, key, uw, counts, beta)
+    r_den = estep.get_sparse_estep("dense")(CFG, key, uw, counts, beta)
     # m is integer-valued (count splits); floats follow the repo's
     # atol=1e-6 convention (eager-vs-jit differs by ~1 ulp)
     np.testing.assert_array_equal(np.asarray(r_pal.m), np.asarray(r_den.m))

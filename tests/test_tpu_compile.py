@@ -1,0 +1,134 @@
+"""Compile every Pallas kernel of the main path for a TPU v5e chip.
+
+Interpret mode cannot see tiling, layout or VMEM refusals; the TPU
+compiler can, and it compiles for a chip that is described rather than
+attached. Shapes are those of ``chip_smoke.py`` (the ``big`` regime of
+``benchmarks/scale_bench.py``: n=1024 nodes, V=50,000, K=4, 2 docs per
+node per step, L=16, 4 Gibbs sweeps). Nothing runs, so these tests say
+nothing about results or times.
+
+The topology is described inside a module fixture, never at import: only
+the worker that runs this file may load the TPU compiler library.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from repro.core.lda import LDAConfig
+from repro.kernels.gossip_mix.ops import mix_matching
+from repro.kernels.lda_gibbs import ops as gibbs_ops
+from repro.kernels.lda_gibbs.lda_gibbs import gibbs_sweeps_pallas
+from repro.kernels.lda_l2r.lda_l2r import l2r_scores_pallas
+from repro.kernels.lda_sparse.lda_sparse import sparse_sweeps_pallas
+from repro.launch.gossip_sim import build_update_step
+
+N_NODES, V, K, B, L, S, BURNIN = 1024, 50_000, 4, 2, 16, 4, 2
+DOCS = N_NODES * B          # one fused E-step over every node's minibatch
+U = 16                      # unique slots of the sparse layout (<= L)
+EVAL_DOCS, PARTICLES = 64, 2
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _spec(sharding, shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _assert_kernel(fn, kernel: str, *args):
+    """``fn`` compiles for the chip, with ``kernel`` as a Mosaic call."""
+    text = fn.lower(*args).compile().as_text()
+    assert any("tpu_custom_call" in ln and kernel in ln
+               for ln in text.splitlines()), f"{kernel} is not compiled"
+
+
+def test_lda_gibbs_compiles(one_chip):
+    _assert_kernel(
+        jax.jit(lambda bw, m, u, z0: gibbs_sweeps_pallas(
+            bw, m, u, z0, alpha=0.5, n_sweeps=S, burnin=BURNIN,
+            interpret=False)), "lda_gibbs",
+        _spec(one_chip, (DOCS, L, K)), _spec(one_chip, (DOCS, L)),
+        _spec(one_chip, (S, DOCS, L)),
+        _spec(one_chip, (DOCS, L), jnp.int32))
+
+
+def test_lda_sparse_compiles(one_chip):
+    _assert_kernel(
+        jax.jit(lambda bw, c, u, z0: sparse_sweeps_pallas(
+            bw, c, u, z0, alpha=0.5, n_sweeps=S, burnin=BURNIN,
+            interpret=False)), "lda_sparse",
+        _spec(one_chip, (DOCS, U, K)), _spec(one_chip, (DOCS, U)),
+        _spec(one_chip, (S, DOCS, U)),
+        _spec(one_chip, (DOCS, U), jnp.int32))
+
+
+@pytest.mark.parametrize("count_weighted", [False, True])
+def test_lda_l2r_compiles(one_chip, count_weighted):
+    _assert_kernel(
+        jax.jit(lambda kd, bw, w, a: l2r_scores_pallas(
+            kd, bw, w, a, n_particles=PARTICLES,
+            count_weighted=count_weighted, interpret=False)), "lda_l2r",
+        _spec(one_chip, (EVAL_DOCS, 2), jnp.uint32),
+        _spec(one_chip, (EVAL_DOCS, L, K)), _spec(one_chip, (EVAL_DOCS, L)),
+        _spec(one_chip, (1, 1)))
+
+
+@pytest.mark.parametrize("v", [V, 1000, 100])
+def test_gossip_mix_compiles(one_chip, v):
+    _assert_kernel(
+        jax.jit(lambda st, p: mix_matching(st, p, interpret=False)),
+        "gossip_mix",
+        _spec(one_chip, (N_NODES, K, v)),
+        _spec(one_chip, (N_NODES,), jnp.int32))
+
+
+@pytest.mark.parametrize("grid", [False, True], ids=["4x1", "2x2"])
+def test_mesh_update_step_compiles(topo, one_chip, monkeypatch, grid):
+    """The mesh launcher's local update, lda_gibbs inside shard_map, for
+    four chips as a 1-D node mesh and as the node x vocab grid."""
+    # the E-step backend asks the platform, which is the CPU here
+    monkeypatch.setattr(gibbs_ops, "resolve_interpret", lambda _: False)
+    devices = np.asarray(topo.devices)
+    vocab_axis = "vocab" if grid else None
+    mesh = (Mesh(devices.reshape(2, 2), ("data", "vocab")) if grid
+            else Mesh(devices, ("data",)))
+    lda = LDAConfig(n_topics=K, vocab_size=V, alpha=0.5, doc_len_max=L,
+                    n_gibbs=S, n_gibbs_burnin=BURNIN)
+    step = build_update_step(lda, B, mesh, vocab_axis=vocab_axis,
+                             estep_backend="pallas")
+    node = NamedSharding(mesh, P("data"))
+    _assert_kernel(
+        step, "lda_gibbs",
+        _spec(NamedSharding(mesh, P("data", None, vocab_axis)),
+              (N_NODES, K, V)),
+        _spec(node, (N_NODES,), jnp.int32),
+        _spec(NamedSharding(mesh, P()), (), jax.random.key(0).dtype),
+        _spec(node, (N_NODES, 8, L), jnp.int32),
+        _spec(node, (N_NODES, 8, L), jnp.bool_),
+        _spec(node, (N_NODES,), jnp.bool_))
