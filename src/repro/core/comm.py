@@ -410,9 +410,10 @@ class MeshComm:
             node = self._node_spec()
 
             def local_mix(stats, src, active):
-                mixed = 0.5 * (stats + stats[src])
-                keep = active.reshape((-1,) + (1,) * (stats.ndim - 1))
-                return jnp.where(keep, mixed, stats)
+                with jax.named_scope("deleda.mix"):
+                    mixed = 0.5 * (stats + stats[src])
+                    keep = active.reshape((-1,) + (1,) * (stats.ndim - 1))
+                    return jnp.where(keep, mixed, stats)
 
             stats_spec = self._stats_spec(ndim)
             fn = jax.jit(jax.shard_map(
@@ -429,10 +430,11 @@ class MeshComm:
             perm_list = list(perm)
 
             def exchange(stats, src, active):
-                other = jax.lax.ppermute(stats, axis, perm_list)
-                mixed = 0.5 * (stats + other[src])
-                keep = active.reshape((-1,) + (1,) * (stats.ndim - 1))
-                return jnp.where(keep, mixed, stats)
+                with jax.named_scope("deleda.mix"):
+                    other = jax.lax.ppermute(stats, axis, perm_list)
+                    mixed = 0.5 * (stats + other[src])
+                    keep = active.reshape((-1,) + (1,) * (stats.ndim - 1))
+                    return jnp.where(keep, mixed, stats)
 
             stats_spec = self._stats_spec(ndim)
             fn = jax.jit(jax.shard_map(

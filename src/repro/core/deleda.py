@@ -370,35 +370,38 @@ def train_steps(config: DeledaConfig, state: TrainState, words: jax.Array,
         and their 1-pair matching views bit-identical, and that keeps this
         fused [A*B, L] batch bit-identical to per-node E-step calls.
         """
-        bw, bm = jax.vmap(
-            lambda i, w_, m_: sample_batch(jax.random.fold_in(k_sel, i),
-                                           w_, m_))(
-            ids, words_rows, mask_rows)                   # [A, B, L]
-        keys = jax.vmap(lambda i: jax.random.fold_in(k_gibbs, i))(ids)
-        # blocked-stats E-step: beta columns are gathered straight from
-        # the (possibly vocab-sharded) statistic — no dense [A, K, V]
-        # eta_star temporary; bitwise-equal to the materialized path.
-        # In the unique layout bw/bm hold (word_id, count) rows instead
-        # of (token, mask) rows and the sweeps are count-weighted.
-        if unique:
-            stats_hat = estep_mod.estep_batch_from_stats_unique(
-                estep, config.lda, keys, bw, bm, stats_rows)
-        else:
-            stats_hat = estep_mod.estep_batch_from_stats(
-                estep, config.lda, keys, bw, bm, stats_rows)  # [A, K, V]
-        stats_hat = stats_hat.reshape(stats_rows.shape)
-        t = steps_rows + 1
-        rho = (rho_fn(t) * corr_rows).astype(stats_rows.dtype)
-        rho = jnp.clip(rho, 0.0, 1.0)
-        if decay_fn is not None:
-            # Robbins–Monro forgetting (lifecycle layer): discount the
-            # carried statistic by d_t before blending — streamed
-            # minibatches supersede stale ones (oem.forgetting_rho)
-            decay = jnp.clip(decay_fn(t), 0.0, 1.0).astype(
-                stats_rows.dtype)
-            rho = forgetting_rho(rho, decay)
-        rho = bcast(rho, stats_rows.ndim)
-        return (1.0 - rho) * stats_rows + rho * stats_hat, t
+        with jax.named_scope("deleda.estep"):
+            bw, bm = jax.vmap(
+                lambda i, w_, m_: sample_batch(jax.random.fold_in(k_sel, i),
+                                               w_, m_))(
+                ids, words_rows, mask_rows)               # [A, B, L]
+            keys = jax.vmap(lambda i: jax.random.fold_in(k_gibbs, i))(ids)
+            # blocked-stats E-step: beta columns are gathered straight
+            # from the (possibly vocab-sharded) statistic — no dense
+            # [A, K, V] eta_star temporary; bitwise-equal to the
+            # materialized path. In the unique layout bw/bm hold
+            # (word_id, count) rows instead of (token, mask) rows and the
+            # sweeps are count-weighted.
+            if unique:
+                stats_hat = estep_mod.estep_batch_from_stats_unique(
+                    estep, config.lda, keys, bw, bm, stats_rows)
+            else:
+                stats_hat = estep_mod.estep_batch_from_stats(
+                    estep, config.lda, keys, bw, bm, stats_rows)  # [A,K,V]
+            stats_hat = stats_hat.reshape(stats_rows.shape)
+        with jax.named_scope("deleda.blend"):
+            t = steps_rows + 1
+            rho = (rho_fn(t) * corr_rows).astype(stats_rows.dtype)
+            rho = jnp.clip(rho, 0.0, 1.0)
+            if decay_fn is not None:
+                # Robbins–Monro forgetting (lifecycle layer): discount the
+                # carried statistic by d_t before blending — streamed
+                # minibatches supersede stale ones (oem.forgetting_rho)
+                decay = jnp.clip(decay_fn(t), 0.0, 1.0).astype(
+                    stats_rows.dtype)
+                rho = forgetting_rho(rho, decay)
+            rho = bcast(rho, stats_rows.ndim)
+            return (1.0 - rho) * stats_rows + rho * stats_hat, t
 
     def iteration(carry, inp):
         stats, steps = carry
@@ -410,49 +413,57 @@ def train_steps(config: DeledaConfig, state: TrainState, words: jax.Array,
 
         if kind == "edge":
             i, j = event[0], event[1]
-            # an event is live unless it is the (i, i) drop sentinel or an
-            # endpoint is down this step (churn) / not a member (lifecycle)
-            ev_live = (i != j) & al[i] & al[j]
-            # -- gossip averaging step (Algorithm 1, line 4); a dead event
-            # mixes (i, i), which every backend applies as the identity
-            j_eff = jnp.where(ev_live, j, i)
-            stats = comm.mix_edge(stats, i, j_eff)
+            with jax.named_scope("deleda.mix"):
+                # an event is live unless it is the (i, i) drop sentinel or
+                # an endpoint is down this step (churn) / not a member
+                # (lifecycle)
+                ev_live = (i != j) & al[i] & al[j]
+                # -- gossip averaging step (Algorithm 1, line 4); a dead
+                # event mixes (i, i), which every backend applies as the
+                # identity
+                j_eff = jnp.where(ev_live, j, i)
+                stats = comm.mix_edge(stats, i, j_eff)
             if config.mode == "sync":
                 # -- every live node updates locally (Algorithm 1, l. 5-7)
                 new_stats, new_steps = update_rows(
                     stats, steps, node_ids, k_sel, k_gibbs, words, mask,
                     corr_row)
-                stats = jnp.where(bcast(al, stats.ndim), new_stats, stats)
-                steps = jnp.where(al, new_steps, steps)
+                with jax.named_scope("deleda.blend"):
+                    stats = jnp.where(bcast(al, stats.ndim), new_stats,
+                                      stats)
+                    steps = jnp.where(al, new_steps, steps)
             else:
                 # -- only the two awake nodes update (async variant)
                 active = jnp.stack([i, j])                    # [2]
                 up_stats, up_steps = update_rows(
                     stats[active], steps[active], active, k_sel, k_gibbs,
                     words[active], mask[active], corr_row[active])
-                upd = jnp.stack([ev_live, ev_live])
-                up_stats = jnp.where(bcast(upd, up_stats.ndim), up_stats,
-                                     stats[active])
-                up_steps = jnp.where(upd, up_steps, steps[active])
-                stats = stats.at[active].set(up_stats)
-                steps = steps.at[active].set(up_steps)
+                with jax.named_scope("deleda.blend"):
+                    upd = jnp.stack([ev_live, ev_live])
+                    up_stats = jnp.where(bcast(upd, up_stats.ndim),
+                                         up_stats, stats[active])
+                    up_steps = jnp.where(upd, up_steps, steps[active])
+                    stats = stats.at[active].set(up_stats)
+                    steps = steps.at[active].set(up_steps)
         else:
             partners = event                                  # [n]
-            # liveness guard: a pair with a down or non-member endpoint
-            # mixes as self-self (symmetric in (i, p[i]), so the row
-            # stays an involution)
-            partners = jnp.where(al & al[partners], partners, node_ids)
-            stats = comm.mix_matching(stats, partners)
+            with jax.named_scope("deleda.mix"):
+                # liveness guard: a pair with a down or non-member endpoint
+                # mixes as self-self (symmetric in (i, p[i]), so the row
+                # stays an involution)
+                partners = jnp.where(al & al[partners], partners, node_ids)
+                stats = comm.mix_matching(stats, partners)
             new_stats, new_steps = update_rows(stats, steps, node_ids,
                                                k_sel, k_gibbs, words,
                                                mask, corr_row)
-            if config.mode == "sync":
-                upd = al                                      # [n]
-            else:
-                # matched live nodes are the awake ones this round
-                upd = (partners != node_ids) & al
-            stats = jnp.where(bcast(upd, stats.ndim), new_stats, stats)
-            steps = jnp.where(upd, new_steps, steps)
+            with jax.named_scope("deleda.blend"):
+                if config.mode == "sync":
+                    upd = al                                  # [n]
+                else:
+                    # matched live nodes are the awake ones this round
+                    upd = (partners != node_ids) & al
+                stats = jnp.where(bcast(upd, stats.ndim), new_stats, stats)
+                steps = jnp.where(upd, new_steps, steps)
 
         return (stats, steps), None
 
@@ -460,7 +471,8 @@ def train_steps(config: DeledaConfig, state: TrainState, words: jax.Array,
         xs, mem = inp
         carry, _ = jax.lax.scan(iteration, carry, xs)
         stats, _steps = carry
-        return carry, (stats, gossip.consensus_distance(stats, mem))
+        with jax.named_scope("deleda.record"):
+            return carry, (stats, gossip.consensus_distance(stats, mem))
 
     n_rec = t_seg // record_every
     t_idx = state.t + jnp.arange(t_seg, dtype=jnp.int32)      # absolute

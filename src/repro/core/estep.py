@@ -331,15 +331,16 @@ def stats_from_per_pos(words: jax.Array, per_pos: jax.Array,
     full-batch-size normalization is kept (correct only for unpadded
     batches).
     """
-    b, _l, k = per_pos.shape
-    flat_w = words.reshape(-1)
-    flat_p = per_pos.reshape(-1, k)
-    stats = jnp.zeros((k, vocab_size), per_pos.dtype)
-    if maskf is None:
-        denom = jnp.asarray(b, per_pos.dtype)
-    else:
-        denom = count_nonempty(maskf).astype(per_pos.dtype)
-    return stats.at[:, flat_w].add(flat_p.T) / denom
+    with jax.named_scope("estep.scatter"):
+        b, _l, k = per_pos.shape
+        flat_w = words.reshape(-1)
+        flat_p = per_pos.reshape(-1, k)
+        stats = jnp.zeros((k, vocab_size), per_pos.dtype)
+        if maskf is None:
+            denom = jnp.asarray(b, per_pos.dtype)
+        else:
+            denom = count_nonempty(maskf).astype(per_pos.dtype)
+        return stats.at[:, flat_w].add(flat_p.T) / denom
 
 
 def beta_w_from_stats(stats: jax.Array, words: jax.Array, tau: float,
@@ -364,12 +365,13 @@ def beta_w_from_stats(stats: jax.Array, words: jax.Array, tau: float,
     — the shard axis is a pure layout axis); words: [B, L] int32.
     Returns beta_w [B, L, K].
     """
-    k = stats.shape[0]
-    stats = stats.reshape(k, -1)
-    if denom is None:
-        denom = (stats + tau).sum(-1)                     # [K]
-    cols = jnp.moveaxis(stats[:, words], 0, -1)           # [B, L, K]
-    return (cols + tau) / denom
+    with jax.named_scope("estep.gather"):
+        k = stats.shape[0]
+        stats = stats.reshape(k, -1)
+        if denom is None:
+            denom = (stats + tau).sum(-1)                 # [K]
+        cols = jnp.moveaxis(stats[:, words], 0, -1)       # [B, L, K]
+        return (cols + tau) / denom
 
 
 def theta_slab(key: jax.Array, doc_ids: jax.Array, beta_w: jax.Array,
@@ -672,18 +674,20 @@ def fused_sweeps(backend: _EStepBase, config: LDAConfig, keys: jax.Array,
     launcher's node x vocab grid (which psum-assembles beta_w across the
     vocab axis before calling this).
     """
-    a, b, l, k = beta_w.shape
-    s = config.n_gibbs
-    uniforms, z0 = jax.vmap(
-        lambda kk: draw_gibbs_randoms(config, kk, b, l, beta_w.dtype))(keys)
-    per_pos, _z, _ndk = backend.sweeps(
-        beta_w.reshape(a * b, l, k),
-        maskf.reshape(a * b, l),
-        jnp.moveaxis(uniforms, 0, 1).reshape(s, a * b, l),
-        z0.reshape(a * b, l),
-        alpha=config.alpha, n_sweeps=s, burnin=config.n_gibbs_burnin,
-        rao_blackwell=rao_blackwell)
-    return per_pos.reshape(a, b, l, k)
+    with jax.named_scope("estep.sweeps"):
+        a, b, l, k = beta_w.shape
+        s = config.n_gibbs
+        uniforms, z0 = jax.vmap(
+            lambda kk: draw_gibbs_randoms(config, kk, b, l,
+                                          beta_w.dtype))(keys)
+        per_pos, _z, _ndk = backend.sweeps(
+            beta_w.reshape(a * b, l, k),
+            maskf.reshape(a * b, l),
+            jnp.moveaxis(uniforms, 0, 1).reshape(s, a * b, l),
+            z0.reshape(a * b, l),
+            alpha=config.alpha, n_sweeps=s, burnin=config.n_gibbs_burnin,
+            rao_blackwell=rao_blackwell)
+        return per_pos.reshape(a, b, l, k)
 
 
 def estep_batch(backend: _EStepBase, config: LDAConfig, keys: jax.Array,
@@ -751,19 +755,20 @@ def fused_sweeps_sparse(backend: _SparseEStepBase, config: LDAConfig,
     elementwise or a last-axis reduction, so fusing nodes changes no
     bits.
     """
-    a, b, u_dim, k = beta_w.shape
-    s = config.n_gibbs
-    uniforms, z0 = jax.vmap(
-        lambda kk: draw_gibbs_randoms(config, kk, b, u_dim,
-                                      beta_w.dtype))(keys)
-    per_unique, _m, _ndk = backend.sweeps(
-        beta_w.reshape(a * b, u_dim, k),
-        countf.reshape(a * b, u_dim),
-        jnp.moveaxis(uniforms, 0, 1).reshape(s, a * b, u_dim),
-        z0.reshape(a * b, u_dim),
-        alpha=config.alpha, n_sweeps=s, burnin=config.n_gibbs_burnin,
-        rao_blackwell=rao_blackwell)
-    return per_unique.reshape(a, b, u_dim, k)
+    with jax.named_scope("estep.sweeps"):
+        a, b, u_dim, k = beta_w.shape
+        s = config.n_gibbs
+        uniforms, z0 = jax.vmap(
+            lambda kk: draw_gibbs_randoms(config, kk, b, u_dim,
+                                          beta_w.dtype))(keys)
+        per_unique, _m, _ndk = backend.sweeps(
+            beta_w.reshape(a * b, u_dim, k),
+            countf.reshape(a * b, u_dim),
+            jnp.moveaxis(uniforms, 0, 1).reshape(s, a * b, u_dim),
+            z0.reshape(a * b, u_dim),
+            alpha=config.alpha, n_sweeps=s, burnin=config.n_gibbs_burnin,
+            rao_blackwell=rao_blackwell)
+        return per_unique.reshape(a, b, u_dim, k)
 
 
 def estep_batch_from_stats_unique(backend: _SparseEStepBase,

@@ -81,64 +81,66 @@ def build_update_step(lda: LDAConfig, batch_size: int, mesh,
         # here is the O(B*L*K) beta-column psum over the vocab axis of a
         # 2-D grid. All of the device's nodes run as ONE fused
         # [n_local*B, L] E-step call; al [n_local] masks down nodes.
-        n_local = stats.shape[0]
-        dev = jax.lax.axis_index("data")
-        key = jax.random.fold_in(key, dev)   # per-device stream (varying
-                                             # over nodes, NOT over vocab
-                                             # shards of the same nodes)
-        ks = jax.vmap(jax.random.split)(jax.random.split(key, n_local))
-        k_sel, k_gibbs = ks[:, 0], ks[:, 1]  # [n_local] each
+        with jax.named_scope("deleda.estep"):
+            n_local = stats.shape[0]
+            dev = jax.lax.axis_index("data")
+            key = jax.random.fold_in(key, dev)   # per-device stream (varying
+                                                 # over nodes, NOT over vocab
+                                                 # shards of the same nodes)
+            ks = jax.vmap(jax.random.split)(jax.random.split(key, n_local))
+            k_sel, k_gibbs = ks[:, 0], ks[:, 1]  # [n_local] each
 
-        def select(k, node_words, node_mask):
-            idx = jax.random.randint(k, (batch_size,), 0,
-                                     node_words.shape[0])
-            return node_words[idx], node_mask[idx]
+            def select(k, node_words, node_mask):
+                idx = jax.random.randint(k, (batch_size,), 0,
+                                         node_words.shape[0])
+                return node_words[idx], node_mask[idx]
 
-        bw, bm = jax.vmap(select)(k_sel, w, m)          # [n_local, B, L]
-        maskf = bm.astype(stats.dtype)
-        if vocab_axis:
-            # -- blocked beta assembly across the vocab axis: each shard
-            # contributes (stats[:, w] + tau) for ITS words, one psum of
-            # the [n_local, B, L, K] partials builds the full likelihood
-            # rows — the dense [K, V] topic matrix never exists anywhere
-            v_local = stats.shape[-1]
-            v0 = jax.lax.axis_index(vocab_axis) * v_local
-            denom = jax.lax.psum((stats + lda.tau).sum(-1),
-                                 vocab_axis)            # [n_local, K]
-            lw = bw - v0                                # local word ids
-            in_shard = (lw >= 0) & (lw < v_local)
-            lw = jnp.clip(lw, 0, v_local - 1)
-            cols = jax.vmap(
-                lambda st, ww: jnp.moveaxis(st[:, ww], 0, -1))(stats, lw)
-            part = jnp.where(in_shard[..., None], cols + lda.tau, 0.0)
-            beta_w = jax.lax.psum(part, vocab_axis) / denom[:, None, None]
-            scatter_w, v_scatter = lw, v_local
-            per_pos_mask = in_shard
-        else:
-            beta_w = jax.vmap(
-                lambda st, ww: estep_mod.beta_w_from_stats(
-                    st, ww, lda.tau))(stats, bw)
-            scatter_w, v_scatter = bw, lda.vocab_size
-            per_pos_mask = None
-        if unique:
-            # count-weighted sweeps over the U unique slots; the rows come
-            # back with their token mass folded in, so the shared scatter
-            # below needs no count reweighting (maskf IS the counts here)
-            per_pos = estep_mod.fused_sweeps_sparse(estep, lda, k_gibbs,
-                                                    beta_w, maskf)
-        else:
-            per_pos = estep_mod.fused_sweeps(estep, lda, k_gibbs, beta_w,
-                                             maskf)     # [n_local,B,L,K]
-        if per_pos_mask is not None:
-            # each vocab shard scatters only ITS words' contributions
-            per_pos = jnp.where(per_pos_mask[..., None], per_pos, 0.0)
-        stats_hat = jax.vmap(
-            lambda ww, pp, mm: estep_mod.stats_from_per_pos(
-                ww, pp, v_scatter, mm))(scatter_w, per_pos, maskf)
-        rho = rho_fn(steps + 1).astype(stats.dtype)[:, None, None]
-        new_stats = (1 - rho) * stats + rho * stats_hat
-        return (jnp.where(al[:, None, None], new_stats, stats),
-                jnp.where(al, steps + 1, steps))
+            bw, bm = jax.vmap(select)(k_sel, w, m)          # [n_local, B, L]
+            maskf = bm.astype(stats.dtype)
+            if vocab_axis:
+                # -- blocked beta assembly across the vocab axis: each shard
+                # contributes (stats[:, w] + tau) for ITS words, one psum of
+                # the [n_local, B, L, K] partials builds the full likelihood
+                # rows — the dense [K, V] topic matrix never exists anywhere
+                v_local = stats.shape[-1]
+                v0 = jax.lax.axis_index(vocab_axis) * v_local
+                denom = jax.lax.psum((stats + lda.tau).sum(-1),
+                                     vocab_axis)            # [n_local, K]
+                lw = bw - v0                                # local word ids
+                in_shard = (lw >= 0) & (lw < v_local)
+                lw = jnp.clip(lw, 0, v_local - 1)
+                cols = jax.vmap(
+                    lambda st, ww: jnp.moveaxis(st[:, ww], 0, -1))(stats, lw)
+                part = jnp.where(in_shard[..., None], cols + lda.tau, 0.0)
+                beta_w = jax.lax.psum(part, vocab_axis) / denom[:, None, None]
+                scatter_w, v_scatter = lw, v_local
+                per_pos_mask = in_shard
+            else:
+                beta_w = jax.vmap(
+                    lambda st, ww: estep_mod.beta_w_from_stats(
+                        st, ww, lda.tau))(stats, bw)
+                scatter_w, v_scatter = bw, lda.vocab_size
+                per_pos_mask = None
+            if unique:
+                # count-weighted sweeps over the U unique slots; the rows come
+                # back with their token mass folded in, so the shared scatter
+                # below needs no count reweighting (maskf IS the counts here)
+                per_pos = estep_mod.fused_sweeps_sparse(estep, lda, k_gibbs,
+                                                        beta_w, maskf)
+            else:
+                per_pos = estep_mod.fused_sweeps(estep, lda, k_gibbs, beta_w,
+                                                 maskf)     # [n_local,B,L,K]
+            if per_pos_mask is not None:
+                # each vocab shard scatters only ITS words' contributions
+                per_pos = jnp.where(per_pos_mask[..., None], per_pos, 0.0)
+            stats_hat = jax.vmap(
+                lambda ww, pp, mm: estep_mod.stats_from_per_pos(
+                    ww, pp, v_scatter, mm))(scatter_w, per_pos, maskf)
+        with jax.named_scope("deleda.blend"):
+            rho = rho_fn(steps + 1).astype(stats.dtype)[:, None, None]
+            new_stats = (1 - rho) * stats + rho * stats_hat
+            return (jnp.where(al[:, None, None], new_stats, stats),
+                    jnp.where(al, steps + 1, steps))
 
     shmap = jax.shard_map(
         update_fn, mesh=mesh,

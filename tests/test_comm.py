@@ -278,6 +278,24 @@ MESH_SCRIPT = textwrap.dedent("""
         max_counts=(("collective-permute", 1),)))
     assert report.ok, report.summary()
     assert report.inventory == {"collective-permute": 1}, report.inventory
+
+    # the round's named scopes reach the shard-mapped programs' HLO
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.core.lda import LDAConfig
+    from repro.launch.gossip_sim import build_update_step
+    lda = LDAConfig(n_topics=4, vocab_size=64, doc_len_max=8, n_gibbs=2,
+                    n_gibbs_burnin=1)
+    node = NamedSharding(mesh.mesh, P("data"))
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=node)
+    step_text = build_update_step(lda, 2, mesh.mesh).lower(
+        sds((8, 4, 64), jnp.float32), sds((8,), jnp.int32),
+        jax.random.key(0), sds((8, 3, 8), jnp.int32),
+        sds((8, 3, 8), jnp.bool_), sds((8,), jnp.bool_)).compile().as_text()
+    for scope in ("deleda.estep", "estep.gather", "estep.sweeps",
+                  "estep.scatter", "deleda.blend"):
+        assert scope in step_text, scope
+    assert "deleda.mix" in compiled.as_text()
     print("COMM_MESH_OK")
 """)
 
