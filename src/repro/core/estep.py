@@ -63,11 +63,14 @@ import jax.numpy as jnp
 
 from repro.core.lda import LDAConfig
 
+LANES = 128     # the TPU vector register's minor (lane) dimension
+
 __all__ = [
     "GibbsResult", "SparseGibbsResult", "sample_keepdims",
     "sample_from_unnormalized", "mean_seq", "gibbs_position_update",
     "gibbs_sweeps_dense", "gibbs_sweeps_sparse", "draw_gibbs_randoms",
-    "stats_from_per_pos", "stats_from_unique", "dense_to_unique",
+    "stats_from_per_pos", "stats_per_node", "stats_from_unique",
+    "dense_to_unique",
     "unique_view",
     "count_nonempty", "beta_w_from_stats", "theta_slab", "DenseEStep",
     "PallasEStep",
@@ -341,6 +344,26 @@ def stats_from_per_pos(words: jax.Array, per_pos: jax.Array,
         else:
             denom = count_nonempty(maskf).astype(per_pos.dtype)
         return stats.at[:, flat_w].add(flat_p.T) / denom
+
+
+def stats_per_node(words: jax.Array, per_pos: jax.Array, vocab_size: int,
+                   maskf: jax.Array) -> jax.Array:
+    """Node-batched :func:`stats_from_per_pos`: [A, K, V] statistics.
+
+    words [A, B, L] (or unique ids [A, B, U]), per_pos [A, B, L, K],
+    maskf [A, B, L] (or float counts): bitwise-equal to vmapping
+    :func:`stats_from_per_pos` over the A nodes. The vmapped scatter is one
+    [K, A*V] buffer; where V is not a multiple of the TPU's 128-lane tile,
+    unflattening it into [A, K, V] is no bitcast, and XLA relayouts it in
+    two loops (one per topic row, one per chunk of nodes) that cost several
+    times the scatter itself. So the buffer's vocabulary axis is padded to
+    the next multiple of 128, which no update touches, and sliced back to V.
+    """
+    width = -(-vocab_size // LANES) * LANES
+    stats = jax.vmap(lambda w, p, m: stats_from_per_pos(w, p, width, m))(
+        words, per_pos, maskf)
+    with jax.named_scope("estep.scatter"):
+        return stats[..., :vocab_size]
 
 
 def beta_w_from_stats(stats: jax.Array, words: jax.Array, tau: float,
@@ -711,9 +734,7 @@ def estep_batch(backend: _EStepBase, config: LDAConfig, keys: jax.Array,
     maskf = mask.astype(beta.dtype)
     per_pos = fused_sweeps(backend, config, keys, beta_w, maskf,
                            rao_blackwell=rao_blackwell)
-    return jax.vmap(
-        lambda w, p, m: stats_from_per_pos(w, p, config.vocab_size, m))(
-            words, per_pos, maskf)
+    return stats_per_node(words, per_pos, config.vocab_size, maskf)
 
 
 def estep_batch_from_stats(backend: _EStepBase, config: LDAConfig,
@@ -737,9 +758,7 @@ def estep_batch_from_stats(backend: _EStepBase, config: LDAConfig,
     maskf = mask.astype(beta_w.dtype)
     per_pos = fused_sweeps(backend, config, keys, beta_w, maskf,
                            rao_blackwell=rao_blackwell)
-    return jax.vmap(
-        lambda w, p, m: stats_from_per_pos(w, p, config.vocab_size, m))(
-            words, per_pos, maskf)
+    return stats_per_node(words, per_pos, config.vocab_size, maskf)
 
 
 def fused_sweeps_sparse(backend: _SparseEStepBase, config: LDAConfig,
@@ -790,6 +809,4 @@ def estep_batch_from_stats_unique(backend: _SparseEStepBase,
     countf = counts.astype(beta_w.dtype)
     per_unique = fused_sweeps_sparse(backend, config, keys, beta_w,
                                      countf, rao_blackwell=rao_blackwell)
-    return jax.vmap(
-        lambda w, p, c: stats_from_unique(w, p, config.vocab_size, c))(
-            uw, per_unique, countf)
+    return stats_per_node(uw, per_unique, config.vocab_size, countf)

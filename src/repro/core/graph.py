@@ -180,7 +180,9 @@ def erdos_renyi_graph(n: int, p: float, seed: int = 0) -> Graph:
         mask = rng.random((n, n)) < p
         edges = [(i, j) for i in range(n) for j in range(i + 1, n)
                  if mask[i, j]]
-        g = Graph(n, np.array(edges, np.int32), name=f"er-{n}-p{p}")
+        # an empty draw is [0, 2], so that it is retried, not refused
+        g = Graph(n, np.array(edges, np.int32).reshape(-1, 2),
+                  name=f"er-{n}-p{p}")
         if g.n_edges and g.is_connected():
             return g
     raise RuntimeError("failed to build a connected Erdos-Renyi graph")

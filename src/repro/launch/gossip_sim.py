@@ -133,9 +133,8 @@ def build_update_step(lda: LDAConfig, batch_size: int, mesh,
             if per_pos_mask is not None:
                 # each vocab shard scatters only ITS words' contributions
                 per_pos = jnp.where(per_pos_mask[..., None], per_pos, 0.0)
-            stats_hat = jax.vmap(
-                lambda ww, pp, mm: estep_mod.stats_from_per_pos(
-                    ww, pp, v_scatter, mm))(scatter_w, per_pos, maskf)
+            stats_hat = estep_mod.stats_per_node(scatter_w, per_pos,
+                                                 v_scatter, maskf)
         with jax.named_scope("deleda.blend"):
             rho = rho_fn(steps + 1).astype(stats.dtype)[:, None, None]
             new_stats = (1 - rho) * stats + rho * stats_hat

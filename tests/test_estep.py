@@ -7,6 +7,8 @@ and the fused multi-node batch path (`estep_batch`) is bit-identical to
 vmapping the single-node E-step with the same fold_in key streams.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -204,6 +206,76 @@ def test_fused_batch_independent_of_batch_mates(node_batch):
     pair = estep.estep_batch(backend, CFG, keys[1:3], words[1:3],
                              mask[1:3], beta[1:3])
     np.testing.assert_array_equal(np.asarray(full[1:3]), np.asarray(pair))
+
+
+SCATTER_CASES = {      # case -> (nodes, vocabulary size)
+    "v1000": (8, 1000),
+    "v1003": (8, 1003),
+    "v1024": (8, 1024),
+    "v1003-two-nodes": (2, 1003),       # the asynchronous mode's batch
+    "empty-docs": (8, 1003),
+    "unique": (8, 1003),
+    "vocab-sharded": (8, 1002),
+}
+
+
+@pytest.mark.parametrize("case", list(SCATTER_CASES))
+def test_stats_per_node_bitwise_matches_vmap(case):
+    """The node-batched scatter, built in a lane-padded buffer, gives the
+    bits of vmapping the single-node scatter: the same updates summed in
+    the same order, then the same division."""
+    a, v = SCATTER_CASES[case]
+    b, l, k = 4, 16, CFG.n_topics
+    kw, kc, kp, km = jax.random.split(jax.random.key(21), 4)
+    # half the positions share the last five words: repeated columns at
+    # the padded edge, summed in update order
+    words = jnp.where(jax.random.bernoulli(kc, 0.5, (a, b, l)),
+                      jax.random.randint(kw, (a, b, l), v - 5, v),
+                      jax.random.randint(kw, (a, b, l), 0, v))
+    mask = jax.random.uniform(km, (a, b, l)) < 0.8
+    if case == "empty-docs":
+        mask = mask.at[:, -2:].set(False).at[0].set(False)
+    maskf = mask.astype(jnp.float32)
+    per_pos = jax.random.uniform(kp, (a, b, l, k)) * maskf[..., None]
+
+    def ref(w, p, m):
+        return jax.vmap(
+            lambda ww, pp, mm: estep.stats_from_per_pos(ww, pp, v, mm))(
+                w, p, m)
+
+    def new(w, p, m):
+        return estep.stats_per_node(w, p, v, m)
+
+    if case == "unique":
+        uw, counts = estep.dense_to_unique(words, mask)
+        countf = counts.astype(jnp.float32)
+        words, per_pos, maskf = uw, per_pos * countf[..., None], countf
+    if case == "vocab-sharded":
+        # through the E-step entry the round calls, carrying [A, K, 2, V/2]
+        cfg = dataclasses.replace(CFG, vocab_size=v)
+        stats = jax.random.uniform(jax.random.key(22), (a, k, 2, v // 2))
+        keys = jax.vmap(lambda i: jax.random.fold_in(jax.random.key(23),
+                                                     i))(jnp.arange(a))
+        backend = estep.get_estep("dense")
+
+        def ref_e(st, w, mk):
+            beta_w = jax.vmap(lambda s_, w_: estep.beta_w_from_stats(
+                s_, w_, cfg.tau))(st, w)
+            mf = mk.astype(beta_w.dtype)
+            pp = estep.fused_sweeps(backend, cfg, keys, beta_w, mf)
+            return ref(w, pp, mf).reshape(st.shape)
+
+        def new_e(st, w, mk):
+            return estep.estep_batch_from_stats(
+                backend, cfg, keys, w, mk, st).reshape(st.shape)
+
+        got = jax.jit(new_e)(stats, words, mask)
+        want = jax.jit(ref_e)(stats, words, mask)
+    else:
+        got = jax.jit(new)(words, per_pos, maskf)
+        want = jax.jit(ref)(words, per_pos, maskf)
+    assert got.shape == want.shape
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 # ---------------------------------------------------------------------------

@@ -52,6 +52,12 @@ def test_graph_validation():
         G.Graph(3, np.array([[0, 1], [1, 0]]))  # duplicate
 
 
+def test_erdos_renyi_retries_an_empty_draw():
+    # seed 61's first 4-node draw has no edge at p=0.5
+    g = G.erdos_renyi_graph(4, 0.5, seed=61)
+    assert g.n_edges > 0 and g.is_connected()
+
+
 def test_is_connected_large_and_disconnected():
     """BFS reachability at n=500 (the old matrix_power overflowed float64
     here) plus explicit negative cases."""

@@ -4,8 +4,9 @@ Interpret mode cannot see tiling, layout or VMEM refusals; the TPU
 compiler can, and it compiles for a chip that is described rather than
 attached. Shapes are those of ``chip_smoke.py`` (the ``big`` regime of
 ``benchmarks/scale_bench.py``: n=1024 nodes, V=50,000, K=4, 2 docs per
-node per step, L=16, 4 Gibbs sweeps). Nothing runs, so these tests say
-nothing about results or times.
+node per step, L=16, 4 Gibbs sweeps); the statistic scatter's test takes
+a training cell's. Nothing runs, so these tests say nothing about results
+or times.
 
 The topology is described inside a module fixture, never at import: only
 the worker that runs this file may load the TPU compiler library.
@@ -20,6 +21,7 @@ import pytest
 from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
+from repro.core import estep
 from repro.core.lda import LDAConfig
 from repro.kernels.gossip_mix.ops import mix_matching
 from repro.kernels.lda_gibbs import ops as gibbs_ops
@@ -132,3 +134,15 @@ def test_mesh_update_step_compiles(topo, one_chip, monkeypatch, grid):
         _spec(node, (N_NODES, 8, L), jnp.int32),
         _spec(node, (N_NODES, 8, L), jnp.bool_),
         _spec(node, (N_NODES,), jnp.bool_))
+
+
+def test_node_batched_scatter_has_no_relayout_loop(one_chip):
+    """The node-batched statistic scatter at PubMed's vocabulary: XLA
+    unflattens the lane-padded [K, A*V] buffer into [A, K, V] without the
+    relayout loops (one per topic row, one per chunk of nodes) that an
+    unaligned V costs."""
+    a, b, l, k, v = 8, 20, 256, 100, 141_043
+    text = jax.jit(lambda w, p, m: estep.stats_per_node(w, p, v, m)).lower(
+        _spec(one_chip, (a, b, l), jnp.int32), _spec(one_chip, (a, b, l, k)),
+        _spec(one_chip, (a, b, l))).compile().as_text()
+    assert "while(" not in text
