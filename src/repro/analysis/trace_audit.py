@@ -13,10 +13,10 @@ module turns all three into machine-checked invariants:
 - :func:`audit_hlo_text` / :func:`audit_compiled` — run one spec against
   one compiled module and report violations + the collective inventory.
 - :data:`ENTRY_POINTS` / :func:`collect_inventories` — the registry of
-  audited repo entry points (the `run_deleda` scan, MeshComm's gossip
-  pass fns on 1-D and 2-D grids, the fused eval chunk, the serving
-  slabs, the mesh local-update step) and the golden-pinning helpers
-  (`tests/golden_collectives.json`).
+  audited repo entry points (the `run_deleda` scan and its node-sharded
+  form, MeshComm's gossip pass fns on 1-D and 2-D grids, the fused eval
+  chunk, the serving slabs, the mesh local-update step) and the
+  golden-pinning helpers (`tests/golden_collectives.json`).
 - :class:`CompileCounter` — the reusable recompile guard generalizing
   the scattered ``_cache_size() == 1`` asserts.
 
@@ -359,6 +359,31 @@ def _build_update_step(grid: tuple[int, int] | None):
     return build
 
 
+def _build_deleda_mesh(n_dev: int):
+    """The node-sharded ``train_steps`` segment on a 1-D mesh of the
+    first ``n_dev`` devices: two nodes a device, two record blocks."""
+    def build():
+        import jax
+        import jax.numpy as jnp
+        import numpy as np
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+        from repro.core import deleda
+        mesh = Mesh(np.asarray(jax.devices()[:n_dev]), ("data",))
+        cfg = deleda.DeledaConfig(lda=_tiny_lda(), mode="sync",
+                                  batch_size=3, comm_backend="mesh",
+                                  mesh=mesh)
+        n, d, t = 2 * n_dev, 6, 4
+        node = NamedSharding(mesh, P("data"))
+        words = jax.device_put(jnp.zeros((n, d, _L), jnp.int32), node)
+        mask = jax.device_put(jnp.ones((n, d, _L), bool), node)
+        state = deleda.init_state(cfg, jax.random.key(0), n)
+        sched = jnp.tile(jnp.arange(n, dtype=jnp.int32) ^ 1, (t, 1))
+        return deleda.train_steps.lower(
+            cfg, state, words, mask, sched, jnp.ones((t, n), jnp.float32),
+            jnp.ones((t, n), bool), record_every=2).compile()
+    return build
+
+
 def _vocab_groups(grid: tuple[int, int]) -> tuple[tuple[int, ...], ...]:
     """Vocab-axis replica groups of a node x vocab grid, in the compiled
     module's logical device coordinates (row-major over the mesh)."""
@@ -421,6 +446,17 @@ ENTRY_POINTS: dict[str, EntryPoint] = {
                       replica_groups=_vocab_groups(_GRID),
                       grouped_kinds=frozenset({"all-reduce"})),
         _build_update_step(_GRID), min_devices=8),
+    # node-sharded training on four devices: the round body's only
+    # collectives are the d - 1 = 3 ppermute passes of the mix, and the
+    # record's two all-reduces (the node sum and the squared norm); no
+    # all-gather of the statistic, no doc-shaped operand
+    "deleda_scan_mesh_1d": EntryPoint(
+        InvariantSpec("deleda_scan_mesh_1d",
+                      allowed_collectives=GOSSIP_ALLOWED | {"all-reduce"},
+                      max_counts=(("collective-permute", 3),
+                                  ("all-reduce", 2)),
+                      doc_len=_L),
+        _build_deleda_mesh(4), min_devices=4),
 }
 
 

@@ -59,7 +59,7 @@ from repro.core.graph import Graph
 __all__ = [
     "GossipSchedule", "Communicator", "DenseSimComm", "PallasSimComm",
     "MeshComm", "get_communicator", "make_grid_mesh", "mesh_round",
-    "SIM_BACKENDS",
+    "SIM_BACKENDS", "device_passes", "mix_matching_sharded",
 ]
 
 # One gossip round over a mesh axis, usable *inside* shard_map (this is the
@@ -475,6 +475,67 @@ class MeshComm:
                        // self.n_vocab_shards)
         return sum(len(perm) * self.n_vocab_shards * shard_block
                    for perm, _, _ in passes)
+
+
+def device_passes(n_dev: int) -> list[tuple[tuple[int, int], ...]]:
+    """The static 1-factorization of ``n_dev`` devices into ppermute passes.
+
+    Each pass is a (src, dst) device permutation made of disjoint pairs,
+    and every pair of devices meets in exactly one pass: n_dev - 1 passes
+    for even n_dev, n_dev for odd (one device sits each pass out). A
+    power of two pairs device a with a XOR k in pass k, so four devices
+    give {0<->1, 2<->3}, {0<->2, 1<->3}, {0<->3, 1<->2}; other counts use
+    the circle method.
+    """
+    if n_dev & (n_dev - 1) == 0:
+        return [tuple((a, a ^ k) for a in range(n_dev))
+                for k in range(1, n_dev)]
+    m = n_dev + n_dev % 2          # odd: a ghost device, whose peer rests
+    passes = []
+    for r in range(m - 1):
+        pairs = [(m - 1, r)] + [((r + i) % (m - 1), (r - i) % (m - 1))
+                                for i in range(1, m // 2)]
+        passes.append(tuple(sorted(
+            pr for a, b in pairs if max(a, b) < n_dev
+            for pr in ((a, b), (b, a)))))
+    return passes
+
+
+def mix_matching_sharded(stats: jax.Array, partners: jax.Array,
+                         axis_name: str, n_dev: int) -> jax.Array:
+    """One matching round inside ``shard_map``, its routing traced.
+
+    ``stats`` is this device's contiguous block of n/n_dev node rows;
+    ``partners`` the whole traced [n] partner vector (replicated). Pairs
+    within the block mix by a row gather; each pass of
+    :func:`device_passes` ships the block to the pass's peer device with
+    one ``ppermute`` (under the ``mix.permute`` scope), and the rows
+    matched to that peer gather their partner's row from what arrived.
+    Every node lies in at most one of these steps, so applying them in
+    turn reads only rows no earlier step changed: the result is
+    ``(s_i + s_p(i)) / 2`` for every row, as :func:`gossip.mix_matching`
+    computes it on one device.
+    """
+    n_local = stats.shape[0]
+    dev = jax.lax.axis_index(axis_name)
+    lo = dev * n_local
+    p = jax.lax.dynamic_slice_in_dim(partners, lo, n_local)
+    ids = lo + jnp.arange(n_local, dtype=p.dtype)
+    p_dev, src = p // n_local, p % n_local
+
+    def mix(rows, other, active):
+        keep = active.reshape((-1,) + (1,) * (rows.ndim - 1))
+        return jnp.where(keep, 0.5 * (rows + other[src]), rows)
+
+    stats = mix(stats, stats, (p_dev == dev) & (p != ids))
+    for perm in device_passes(n_dev):
+        peer_of = dict(perm)
+        peer = jnp.asarray([peer_of.get(d, d) for d in range(n_dev)],
+                           p.dtype)[dev]
+        with jax.named_scope("mix.permute"):
+            other = jax.lax.ppermute(stats, axis_name, list(perm))
+            stats = mix(stats, other, (p_dev == peer) & (peer != dev))
+    return stats
 
 
 SIM_BACKENDS = ("dense", "pallas")

@@ -81,6 +81,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import provenance as prov_mod
 from repro.checkpoint import checkpoint as ckpt_mod
@@ -134,6 +135,13 @@ class DeledaConfig:
                                      # documents supersede stale ones
                                      # (oem.forgetting_rho); None = the
                                      # paper's plain eq. (2), bit-exact
+    mesh: Mesh | None = dataclasses.field(default=None, repr=False)
+                                     # comm_backend="mesh": the 1-D device
+                                     # mesh whose devices hold the nodes in
+                                     # contiguous blocks of n/d; train_steps
+                                     # then runs under shard_map and gossips
+                                     # with ppermute (left out of the repr,
+                                     # so checkpoint digests do not change)
 
     def __post_init__(self):
         if self.mode not in ("sync", "async"):
@@ -160,12 +168,19 @@ class DeledaConfig:
                 stacklevel=3)
             if self.estep_backend == "dense":
                 object.__setattr__(self, "estep_backend", "pallas")
-        if self.comm_backend not in comm_mod.SIM_BACKENDS:
+        if self.comm_backend == "mesh":
+            if self.mesh is None or len(self.mesh.axis_names) != 1:
+                raise ValueError("comm_backend='mesh' needs a 1-D device "
+                                 "mesh (DeledaConfig.mesh) to hold the nodes")
+            if self.vocab_shards > 1 or self.eval_every:
+                raise ValueError("the mesh backend carries dense [n, K, V] "
+                                 "statistics with no in-loop evaluation")
+        elif self.mesh is not None:
+            raise ValueError("DeledaConfig.mesh needs comm_backend='mesh'")
+        elif self.comm_backend not in comm_mod.SIM_BACKENDS:
             raise ValueError(
                 f"comm_backend must be one of {comm_mod.SIM_BACKENDS} "
-                f"inside the simulation substrate, got "
-                f"{self.comm_backend!r} (the mesh backend lives in "
-                f"launch/gossip_sim.py)")
+                f"or 'mesh', got {self.comm_backend!r}")
         if self.estep_backend not in estep_mod.ESTEP_BACKENDS:
             raise ValueError(
                 f"estep_backend must be one of {estep_mod.ESTEP_BACKENDS}, "
@@ -275,6 +290,16 @@ def _resolve_schedule_kind(schedule: jax.Array, n: int, kind: str) -> str:
                      f"[T, 2] edges nor [T, {n}] matchings")
 
 
+def _node_axis(config: DeledaConfig, n: int) -> str:
+    """The mesh's node axis; its devices must split n into equal blocks."""
+    (axis,) = config.mesh.axis_names
+    n_dev = config.mesh.shape[axis]
+    if n % n_dev:
+        raise ValueError(f"n={n} nodes do not split over the mesh's "
+                         f"{n_dev} devices")
+    return axis
+
+
 def init_state(config: DeledaConfig, key: jax.Array, n: int) -> TrainState:
     """Build the step-0 :class:`TrainState` for an ``n``-node network.
 
@@ -283,10 +308,29 @@ def init_state(config: DeledaConfig, key: jax.Array, n: int) -> TrainState:
     so existing seeds keep their init statistics bit-identical; the run
     half is STORED as ``TrainState.key`` and per-step keys derive from
     it by absolute step index.
+
+    With ``config.mesh`` each device draws its own contiguous block of
+    node rows (the same numbers), and ``stats``, ``steps`` and ``member``
+    come out sharded by node; ``key`` and the scalars are replicated.
     """
     k_init, k_run = jax.random.split(key)
-    stats0 = jax.vmap(lambda k: init_stats(config.lda, k))(
-        jax.random.split(k_init, n))                    # [n, K, V]
+    draw = jax.vmap(lambda k: init_stats(config.lda, k))
+    if config.mesh is None:
+        stats0 = draw(jax.random.split(k_init, n))      # [n, K, V]
+    else:
+        axis = _node_axis(config, n)
+        node = NamedSharding(config.mesh, P(axis))
+        rep = NamedSharding(config.mesh, P())
+        stats0 = jax.shard_map(draw, mesh=config.mesh, in_specs=P(axis),
+                               out_specs=P(axis))(jax.random.split(k_init, n))
+        return TrainState(
+            stats=stats0,
+            steps=jax.device_put(jnp.zeros((n,), jnp.int32), node),
+            key=jax.device_put(k_run, rep),
+            t=jax.device_put(jnp.zeros((), jnp.int32), rep),
+            stats_version=jax.device_put(jnp.zeros((), jnp.int32), rep),
+            member=jax.device_put(jnp.ones((n,), bool), node),
+            cursor=jax.device_put(jnp.zeros((), jnp.int32), rep))
     if config.vocab_shards > 1:
         # the sharded carry: [n, K, S, V/S] — a pure layout reshape (V is
         # contiguous), so the dense and sharded trajectories are the same
@@ -327,13 +371,29 @@ def train_steps(config: DeledaConfig, state: TrainState, words: jax.Array,
     updates, its counter frozen); member_rec [T/record_every, n] bool
     membership at each record point (None = everyone: the consensus
     trace is then the original unmasked computation, bit-for-bit).
+
+    ``config.mesh`` (``comm_backend="mesh"``, matching schedules) runs the
+    round under ``shard_map`` over the mesh's node axis: ``state`` as
+    :func:`init_state` shards it, words/mask sharded by node, the rest
+    replicated. Each device takes its contiguous n/d rows; keys and
+    minibatches follow the GLOBAL node id, so a seed follows the
+    one-device trajectory. The mix is one ppermute pass per pair of
+    devices (:func:`comm.mix_matching_sharded`), the record reduces
+    across devices, ``history`` comes out sharded by node, and nothing
+    gathers the [n, K, V] statistic onto one device.
     """
     t_seg = schedule.shape[0]
     if t_seg % record_every != 0:
         raise ValueError(f"segment length {t_seg} must be divisible by "
                          f"record_every={record_every}")
     n, d, l = words.shape
-    comm = comm_mod.get_communicator(config.comm_backend)
+    mesh = config.mesh
+    if mesh is None:
+        comm = comm_mod.get_communicator(config.comm_backend)
+    else:
+        axis = _node_axis(config, n)
+        if kind != "matching":
+            raise ValueError("the mesh backend runs matching schedules only")
     unique = config.corpus_layout == "unique"
     if unique:
         estep = estep_mod.get_sparse_estep(config.estep_backend)
@@ -350,7 +410,7 @@ def train_steps(config: DeledaConfig, state: TrainState, words: jax.Array,
                 if config.decay is not None else None)
     n_topics, vocab = config.lda.n_topics, config.lda.vocab_size
     shards = config.vocab_shards
-    node_ids = jnp.arange(n, dtype=jnp.int32)
+    all_ids = jnp.arange(n, dtype=jnp.int32)
 
     def bcast(rows, ndim):
         # [n]-shaped masks/steps against the (possibly vocab-sharded) stats
@@ -403,77 +463,6 @@ def train_steps(config: DeledaConfig, state: TrainState, words: jax.Array,
             rho = bcast(rho, stats_rows.ndim)
             return (1.0 - rho) * stats_rows + rho * stats_hat, t
 
-    def iteration(carry, inp):
-        stats, steps = carry
-        event, t_abs, al, corr_row = inp                      # al/corr [n]
-        # the per-step stream is a pure function of the ABSOLUTE step
-        # index — segmentation-invariant, hence kill/restore-invariant
-        k = jax.random.fold_in(state.key, t_abs)
-        k_sel, k_gibbs = jax.random.split(k)
-
-        if kind == "edge":
-            i, j = event[0], event[1]
-            with jax.named_scope("deleda.mix"):
-                # an event is live unless it is the (i, i) drop sentinel or
-                # an endpoint is down this step (churn) / not a member
-                # (lifecycle)
-                ev_live = (i != j) & al[i] & al[j]
-                # -- gossip averaging step (Algorithm 1, line 4); a dead
-                # event mixes (i, i), which every backend applies as the
-                # identity
-                j_eff = jnp.where(ev_live, j, i)
-                stats = comm.mix_edge(stats, i, j_eff)
-            if config.mode == "sync":
-                # -- every live node updates locally (Algorithm 1, l. 5-7)
-                new_stats, new_steps = update_rows(
-                    stats, steps, node_ids, k_sel, k_gibbs, words, mask,
-                    corr_row)
-                with jax.named_scope("deleda.blend"):
-                    stats = jnp.where(bcast(al, stats.ndim), new_stats,
-                                      stats)
-                    steps = jnp.where(al, new_steps, steps)
-            else:
-                # -- only the two awake nodes update (async variant)
-                active = jnp.stack([i, j])                    # [2]
-                up_stats, up_steps = update_rows(
-                    stats[active], steps[active], active, k_sel, k_gibbs,
-                    words[active], mask[active], corr_row[active])
-                with jax.named_scope("deleda.blend"):
-                    upd = jnp.stack([ev_live, ev_live])
-                    up_stats = jnp.where(bcast(upd, up_stats.ndim),
-                                         up_stats, stats[active])
-                    up_steps = jnp.where(upd, up_steps, steps[active])
-                    stats = stats.at[active].set(up_stats)
-                    steps = steps.at[active].set(up_steps)
-        else:
-            partners = event                                  # [n]
-            with jax.named_scope("deleda.mix"):
-                # liveness guard: a pair with a down or non-member endpoint
-                # mixes as self-self (symmetric in (i, p[i]), so the row
-                # stays an involution)
-                partners = jnp.where(al & al[partners], partners, node_ids)
-                stats = comm.mix_matching(stats, partners)
-            new_stats, new_steps = update_rows(stats, steps, node_ids,
-                                               k_sel, k_gibbs, words,
-                                               mask, corr_row)
-            with jax.named_scope("deleda.blend"):
-                if config.mode == "sync":
-                    upd = al                                  # [n]
-                else:
-                    # matched live nodes are the awake ones this round
-                    upd = (partners != node_ids) & al
-                stats = jnp.where(bcast(upd, stats.ndim), new_stats, stats)
-                steps = jnp.where(upd, new_steps, steps)
-
-        return (stats, steps), None
-
-    def record_block(carry, inp):
-        xs, mem = inp
-        carry, _ = jax.lax.scan(iteration, carry, xs)
-        stats, _steps = carry
-        with jax.named_scope("deleda.record"):
-            return carry, (stats, gossip.consensus_distance(stats, mem))
-
     n_rec = t_seg // record_every
     t_idx = state.t + jnp.arange(t_seg, dtype=jnp.int32)      # absolute
     blocks = jax.tree_util.tree_map(
@@ -508,6 +497,107 @@ def train_steps(config: DeledaConfig, state: TrainState, words: jax.Array,
         else:
             ew, em = spec.words, spec.mask
 
+    def run_segment(stats, steps, key, words, mask, xs):
+        """The segment's scans over one device's node rows: all n, or
+        under ``shard_map`` the device's contiguous block, whose rows
+        keep their GLOBAL node ids (keys, minibatches) while the
+        schedule, liveness and weights come in whole."""
+        if mesh is None:
+            node_ids, mix = all_ids, comm.mix_matching
+            record = gossip.consensus_distance
+
+            def local(rows):
+                return rows
+        else:
+            n_local = stats.shape[0]
+            lo = jax.lax.axis_index(axis) * n_local
+            node_ids = lo + jnp.arange(n_local, dtype=jnp.int32)
+            mix = partial(comm_mod.mix_matching_sharded, axis_name=axis,
+                          n_dev=mesh.shape[axis])
+            record = partial(gossip.consensus_distance, axis_name=axis)
+
+            def local(rows):
+                return jax.lax.dynamic_slice_in_dim(rows, lo, n_local)
+
+        def iteration(carry, inp):
+            stats, steps = carry
+            event, t_abs, al, corr_row = inp                  # al/corr [n]
+            # the per-step stream is a pure function of the ABSOLUTE step
+            # index — segmentation-invariant, hence kill/restore-invariant
+            k = jax.random.fold_in(key, t_abs)
+            k_sel, k_gibbs = jax.random.split(k)
+
+            if kind == "edge":
+                i, j = event[0], event[1]
+                with jax.named_scope("deleda.mix"):
+                    # an event is live unless it is the (i, i) drop
+                    # sentinel or an endpoint is down this step (churn) /
+                    # not a member (lifecycle)
+                    ev_live = (i != j) & al[i] & al[j]
+                    # -- gossip averaging step (Algorithm 1, line 4); a
+                    # dead event mixes (i, i), which every backend applies
+                    # as the identity
+                    j_eff = jnp.where(ev_live, j, i)
+                    stats = comm.mix_edge(stats, i, j_eff)
+                if config.mode == "sync":
+                    # -- every live node updates locally (Alg. 1, l. 5-7)
+                    new_stats, new_steps = update_rows(
+                        stats, steps, node_ids, k_sel, k_gibbs, words,
+                        mask, corr_row)
+                    with jax.named_scope("deleda.blend"):
+                        stats = jnp.where(bcast(al, stats.ndim), new_stats,
+                                          stats)
+                        steps = jnp.where(al, new_steps, steps)
+                else:
+                    # -- only the two awake nodes update (async variant)
+                    active = jnp.stack([i, j])                # [2]
+                    up_stats, up_steps = update_rows(
+                        stats[active], steps[active], active, k_sel,
+                        k_gibbs, words[active], mask[active],
+                        corr_row[active])
+                    with jax.named_scope("deleda.blend"):
+                        upd = jnp.stack([ev_live, ev_live])
+                        up_stats = jnp.where(bcast(upd, up_stats.ndim),
+                                             up_stats, stats[active])
+                        up_steps = jnp.where(upd, up_steps, steps[active])
+                        stats = stats.at[active].set(up_stats)
+                        steps = steps.at[active].set(up_steps)
+            else:
+                partners = event                              # [n]
+                with jax.named_scope("deleda.mix"):
+                    # liveness guard: a pair with a down or non-member
+                    # endpoint mixes as self-self (symmetric in (i, p[i]),
+                    # so the row stays an involution)
+                    partners = jnp.where(al & al[partners], partners,
+                                         all_ids)
+                    stats = mix(stats, partners)
+                partners, al, corr_row = (local(partners), local(al),
+                                          local(corr_row))
+                new_stats, new_steps = update_rows(stats, steps, node_ids,
+                                                   k_sel, k_gibbs, words,
+                                                   mask, corr_row)
+                with jax.named_scope("deleda.blend"):
+                    if config.mode == "sync":
+                        upd = al                              # [n]
+                    else:
+                        # matched live nodes are the awake ones this round
+                        upd = (partners != node_ids) & al
+                    stats = jnp.where(bcast(upd, stats.ndim), new_stats,
+                                      stats)
+                    steps = jnp.where(upd, new_steps, steps)
+
+            return (stats, steps), None
+
+        def record_block(carry, inp):
+            xs, mem = inp
+            carry, _ = jax.lax.scan(iteration, carry, xs)
+            stats, _steps = carry
+            with jax.named_scope("deleda.record"):
+                return carry, (stats, record(stats, mem))
+
+        if not config.eval_every:
+            return (*jax.lax.scan(record_block, (stats, steps), xs), None)
+
         def eval_block(carry, inp):
             carry, (hist, cons) = jax.lax.scan(record_block, carry, inp)
             stats, _steps = carry
@@ -520,24 +610,38 @@ def train_steps(config: DeledaConfig, state: TrainState, words: jax.Array,
         xs = jax.tree_util.tree_map(
             lambda x: x.reshape((n_eval, blocks_per_eval) + x.shape[1:]),
             xs)
-        (stats, steps), (history, consensus, eval_lp) = jax.lax.scan(
-            eval_block, (state.stats, state.steps), xs)
-        history = history.reshape((n_rec,) + history.shape[2:])
-        consensus = consensus.reshape(n_rec)
+        carry, (history, consensus, eval_lp) = jax.lax.scan(
+            eval_block, (stats, steps), xs)
+        return (carry, (history.reshape((n_rec,) + history.shape[2:]),
+                        consensus.reshape(n_rec)), eval_lp)
+
+    if mesh is None:
+        (stats, steps), (history, consensus), eval_lp = run_segment(
+            state.stats, state.steps, state.key, words, mask, xs)
     else:
+        # one program over the node axis: each device carries its block
+        # of the statistic, and only the mix's ppermutes and the record's
+        # all-reduces cross devices
+        node = P(axis)
+        (stats, steps), (history, consensus) = jax.shard_map(
+            lambda *a: run_segment(*a)[:2], mesh=mesh,
+            in_specs=(node, node, P(), node, node, P()),
+            out_specs=((node, node), (P(None, axis), P())))(
+                state.stats, state.steps, state.key, words, mask, xs)
         eval_lp = None
-        (stats, steps), (history, consensus) = jax.lax.scan(
-            record_block, (state.stats, state.steps), xs)
     if shards > 1:
         # externally the trace is always dense [.., K, V]; the shard axis
         # was contiguous layout only, so this reshape is free
         history = history.reshape(n_rec, n, n_topics, vocab)
+    member = state.member if mem_rec is None else mem_rec[-1]
+    if mesh is not None and mem_rec is not None:
+        member = jax.lax.with_sharding_constraint(
+            member, NamedSharding(mesh, P(axis)))
     new_state = TrainState(
         stats=stats, steps=steps, key=state.key,
         t=state.t + t_seg,
         stats_version=state.stats_version + t_seg,
-        member=state.member if mem_rec is None else mem_rec[-1],
-        cursor=state.cursor)
+        member=member, cursor=state.cursor)
     return new_state, SegmentTrace(history=history, consensus=consensus,
                                    eval_lp=eval_lp)
 

@@ -150,7 +150,8 @@ def mixing_matrix_matching(partners: np.ndarray) -> np.ndarray:
 
 
 def consensus_distance(stats: jax.Array,
-                       member: jax.Array | None = None) -> jax.Array:
+                       member: jax.Array | None = None,
+                       axis_name: str | None = None) -> jax.Array:
     """||S - mean(S) 1^T||_F — the left side of paper eq. (3).
 
     ``member`` ([n] bool, lifecycle layer) restricts both the mean and
@@ -158,7 +159,14 @@ def consensus_distance(stats: jax.Array,
     (or has permanently left) carries init-only statistics that say
     nothing about the live network's agreement. ``member=None`` is the
     original unmasked computation, bit-for-bit.
+
+    With ``axis_name`` it runs inside ``shard_map`` on one device's
+    contiguous block of node rows (``member`` stays the whole [n] row):
+    one all-reduce of the node sum gives the mean, and one of a scalar
+    the squared norm.
     """
+    if axis_name is not None:
+        return _consensus_distance_across(stats, member, axis_name)
     if member is None:
         mean = stats.mean(axis=0, keepdims=True)
         return jnp.linalg.norm((stats - mean).reshape(stats.shape[0], -1))
@@ -167,6 +175,24 @@ def consensus_distance(stats: jax.Array,
     count = jnp.maximum(jnp.sum(member), 1).astype(stats.dtype)
     mean = (stats * w).sum(axis=0, keepdims=True) / count
     return jnp.linalg.norm(((stats - mean) * w).reshape(stats.shape[0], -1))
+
+
+def _consensus_distance_across(stats, member, axis_name):
+    n_local = stats.shape[0]
+    if member is None:
+        w = None
+        count = n_local * jax.lax.axis_size(axis_name)
+    else:
+        lo = jax.lax.axis_index(axis_name) * n_local
+        w = jax.lax.dynamic_slice_in_dim(member, lo, n_local).astype(
+            stats.dtype).reshape((-1,) + (1,) * (stats.ndim - 1))
+        count = jnp.maximum(jnp.sum(member), 1).astype(stats.dtype)
+    total = jax.lax.psum((stats if w is None else stats * w).sum(axis=0),
+                         axis_name)
+    dev = stats - total / count
+    if w is not None:
+        dev = dev * w
+    return jnp.sqrt(jax.lax.psum(jnp.sum(dev * dev), axis_name))
 
 
 def consensus_envelope(lambda2: float, rhos: np.ndarray,

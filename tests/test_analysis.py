@@ -142,6 +142,8 @@ def test_golden_covers_mesh_rows_too():
     assert golden["mesh_pass_1d"]["collectives"] == {"collective-permute": 1}
     assert golden["grid_estep_2d"]["collectives"] == {"all-reduce": 2}
     assert golden["update_step_1d"]["collectives"] == {}
+    assert golden["deleda_scan_mesh_1d"]["collectives"] == {
+        "collective-permute": 3, "all-reduce": 2}
 
 
 # ---------------------------------------------------------------------------

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import Mesh
 
 from repro.core import comm, deleda, gossip
 from repro.core.graph import complete_graph, watts_strogatz_graph
@@ -230,9 +231,69 @@ def test_run_deleda_async_matching_counts_matched_nodes(corpus):
     assert int(trace.steps.sum()) == awake
 
 
+@pytest.mark.parametrize("n_dev", [1, 2, 3, 4, 6, 8])
+def test_device_passes_are_a_one_factorization(n_dev):
+    passes = comm.device_passes(n_dev)
+    odd = n_dev % 2 and n_dev > 1          # one device rests each pass
+    assert len(passes) == (n_dev if odd else n_dev - 1)
+    met = []
+    for perm in passes:
+        peer = dict(perm)
+        assert all(peer[b] == a for a, b in perm)       # pairs, both ways
+        assert len(peer) == len(perm)                   # disjoint
+        met += [(a, b) for a, b in perm if a < b]
+    pairs = [(a, b) for a in range(n_dev) for b in range(a + 1, n_dev)]
+    assert sorted(met) == pairs                         # each pair once
+    if n_dev == 4:
+        assert [sorted((a, b) for a, b in p if a < b) for p in passes] == [
+            [(0, 1), (2, 3)], [(0, 2), (1, 3)], [(0, 3), (1, 2)]]
+
+
+def test_run_deleda_passes_the_mesh_through():
+    """run_deleda on a one-device mesh: shard_map, a record reduced over
+    the node axis, no ppermute pass; the dense run's trajectory."""
+    n, t = 4, 8
+    rng = np.random.default_rng(2)
+    words = jnp.asarray(rng.integers(0, CFG.vocab_size, (n, 6, 16)),
+                        jnp.int32)
+    mask = jnp.asarray(rng.random((n, 6, 16)) < 0.7)
+    sched, degs = deleda.make_run_inputs(complete_graph(n), t, seed=3,
+                                         kind="matching")
+    mesh = Mesh(np.asarray(jax.devices()[:1]), ("nodes",))
+    runs = [deleda.run_deleda(
+        deleda.DeledaConfig(lda=CFG, mode="sync", batch_size=2, **kw),
+        jax.random.key(5), words, mask, sched, degs, t, record_every=4)
+        for kw in ({}, dict(comm_backend="mesh", mesh=mesh))]
+    np.testing.assert_allclose(runs[1].stats, runs[0].stats, rtol=1e-6,
+                               atol=1e-7)
+    np.testing.assert_allclose(runs[1].consensus, runs[0].consensus,
+                               rtol=1e-6)
+    np.testing.assert_array_equal(runs[1].steps, runs[0].steps)
+
+
 def test_deleda_config_rejects_mesh_backend():
+    """What the mesh backend still refuses: no mesh to hold the nodes, a
+    mesh without the backend, a 2-D mesh, and edge schedules."""
     with pytest.raises(ValueError):
         deleda.DeledaConfig(lda=CFG, comm_backend="mesh")
+    mesh = Mesh(np.asarray(jax.devices()[:1]), ("nodes",))
+    with pytest.raises(ValueError):
+        deleda.DeledaConfig(lda=CFG, mesh=mesh)
+    with pytest.raises(ValueError):
+        deleda.DeledaConfig(lda=CFG, comm_backend="mesh",
+                            mesh=Mesh(np.asarray(jax.devices()[:1]).reshape(
+                                1, 1), ("nodes", "vocab")))
+    cfg = deleda.DeledaConfig(lda=CFG, mode="sync", batch_size=2,
+                              comm_backend="mesh", mesh=mesh)
+    n, t = 4, 2
+    state = deleda.init_state(cfg, jax.random.key(0), n)
+    words = jnp.zeros((n, 3, CFG.doc_len_max), jnp.int32)
+    with pytest.raises(ValueError):
+        deleda.train_steps(cfg, state, words, words > 0,
+                           jnp.zeros((t, 2), jnp.int32),
+                           jnp.ones((t, n), jnp.float32),
+                           jnp.ones((t, n), bool), record_every=t,
+                           kind="edge")
     with pytest.raises(ValueError):
         comm.get_communicator("carrier-pigeon")
 

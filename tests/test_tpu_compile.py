@@ -21,7 +21,7 @@ import pytest
 from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
-from repro.core import estep
+from repro.core import deleda, estep
 from repro.core.lda import LDAConfig
 from repro.kernels.gossip_mix.ops import mix_matching
 from repro.kernels.lda_gibbs import ops as gibbs_ops
@@ -134,6 +134,35 @@ def test_mesh_update_step_compiles(topo, one_chip, monkeypatch, grid):
         _spec(node, (N_NODES, 8, L), jnp.int32),
         _spec(node, (N_NODES, 8, L), jnp.bool_),
         _spec(node, (N_NODES,), jnp.bool_))
+
+
+def test_mesh_train_steps_compiles(topo, one_chip):
+    """The node-sharded ``train_steps`` segment for four chips: the round
+    body's three ppermute passes and the record's two all-reduces are its
+    only collectives, and the statistic is never gathered."""
+    mesh = Mesh(np.asarray(topo.devices), ("data",))
+    lda = LDAConfig(n_topics=K, vocab_size=V, alpha=0.5, doc_len_max=L,
+                    n_gibbs=S, n_gibbs_burnin=BURNIN)
+    cfg = deleda.DeledaConfig(lda=lda, mode="sync", batch_size=B,
+                              comm_backend="mesh", mesh=mesh)
+    node, rep = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+    seg = 4
+    state = deleda.TrainState(
+        stats=_spec(node, (N_NODES, K, V)),
+        steps=_spec(node, (N_NODES,), jnp.int32),
+        key=_spec(rep, (), jax.random.key(0).dtype),
+        t=_spec(rep, (), jnp.int32), stats_version=_spec(rep, (), jnp.int32),
+        member=_spec(node, (N_NODES,), jnp.bool_),
+        cursor=_spec(rep, (), jnp.int32))
+    text = deleda.train_steps.lower(
+        cfg, state, _spec(node, (N_NODES, 8, L), jnp.int32),
+        _spec(node, (N_NODES, 8, L), jnp.bool_),
+        _spec(rep, (seg, N_NODES), jnp.int32),
+        _spec(rep, (seg, N_NODES)), _spec(rep, (seg, N_NODES), jnp.bool_),
+        record_every=seg).compile().as_text()
+    assert text.count("collective-permute-start(") == 3
+    assert text.count(" all-reduce(") == 2
+    assert "all-gather" not in text and "all-to-all" not in text
 
 
 def test_node_batched_scatter_has_no_relayout_loop(one_chip):
