@@ -294,12 +294,13 @@ def _route_matching(partners: np.ndarray, n_dev: int):
     Nodes are block-contiguous over the axis: device d owns rows
     [d*n_local, (d+1)*n_local). Cross-device pairs are greedily colored into
     *device-level matchings* ("passes"); each pass is one bidirectional
-    ppermute of the full local block plus a per-node row-gather from the
-    received block. With one node per device every matching is a single
-    pass — one [K, V] block per device per round.
+    ppermute of the full local block plus a per-node row fetch from the
+    received block (:func:`gossip.partner_mix`). With one node per device
+    every matching is a single pass — one [K, V] block per device per
+    round.
 
     Returns ((intra_src, intra_active), [(perm, remote_src, active), ...])
-    where intra_src/remote_src are [n] local-row gather indices and perm is
+    where intra_src/remote_src are [n] local row indices and perm is
     the static (src, dst) device permutation of the pass.
     """
     partners = np.asarray(partners)
@@ -355,7 +356,7 @@ class MeshComm:
     leading (node) axis: ``mix_matching`` routes the matching as intra-device
     row mixes plus one-hop ppermute passes (see :func:`_route_matching`).
     The routing is host-static (schedules are pre-drawn), so each distinct
-    device-permutation compiles once and is cached; the per-node gather
+    device-permutation compiles once and is cached; the per-node row
     indices stay traced, so two rounds sharing a device permutation share a
     compilation.
 
@@ -411,9 +412,7 @@ class MeshComm:
 
             def local_mix(stats, src, active):
                 with jax.named_scope("deleda.mix"):
-                    mixed = 0.5 * (stats + stats[src])
-                    keep = active.reshape((-1,) + (1,) * (stats.ndim - 1))
-                    return jnp.where(keep, mixed, stats)
+                    return gossip.partner_mix(stats, stats, src, active)
 
             stats_spec = self._stats_spec(ndim)
             fn = jax.jit(jax.shard_map(
@@ -432,9 +431,7 @@ class MeshComm:
             def exchange(stats, src, active):
                 with jax.named_scope("deleda.mix"):
                     other = jax.lax.ppermute(stats, axis, perm_list)
-                    mixed = 0.5 * (stats + other[src])
-                    keep = active.reshape((-1,) + (1,) * (stats.ndim - 1))
-                    return jnp.where(keep, mixed, stats)
+                    return gossip.partner_mix(stats, other, src, active)
 
             stats_spec = self._stats_spec(ndim)
             fn = jax.jit(jax.shard_map(
@@ -507,14 +504,16 @@ def mix_matching_sharded(stats: jax.Array, partners: jax.Array,
 
     ``stats`` is this device's contiguous block of n/n_dev node rows;
     ``partners`` the whole traced [n] partner vector (replicated). Pairs
-    within the block mix by a row gather; each pass of
+    within the block mix through :func:`gossip.partner_mix`; each pass of
     :func:`device_passes` ships the block to the pass's peer device with
     one ``ppermute`` (under the ``mix.permute`` scope), and the rows
-    matched to that peer gather their partner's row from what arrived.
+    matched to that peer fetch their partner's row from what arrived the
+    same way.
     Every node lies in at most one of these steps, so applying them in
     turn reads only rows no earlier step changed: the result is
     ``(s_i + s_p(i)) / 2`` for every row, as :func:`gossip.mix_matching`
-    computes it on one device.
+    computes it on one device. A non-finite entry of a block reaches that
+    entry of every row a step mixes from the block.
     """
     n_local = stats.shape[0]
     dev = jax.lax.axis_index(axis_name)
@@ -522,19 +521,15 @@ def mix_matching_sharded(stats: jax.Array, partners: jax.Array,
     p = jax.lax.dynamic_slice_in_dim(partners, lo, n_local)
     ids = lo + jnp.arange(n_local, dtype=p.dtype)
     p_dev, src = p // n_local, p % n_local
-
-    def mix(rows, other, active):
-        keep = active.reshape((-1,) + (1,) * (rows.ndim - 1))
-        return jnp.where(keep, 0.5 * (rows + other[src]), rows)
-
-    stats = mix(stats, stats, (p_dev == dev) & (p != ids))
+    stats = gossip.partner_mix(stats, stats, src, (p_dev == dev) & (p != ids))
     for perm in device_passes(n_dev):
         peer_of = dict(perm)
         peer = jnp.asarray([peer_of.get(d, d) for d in range(n_dev)],
                            p.dtype)[dev]
         with jax.named_scope("mix.permute"):
             other = jax.lax.ppermute(stats, axis_name, list(perm))
-            stats = mix(stats, other, (p_dev == peer) & (peer != dev))
+            stats = gossip.partner_mix(stats, other, src,
+                                       (p_dev == peer) & (peer != dev))
     return stats
 
 

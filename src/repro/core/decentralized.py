@@ -158,7 +158,9 @@ def sync_tree_sim(tree, spec: SyncSpec, n_nodes: int,
     Semantics match sync_tree_mesh with a single axis of size n_nodes.
     Rounds are applied through a simulation `Communicator` (pure-jnp dense
     by default; pass comm=PallasSimComm(...) to route [n, K, V] leaves
-    through the gossip_mix kernel).
+    through the gossip_mix kernel). Through the default communicator a
+    non-finite entry of one node's leaf reaches that entry of every
+    node's leaf in the first round (:func:`gossip.partner_mix`).
     """
     if spec.kind == "allreduce":
         return jax.tree.map(

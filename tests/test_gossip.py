@@ -3,6 +3,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from hyputil import given, settings, st
 
 from repro.core import gossip
@@ -36,6 +37,64 @@ def test_mix_matching_preserves_mean(n, seed):
     out = gossip.mix_matching(s, jnp.asarray(p))
     np.testing.assert_allclose(np.asarray(out.mean(0)),
                                np.asarray(s.mean(0)), atol=1e-5)
+
+
+def _guarded_involution(n, rng):
+    """A random matching with self-partners, then the training round's
+    liveness guard: a pair with a down endpoint mixes as self-self."""
+    p = np.arange(n)
+    order = rng.permutation(n)
+    for a, b in zip(order[::2], order[1::2]):
+        if rng.random() < 0.8:
+            p[a], p[b] = b, a
+    live = rng.random(n) < 0.85
+    return np.where(live & live[p], p, np.arange(n)).astype(np.int32)
+
+
+@pytest.mark.parametrize("with_active", [False, True],
+                         ids=["all-rows", "active"])
+@pytest.mark.parametrize("trailing", [(5,), (3, 11), (100, 131)],
+                         ids=lambda t: "x".join(map(str, t)))
+@pytest.mark.parametrize("n", [2, 7, 32, 128, 129, 200])
+def test_partner_mix_bitwise_equals_row_gather(n, trailing, with_active):
+    """The partner rows come from a one-hot contraction, at node counts
+    on both sides of the 128 rows of an MXU pass: it gives the gather's
+    bits."""
+    rng = np.random.default_rng(n * 131 + len(trailing))
+    p = _guarded_involution(n, rng)
+    s = jnp.asarray(rng.standard_normal((n, *trailing))
+                    * 10.0 ** rng.uniform(-6, 6, (n, *trailing)),
+                    jnp.float32)
+    active = jnp.asarray(rng.random(n) < 0.7) if with_active else None
+    out = gossip.partner_mix(s, s, jnp.asarray(p), active)
+    mixed = 0.5 * (s + s[jnp.asarray(p)])
+    want = mixed if active is None else jnp.where(
+        active.reshape((-1,) + (1,) * len(trailing)), mixed, s)
+    assert jnp.array_equal(out, want)
+    if active is None:
+        assert jnp.array_equal(gossip.mix_matching(s, jnp.asarray(p)), want)
+
+
+@pytest.mark.parametrize("bad", [np.nan, np.inf], ids=["nan", "inf"])
+@pytest.mark.parametrize("n", [7, 32])
+def test_partner_mix_spreads_non_finite_to_every_active_row(n, bad):
+    """0 * NaN and 0 * inf are NaN in the one-hot contraction, so one
+    non-finite entry reaches that entry of every active row; every other
+    entry, and every inactive row, is the gather's result."""
+    rng = np.random.default_rng(n)
+    p = _guarded_involution(n, rng)
+    s = rng.standard_normal((n, 3, 5)).astype(np.float32)
+    s[n // 2, 1, 2] = bad
+    active = rng.random(n) < 0.6
+    active[:2] = True, False
+    out = np.asarray(gossip.partner_mix(jnp.asarray(s), jnp.asarray(s),
+                                        jnp.asarray(p), jnp.asarray(active)))
+    want = np.where(active[:, None, None], 0.5 * (s + s[p]), s)
+    hit = np.zeros((3, 5), bool)
+    hit[1, 2] = True
+    assert not np.isfinite(out[active][:, hit]).any()
+    np.testing.assert_array_equal(out[active][:, ~hit], want[active][:, ~hit])
+    np.testing.assert_array_equal(out[~active], s[~active])
 
 
 def test_hypercube_rounds_reach_exact_consensus():

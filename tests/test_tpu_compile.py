@@ -4,15 +4,16 @@ Interpret mode cannot see tiling, layout or VMEM refusals; the TPU
 compiler can, and it compiles for a chip that is described rather than
 attached. Shapes are those of ``chip_smoke.py`` (the ``big`` regime of
 ``benchmarks/scale_bench.py``: n=1024 nodes, V=50,000, K=4, 2 docs per
-node per step, L=16, 4 Gibbs sweeps); the statistic scatter's test takes
-a training cell's. Nothing runs, so these tests say nothing about results
-or times.
+node per step, L=16, 4 Gibbs sweeps); the statistic scatter's and the
+gossip mix's tests take a training cell's. Nothing runs, so these tests
+say nothing about results or times.
 
 The topology is described inside a module fixture, never at import: only
 the worker that runs this file may load the TPU compiler library.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +23,7 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
 from repro.core import deleda, estep
+from repro.core.comm import DenseSimComm
 from repro.core.lda import LDAConfig
 from repro.kernels.gossip_mix.ops import mix_matching
 from repro.kernels.lda_gibbs import ops as gibbs_ops
@@ -136,10 +138,67 @@ def test_mesh_update_step_compiles(topo, one_chip, monkeypatch, grid):
         _spec(node, (N_NODES,), jnp.bool_))
 
 
-def test_mesh_train_steps_compiles(topo, one_chip):
-    """The node-sharded ``train_steps`` segment for four chips: the round
-    body's three ppermute passes and the record's two all-reduces are its
-    only collectives, and the statistic is never gathered."""
+# an f32 row gather under the gossip mix's scope: the node-axis gather
+# that partner_mix replaces with a contraction
+_MIX_ROW_GATHER = re.compile(r"= f32\[[^\]]*\][^ ]* gather\(.*deleda\.mix")
+
+
+def _mix_contractions(text: str) -> int:
+    """Contractions at HIGHEST precision under the ``mix.contract`` scope."""
+    return sum("operand_precision={highest,highest}" in ln
+               and "mix.contract" in ln for ln in text.splitlines())
+
+
+def _assert_no_reduce_with_contraction(text: str):
+    """No fused computation holds both a mix contraction and a reduce:
+    fused into the contraction's output, the E-step's sum over V would
+    run in another order than after a gather (partner_mix's barrier)."""
+    for block in text.split("\n\n"):
+        if any("operand_precision={highest,highest}" in ln
+               and "mix.contract" in ln for ln in block.splitlines()):
+            assert " reduce(" not in block
+
+
+def test_dense_mix_is_one_highest_contraction(one_chip):
+    """The one-chip mix at PubMed's shape fetches partner rows with one
+    f32 contraction at HIGHEST precision, not a node-axis row gather: a
+    default-precision dot would be a single bf16 pass."""
+    text = jax.jit(DenseSimComm().mix_matching).lower(
+        _spec(one_chip, (32, 100, 141_043)),
+        _spec(one_chip, (32,), jnp.int32)).compile().as_text()
+    assert "gather(" not in text
+    assert text.count("operand_precision={highest,highest}") == 1
+    assert _mix_contractions(text) == 1
+
+
+def test_train_steps_mix_is_one_highest_contraction(one_chip):
+    """The one-chip ``train_steps`` segment at the cells' 32 nodes: its
+    round's mix is one HIGHEST contraction and no f32 row gather."""
+    n, seg = 32, 4
+    lda = LDAConfig(n_topics=K, vocab_size=V, alpha=0.5, doc_len_max=L,
+                    n_gibbs=S, n_gibbs_burnin=BURNIN)
+    cfg = deleda.DeledaConfig(lda=lda, mode="sync", batch_size=B)
+    state = deleda.TrainState(
+        stats=_spec(one_chip, (n, K, V)),
+        steps=_spec(one_chip, (n,), jnp.int32),
+        key=_spec(one_chip, (), jax.random.key(0).dtype),
+        t=_spec(one_chip, (), jnp.int32),
+        stats_version=_spec(one_chip, (), jnp.int32),
+        member=_spec(one_chip, (n,), jnp.bool_),
+        cursor=_spec(one_chip, (), jnp.int32))
+    text = deleda.train_steps.lower(
+        cfg, state, _spec(one_chip, (n, 8, L), jnp.int32),
+        _spec(one_chip, (n, 8, L), jnp.bool_),
+        _spec(one_chip, (seg, n), jnp.int32), _spec(one_chip, (seg, n)),
+        _spec(one_chip, (seg, n), jnp.bool_),
+        record_every=seg).compile().as_text()
+    assert not any(_MIX_ROW_GATHER.search(ln) for ln in text.splitlines())
+    assert _mix_contractions(text) == 1
+    _assert_no_reduce_with_contraction(text)
+
+
+def _mesh_train_steps_text(topo, n_nodes: int) -> str:
+    """The node-sharded ``train_steps`` segment for four chips, compiled."""
     mesh = Mesh(np.asarray(topo.devices), ("data",))
     lda = LDAConfig(n_topics=K, vocab_size=V, alpha=0.5, doc_len_max=L,
                     n_gibbs=S, n_gibbs_burnin=BURNIN)
@@ -148,21 +207,43 @@ def test_mesh_train_steps_compiles(topo, one_chip):
     node, rep = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
     seg = 4
     state = deleda.TrainState(
-        stats=_spec(node, (N_NODES, K, V)),
-        steps=_spec(node, (N_NODES,), jnp.int32),
+        stats=_spec(node, (n_nodes, K, V)),
+        steps=_spec(node, (n_nodes,), jnp.int32),
         key=_spec(rep, (), jax.random.key(0).dtype),
         t=_spec(rep, (), jnp.int32), stats_version=_spec(rep, (), jnp.int32),
-        member=_spec(node, (N_NODES,), jnp.bool_),
+        member=_spec(node, (n_nodes,), jnp.bool_),
         cursor=_spec(rep, (), jnp.int32))
     text = deleda.train_steps.lower(
-        cfg, state, _spec(node, (N_NODES, 8, L), jnp.int32),
-        _spec(node, (N_NODES, 8, L), jnp.bool_),
-        _spec(rep, (seg, N_NODES), jnp.int32),
-        _spec(rep, (seg, N_NODES)), _spec(rep, (seg, N_NODES), jnp.bool_),
+        cfg, state, _spec(node, (n_nodes, 8, L), jnp.int32),
+        _spec(node, (n_nodes, 8, L), jnp.bool_),
+        _spec(rep, (seg, n_nodes), jnp.int32),
+        _spec(rep, (seg, n_nodes)), _spec(rep, (seg, n_nodes), jnp.bool_),
         record_every=seg).compile().as_text()
     assert text.count("collective-permute-start(") == 3
     assert text.count(" all-reduce(") == 2
     assert "all-gather" not in text and "all-to-all" not in text
+    return text
+
+
+def test_mesh_train_steps_compiles(topo, one_chip):
+    """The node-sharded ``train_steps`` segment for four chips: the round
+    body's three ppermute passes and the record's two all-reduces are its
+    only collectives, and the statistic is never gathered across chips.
+    With 256 nodes a chip the in-block mix and each pass still fetch
+    partner rows by a HIGHEST contraction, not a row gather."""
+    text = _mesh_train_steps_text(topo, N_NODES)
+    assert not any(_MIX_ROW_GATHER.search(ln) for ln in text.splitlines())
+    assert _mix_contractions(text) == 4
+
+
+def test_mesh_train_steps_mix_is_highest_contraction(topo, one_chip):
+    """With the cells' 32 nodes a chip, the in-block mix and each of the
+    three passes fetch partner rows by a HIGHEST contraction, and no f32
+    row gather is left under ``deleda.mix``."""
+    text = _mesh_train_steps_text(topo, 128)
+    assert not any(_MIX_ROW_GATHER.search(ln) for ln in text.splitlines())
+    assert _mix_contractions(text) == 4
+    _assert_no_reduce_with_contraction(text)
 
 
 def test_node_batched_scatter_has_no_relayout_loop(one_chip):
