@@ -3,10 +3,11 @@
 Two deliverables:
 
 1. **Backend sweep** (the default): run identical matching schedules
-   through every `repro.core.comm` backend — DenseSimComm (jnp oracle),
-   PallasSimComm (gossip_mix kernel) and MeshComm (ppermute routing over
-   the host mesh) — and write ``BENCH_gossip.json`` with bytes-moved and
-   wall-clock per backend, so future PRs have a perf trajectory to beat.
+   through every `repro.core.comm` mixer — DenseSimComm (jnp oracle),
+   PallasSimComm (gossip_mix kernel) and mix_matching_sharded (ppermute
+   passes over the host mesh, inside shard_map) — and write
+   ``BENCH_gossip.json`` with bytes-moved and wall-clock per backend, so
+   future PRs have a perf trajectory to beat.
    (Interpret-mode Pallas wall-times on CPU are NOT TPU predictions; the
    dense oracle is the CPU reference.)
 
@@ -28,15 +29,19 @@ import subprocess
 import sys
 import textwrap
 import time
+from functools import partial
 
 import jax
+import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from benchmarks import bench_util
 from repro.configs import get_config, list_archs
 from repro.core import comm as comm_mod
 from repro.core import decentralized as dec
 from repro.core.graph import watts_strogatz_graph
+from repro.launch.mesh import make_host_mesh
 
 SPECS = ["allreduce", "gossip-hypercube", "gossip-hypercube[2]",
          "gossip-hypercube[1]", "gossip-ring[2]"]
@@ -63,9 +68,25 @@ VERIFY = textwrap.dedent("""
 """ % SPECS)
 
 
+def mesh_mixer():
+    """``mix_matching_sharded`` over the host's devices, and the bytes its
+    passes ship a round: every pass moves each device's whole block."""
+    mesh = make_host_mesh()
+    n_dev = mesh.shape["data"]
+    mix = jax.jit(jax.shard_map(
+        partial(comm_mod.mix_matching_sharded, axis_name="data",
+                n_dev=n_dev),
+        mesh=mesh, in_specs=(P("data"), P()), out_specs=P("data")))
+
+    def bytes_per_round(shape, itemsize, partners):
+        block = shape[0] // n_dev * int(np.prod(shape[1:])) * itemsize
+        return sum(len(p) for p in comm_mod.device_passes(n_dev)) * block
+    return mix, bytes_per_round
+
+
 def bench_backends(n: int, k_topics: int, vocab: int, rounds: int,
                    seed: int, out_path: str) -> dict:
-    """Time every Communicator backend on one matching schedule."""
+    """Time every gossip mixer on one matching schedule."""
     graph = watts_strogatz_graph(n, 4, 0.3, seed)
     sched = comm_mod.GossipSchedule.draw_matchings(
         graph, rounds, np.random.default_rng(seed))
@@ -84,26 +105,29 @@ def bench_backends(n: int, k_topics: int, vocab: int, rounds: int,
     print(f"{'backend':>8s} {'us/round':>10s} {'MB moved':>10s} "
           f"{'vs dense':>9s}")
 
-    def run_all(c, s):
+    def run_all(mix, s):
         for t in range(sched.n_rounds):
-            s = c.mix_matching(s, sched.data[t])
+            s = mix(s, jnp.asarray(sched.data[t]))
         return s
 
-    ref_out = np.asarray(run_all(comm_mod.DenseSimComm(), stats))
+    mixers = {name: (c.mix_matching, c.bytes_per_round)
+              for name, c in ((b, comm_mod.get_communicator(b))
+                              for b in comm_mod.SIM_BACKENDS)}
+    mixers["mesh"] = mesh_mixer()
+    ref_out = np.asarray(run_all(mixers["dense"][0], stats))
     ref_us = None
-    for name in ("dense", "pallas", "mesh"):
-        c = comm_mod.get_communicator(name)
-        out = run_all(c, stats)                       # warmup / compile
+    for name, (mix, bytes_per_round) in mixers.items():
+        out = run_all(mix, stats)                     # warmup / compile
         jax.block_until_ready(out)
         t0 = time.perf_counter()
         iters = 3
         for _ in range(iters):
-            out = run_all(c, stats)
+            out = run_all(mix, stats)
         jax.block_until_ready(out)
         us_per_round = ((time.perf_counter() - t0) / iters
                         / sched.n_rounds * 1e6)
         total_bytes = sum(
-            c.bytes_per_round(stats.shape, itemsize, sched.data[t])
+            bytes_per_round(stats.shape, itemsize, sched.data[t])
             for t in range(sched.n_rounds))
         err = float(np.abs(np.asarray(out) - ref_out).max())
         assert err < 1e-5, (name, err)

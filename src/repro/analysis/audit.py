@@ -14,7 +14,7 @@ mesh tests use), so one invocation covers everything:
 
     PYTHONPATH=src python -m repro.analysis.audit
     PYTHONPATH=src python -m repro.analysis.audit --regen   # repin golden
-    PYTHONPATH=src python -m repro.analysis.audit --only mesh_pass_2d
+    PYTHONPATH=src python -m repro.analysis.audit --only deleda_scan_grid_2d
 """
 
 from __future__ import annotations

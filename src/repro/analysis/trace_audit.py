@@ -14,9 +14,9 @@ module turns all three into machine-checked invariants:
   one compiled module and report violations + the collective inventory.
 - :data:`ENTRY_POINTS` / :func:`collect_inventories` — the registry of
   audited repo entry points (the `run_deleda` scan and its node-sharded
-  form, MeshComm's gossip pass fns on 1-D and 2-D grids, the fused eval
-  chunk, the serving slabs, the mesh local-update step) and the
-  golden-pinning helpers (`tests/golden_collectives.json`).
+  forms on a 1-D mesh and a node x vocab grid, the fused eval chunk, the
+  serving slabs) and the golden-pinning helpers
+  (`tests/golden_collectives.json`).
 - :class:`CompileCounter` — the reusable recompile guard generalizing
   the scattered ``_cache_size() == 1`` asserts.
 
@@ -303,76 +303,24 @@ def _build_serve_slab(kind: str):
     return build
 
 
-def _mesh_pass_args():
-    import jax.numpy as jnp
-    n, k, v = 8, 3, 32
-    stats = jnp.zeros((n, k, v), jnp.float32)
-    src = jnp.arange(n, dtype=jnp.int32)
-    active = jnp.ones((n,), bool)
-    return stats, src, active
-
-
-def _build_mesh_pass(grid: tuple[int, int] | None):
+def _build_deleda_mesh(shape: tuple[int, ...]):
+    """The node-sharded ``train_steps`` segment on a mesh of the first
+    devices, ``shape`` (nodes,) or (nodes, vocab): two nodes a node-axis
+    device, two record blocks."""
     def build():
-        from repro.core import comm as comm_mod
-        if grid is None:
-            comm = comm_mod.MeshComm()
-            perm = tuple((i, i ^ 1) for i in range(comm.n_devices))
-        else:
-            mesh = comm_mod.make_grid_mesh(*grid)
-            comm = comm_mod.MeshComm(mesh=mesh, vocab_axis="vocab")
-            perm = tuple((i, i ^ 1) for i in range(grid[0]))
-        return comm._get_pass_fn(perm, 3).lower(
-            *_mesh_pass_args()).compile()
-    return build
+        import math
 
-
-def _build_mesh_local():
-    def build():
-        from repro.core import comm as comm_mod
-        comm = comm_mod.MeshComm()
-        return comm._get_local_fn(3).lower(*_mesh_pass_args()).compile()
-    return build
-
-
-def _build_update_step(grid: tuple[int, int] | None):
-    def build():
-        import jax
-        import jax.numpy as jnp
-        from repro.core import comm as comm_mod
-        from repro.launch.gossip_sim import build_update_step
-        from repro.launch.mesh import make_host_mesh
-        lda = _tiny_lda()
-        if grid is None:
-            mesh, vocab_axis = make_host_mesh(), None
-        else:
-            mesh, vocab_axis = comm_mod.make_grid_mesh(*grid), "vocab"
-        step = build_update_step(lda, 3, mesh, vocab_axis=vocab_axis)
-        n, d = 8, 6
-        stats = jnp.zeros((n, lda.n_topics, lda.vocab_size), jnp.float32)
-        steps = jnp.zeros((n,), jnp.int32)
-        words = jnp.zeros((n, d, _L), jnp.int32)
-        mask = jnp.ones((n, d, _L), bool)
-        alive = jnp.ones((n,), bool)
-        return step.lower(stats, steps, jax.random.key(0), words, mask,
-                          alive).compile()
-    return build
-
-
-def _build_deleda_mesh(n_dev: int):
-    """The node-sharded ``train_steps`` segment on a 1-D mesh of the
-    first ``n_dev`` devices: two nodes a device, two record blocks."""
-    def build():
         import jax
         import jax.numpy as jnp
         import numpy as np
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
         from repro.core import deleda
-        mesh = Mesh(np.asarray(jax.devices()[:n_dev]), ("data",))
+        devices = np.asarray(jax.devices()[:math.prod(shape)])
+        mesh = Mesh(devices.reshape(shape), ("data", "vocab")[:len(shape)])
         cfg = deleda.DeledaConfig(lda=_tiny_lda(), mode="sync",
                                   batch_size=3, comm_backend="mesh",
                                   mesh=mesh)
-        n, d, t = 2 * n_dev, 6, 4
+        n, d, t = 2 * shape[0], 6, 4
         node = NamedSharding(mesh, P("data"))
         words = jax.device_put(jnp.zeros((n, d, _L), jnp.int32), node)
         mask = jax.device_put(jnp.ones((n, d, _L), bool), node)
@@ -383,15 +331,6 @@ def _build_deleda_mesh(n_dev: int):
             jnp.ones((t, n), bool), record_every=2).compile()
     return build
 
-
-def _vocab_groups(grid: tuple[int, int]) -> tuple[tuple[int, ...], ...]:
-    """Vocab-axis replica groups of a node x vocab grid, in the compiled
-    module's logical device coordinates (row-major over the mesh)."""
-    nd, vd = grid
-    return tuple(tuple(range(r * vd, (r + 1) * vd)) for r in range(nd))
-
-
-_GRID = (4, 2)
 
 ENTRY_POINTS: dict[str, EntryPoint] = {
     # single-device rows (tier-1): the simulation scan, the fused eval
@@ -420,32 +359,6 @@ ENTRY_POINTS: dict[str, EntryPoint] = {
         InvariantSpec("serve_slab_mixture", doc_len=64,
                       max_temp_bytes=1 << 20),
         _build_serve_slab("mixture")),
-    # mesh rows (8 host devices): gossip is ppermute-only, the local
-    # update has no collectives on a 1-D mesh, and the 2-D grid's only
-    # collectives are the two vocab-axis psums of the blocked beta
-    # assembly (denominator + column partials) — grouped over vocab
-    # rows, never over the node axis, never a doc-shaped operand.
-    "mesh_local_1d": EntryPoint(
-        InvariantSpec("mesh_local_1d", doc_len=_L),
-        _build_mesh_local(), min_devices=8),
-    "mesh_pass_1d": EntryPoint(
-        InvariantSpec("mesh_pass_1d", allowed_collectives=GOSSIP_ALLOWED,
-                      max_counts=(("collective-permute", 1),), doc_len=_L),
-        _build_mesh_pass(None), min_devices=8),
-    "mesh_pass_2d": EntryPoint(
-        InvariantSpec("mesh_pass_2d", allowed_collectives=GOSSIP_ALLOWED,
-                      max_counts=(("collective-permute", 1),), doc_len=_L),
-        _build_mesh_pass(_GRID), min_devices=8),
-    "update_step_1d": EntryPoint(
-        InvariantSpec("update_step_1d", doc_len=_L),
-        _build_update_step(None), min_devices=8),
-    "grid_estep_2d": EntryPoint(
-        InvariantSpec("grid_estep_2d",
-                      allowed_collectives=frozenset({"all-reduce"}),
-                      max_counts=(("all-reduce", 2),), doc_len=_L,
-                      replica_groups=_vocab_groups(_GRID),
-                      grouped_kinds=frozenset({"all-reduce"})),
-        _build_update_step(_GRID), min_devices=8),
     # node-sharded training on four devices: the round body's only
     # collectives are the d - 1 = 3 ppermute passes of the mix, and the
     # record's two all-reduces (the node sum and the squared norm); no
@@ -456,7 +369,19 @@ ENTRY_POINTS: dict[str, EntryPoint] = {
                       max_counts=(("collective-permute", 3),
                                   ("all-reduce", 2)),
                       doc_len=_L),
-        _build_deleda_mesh(4), min_devices=4),
+        _build_deleda_mesh((4,)), min_devices=4),
+    # the node x vocab grid, 2 x 2: the node axis's one ppermute pass;
+    # one all-reduce over each vocab row for the blocked beta assembly
+    # (its two psums, the column partials and the denominator, combined
+    # into one); the record's node sum over the node axis and its squared
+    # norm over both axes. No all-gather, no doc-shaped operand
+    "deleda_scan_grid_2d": EntryPoint(
+        InvariantSpec("deleda_scan_grid_2d",
+                      allowed_collectives=GOSSIP_ALLOWED | {"all-reduce"},
+                      max_counts=(("collective-permute", 1),
+                                  ("all-reduce", 3)),
+                      doc_len=_L),
+        _build_deleda_mesh((2, 2)), min_devices=4),
 }
 
 

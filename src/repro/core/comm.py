@@ -1,11 +1,8 @@
-"""Unified gossip communication layer: one schedule object, three backends.
+"""Unified gossip communication layer: one schedule object, its mixers.
 
 The paper's core move — replace global aggregation of the [K, V] sufficient
-statistic with pairwise gossip averaging — used to be implemented three
-separate times in this repo (single-edge jnp mixing inside ``run_deleda``'s
-scan, an all_gather-then-select in the mesh launcher, and the scalar-prefetch
-Pallas kernel that nothing called). This module is the single abstraction
-they all now share:
+statistic with pairwise gossip averaging — is this module, shared by every
+training path:
 
 * :class:`GossipSchedule` — a pre-drawn sequence of gossip events, either
   single activated edges (the paper's asynchronous Algorithm 1) or maximal
@@ -14,33 +11,30 @@ they all now share:
   foldable into one ``lax.scan``.
 
 * :class:`Communicator` — the protocol ``mix_matching(stats, partners)`` /
-  ``mix_edge(stats, i, j)`` with three interchangeable backends:
+  ``mix_edge(stats, i, j)`` with two interchangeable one-device backends:
 
   - :class:`DenseSimComm`   pure-jnp oracle (node axis is a real array axis)
   - :class:`PallasSimComm`  the kernels/gossip_mix scalar-prefetch kernel
-  - :class:`MeshComm`       ppermute pair exchanges over a device mesh axis;
-    documents physically never leave their device (the privacy placement),
-    and one matching round moves one local statistics block per device —
-    O(K*V) bytes, not the O(n*K*V) of the old all_gather hack.
+
+* :func:`mix_matching_sharded` — one matching round inside ``shard_map``,
+  each device holding a contiguous block of node rows: pairs within a
+  block mix in place and each pair of devices exchanges blocks with one
+  ``ppermute`` (:func:`device_passes`). Documents never leave their
+  device (the privacy placement). ``train_steps`` runs it over the node
+  axis of ``DeledaConfig.mesh``; a second, vocab axis splits every block
+  by V columns, and since gossip is row-linear each column block mixes
+  over the node axis on its own.
 
 Statistics enter the consensus linearly (exactly the property exploited by
 Campbell & How's approximate decentralized Bayes and by Cyffers & Bellet's
-privacy amplification), so all three backends compute the *same* averaging
-map and are asserted equivalent in tests/test_comm.py.
+privacy amplification), so every mixer computes the *same* averaging map,
+asserted in tests/test_comm.py.
 
-**Vocab sharding (the Scale layer).** Gossip averaging is row-linear in
-the statistic, so splitting the vocab axis into S blocks splits one
-matching round into S *independent* per-shard rounds that may live on
-different devices or mix as separate blocks. Every backend accepts
-vocab-sharded statistics ``[n, K, S, V/S]`` next to the dense
-``[n, K, V]``: the sim backends treat the shard axis as layout (dense is
-shard-oblivious; the Pallas kernel streams the flattened contiguous
-``[n, K, S*V/S]`` view, identical floats), and :class:`MeshComm` built on
-a 2-D node x vocab device grid (``vocab_axis=...``) routes each matching
-round as per-shard one-hop ppermutes over the NODE axis — every vocab
-shard of a matched pair exchanges its own [K, V/S] block in parallel, so
-the per-link payload drops by S while total wire bytes stay put
-(``bytes_per_round`` accounts per-shard payloads).
+**Vocab sharding (the Scale layer).** Both backends accept vocab-sharded
+statistics ``[n, K, S, V/S]`` next to the dense ``[n, K, V]``, the shard
+axis being layout: dense is shard-oblivious, and the Pallas kernel
+streams the flattened contiguous ``[n, K, S*V/S]`` view (identical
+floats).
 """
 
 from __future__ import annotations
@@ -51,15 +45,14 @@ from typing import Protocol, runtime_checkable
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import AxisType, PartitionSpec as P
 
 from repro.core import gossip
 from repro.core.graph import Graph
 
 __all__ = [
     "GossipSchedule", "Communicator", "DenseSimComm", "PallasSimComm",
-    "MeshComm", "get_communicator", "make_grid_mesh", "mesh_round",
-    "SIM_BACKENDS", "device_passes", "mix_matching_sharded",
+    "get_communicator", "mesh_round", "SIM_BACKENDS", "device_passes",
+    "mix_matching_sharded",
 ]
 
 # One gossip round over a mesh axis, usable *inside* shard_map (this is the
@@ -278,201 +271,8 @@ class PallasSimComm:
 
 
 # ----------------------------------------------------------------------------
-# Mesh backend: ppermute pair exchanges over a named axis
+# Mesh: ppermute pair exchanges over a named axis, inside shard_map
 # ----------------------------------------------------------------------------
-
-def make_grid_mesh(n_node_devices: int, n_vocab_devices: int,
-                   axis_names: tuple[str, str] = ("data", "vocab")):
-    """A 2-D node x vocab device grid for vocab-sharded MeshComm gossip."""
-    return jax.make_mesh((n_node_devices, n_vocab_devices), axis_names,
-                         axis_types=(AxisType.Auto,) * 2)
-
-
-def _route_matching(partners: np.ndarray, n_dev: int):
-    """Decompose one matching into intra-device mixing + ppermute passes.
-
-    Nodes are block-contiguous over the axis: device d owns rows
-    [d*n_local, (d+1)*n_local). Cross-device pairs are greedily colored into
-    *device-level matchings* ("passes"); each pass is one bidirectional
-    ppermute of the full local block plus a per-node row fetch from the
-    received block (:func:`gossip.partner_mix`). With one node per device
-    every matching is a single pass — one [K, V] block per device per
-    round.
-
-    Returns ((intra_src, intra_active), [(perm, remote_src, active), ...])
-    where intra_src/remote_src are [n] local row indices and perm is
-    the static (src, dst) device permutation of the pass.
-    """
-    partners = np.asarray(partners)
-    n = len(partners)
-    if n % n_dev:
-        raise ValueError(f"n={n} not divisible by n_dev={n_dev}")
-    n_local = n // n_dev
-
-    intra_src = (np.arange(n, dtype=np.int32) % n_local)
-    intra_active = np.zeros(n, bool)
-    cross: list[tuple[int, int]] = []
-    for i in range(n):
-        j = int(partners[i])
-        if j <= i:
-            continue
-        if i // n_local == j // n_local:
-            intra_src[i] = j % n_local
-            intra_src[j] = i % n_local
-            intra_active[i] = intra_active[j] = True
-        else:
-            cross.append((i, j))
-
-    passes = []      # [{devmap: {a: b}, nodes: [(i, j)]}]
-    for i, j in cross:
-        a, b = i // n_local, j // n_local
-        for ps in passes:
-            pa, pb = ps["devmap"].get(a), ps["devmap"].get(b)
-            if (pa is None and pb is None) or (pa == b and pb == a):
-                ps["devmap"][a] = b
-                ps["devmap"][b] = a
-                ps["nodes"].append((i, j))
-                break
-        else:
-            passes.append({"devmap": {a: b, b: a}, "nodes": [(i, j)]})
-
-    routed = []
-    for ps in passes:
-        perm = tuple(sorted(ps["devmap"].items()))
-        remote_src = (np.arange(n, dtype=np.int32) % n_local)
-        active = np.zeros(n, bool)
-        for i, j in ps["nodes"]:
-            remote_src[i] = j % n_local
-            remote_src[j] = i % n_local
-            active[i] = active[j] = True
-        routed.append((perm, remote_src, active))
-    return (intra_src, intra_active), routed
-
-
-class MeshComm:
-    """Gossip over a device mesh axis via pairwise ``ppermute`` exchanges.
-
-    Host-level interface over globally-shaped [n, ...] arrays sharded on the
-    leading (node) axis: ``mix_matching`` routes the matching as intra-device
-    row mixes plus one-hop ppermute passes (see :func:`_route_matching`).
-    The routing is host-static (schedules are pre-drawn), so each distinct
-    device-permutation compiles once and is cached; the per-node row
-    indices stay traced, so two rounds sharing a device permutation share a
-    compilation.
-
-    ``vocab_axis`` names the second mesh axis of a 2-D node x vocab device
-    grid (:func:`make_grid_mesh`): statistics are then ALSO sharded over
-    the vocab axis — the last axis of dense [n, K, V] stats, the shard
-    axis of vocab-sharded [n, K, S, V/S] stats — and each ppermute pass
-    exchanges every vocab shard's own block over the node axis in
-    parallel. Gossip is row-linear, so the per-shard rounds compose to
-    exactly the dense averaging map.
-
-    For code already *inside* shard_map, use :func:`mesh_round` directly.
-    """
-
-    name = "mesh"
-
-    def __init__(self, mesh=None, axis_name: str = "data",
-                 vocab_axis: str | None = None):
-        if mesh is None:
-            n = len(jax.devices())
-            mesh = jax.make_mesh((n,), (axis_name,),
-                                 axis_types=(AxisType.Auto,))
-        self.mesh = mesh
-        self.axis_name = axis_name
-        self.vocab_axis = vocab_axis
-        self.n_devices = int(dict(mesh.shape)[axis_name])
-        self.n_vocab_shards = (1 if vocab_axis is None
-                               else int(dict(mesh.shape)[vocab_axis]))
-        self._pass_fns: dict[tuple, object] = {}
-        self._local_fns: dict[int, object] = {}
-
-    # -- jitted building blocks ---------------------------------------------
-
-    def _node_spec(self):
-        return P(self.axis_name)
-
-    def _stats_spec(self, ndim: int):
-        """Node axis leading; vocab axis (if any) on V for dense [n, K, V]
-        stats and on S for vocab-sharded [n, K, S, V/S] stats."""
-        spec = [self.axis_name] + [None] * (ndim - 1)
-        if self.vocab_axis is not None:
-            if ndim < 3:
-                raise ValueError(
-                    f"vocab-sharded MeshComm needs [n, K, V] or "
-                    f"[n, K, S, V/S] stats, got ndim={ndim}")
-            spec[2 if ndim >= 4 else ndim - 1] = self.vocab_axis
-        return P(*spec)
-
-    def _get_local_fn(self, ndim: int):
-        fn = self._local_fns.get(ndim)
-        if fn is None:
-            node = self._node_spec()
-
-            def local_mix(stats, src, active):
-                with jax.named_scope("deleda.mix"):
-                    return gossip.partner_mix(stats, stats, src, active)
-
-            stats_spec = self._stats_spec(ndim)
-            fn = jax.jit(jax.shard_map(
-                local_mix, mesh=self.mesh,
-                in_specs=(stats_spec, node, node), out_specs=stats_spec))
-            self._local_fns[ndim] = fn
-        return fn
-
-    def _get_pass_fn(self, perm: tuple, ndim: int = 3):
-        fn = self._pass_fns.get((perm, ndim))
-        if fn is None:
-            node = self._node_spec()
-            axis = self.axis_name
-            perm_list = list(perm)
-
-            def exchange(stats, src, active):
-                with jax.named_scope("deleda.mix"):
-                    other = jax.lax.ppermute(stats, axis, perm_list)
-                    return gossip.partner_mix(stats, other, src, active)
-
-            stats_spec = self._stats_spec(ndim)
-            fn = jax.jit(jax.shard_map(
-                exchange, mesh=self.mesh,
-                in_specs=(stats_spec, node, node), out_specs=stats_spec))
-            self._pass_fns[(perm, ndim)] = fn
-        return fn
-
-    # -- Communicator interface ---------------------------------------------
-
-    def mix_matching(self, stats, partners):
-        partners = np.asarray(partners, np.int32)
-        (intra_src, intra_active), passes = _route_matching(
-            partners, self.n_devices)
-        if intra_active.any():
-            stats = self._get_local_fn(stats.ndim)(
-                stats, jnp.asarray(intra_src), jnp.asarray(intra_active))
-        for perm, remote_src, active in passes:
-            stats = self._get_pass_fn(perm, stats.ndim)(
-                stats, jnp.asarray(remote_src), jnp.asarray(active))
-        return stats
-
-    def mix_edge(self, stats, i, j):
-        # host-level routing: i, j must be concrete (schedules are pre-drawn)
-        n = stats.shape[0]
-        p = np.arange(n, dtype=np.int32)
-        p[int(i)], p[int(j)] = int(j), int(i)
-        return self.mix_matching(stats, p)
-
-    def bytes_per_round(self, stats_shape, itemsize, partners):
-        # each ppermute pass moves one PER-SHARD local block per involved
-        # (node-axis) device — all n_vocab_shards shards of a matched pair
-        # exchange in parallel, so the per-link payload is 1/S of the dense
-        # block while the round total is unchanged
-        _, passes = _route_matching(np.asarray(partners), self.n_devices)
-        n_local = stats_shape[0] // self.n_devices
-        shard_block = (n_local * _pair_payload_bytes(stats_shape, itemsize)
-                       // self.n_vocab_shards)
-        return sum(len(perm) * self.n_vocab_shards * shard_block
-                   for perm, _, _ in passes)
-
 
 def device_passes(n_dev: int) -> list[tuple[tuple[int, int], ...]]:
     """The static 1-factorization of ``n_dev`` devices into ppermute passes.
@@ -537,12 +337,10 @@ SIM_BACKENDS = ("dense", "pallas")
 
 
 def get_communicator(name: str, **kwargs) -> Communicator:
-    """Factory: 'dense' | 'pallas' | 'mesh' (kwargs go to the backend)."""
+    """Factory: 'dense' | 'pallas' (kwargs go to the backend)."""
     if name == "dense":
         return DenseSimComm(**kwargs)
     if name == "pallas":
         return PallasSimComm(**kwargs)
-    if name == "mesh":
-        return MeshComm(**kwargs)
     raise ValueError(f"unknown communicator backend {name!r}; "
-                     f"want dense | pallas | mesh")
+                     f"want dense | pallas")

@@ -136,12 +136,14 @@ class DeledaConfig:
                                      # (oem.forgetting_rho); None = the
                                      # paper's plain eq. (2), bit-exact
     mesh: Mesh | None = dataclasses.field(default=None, repr=False)
-                                     # comm_backend="mesh": the 1-D device
-                                     # mesh whose devices hold the nodes in
-                                     # contiguous blocks of n/d; train_steps
-                                     # then runs under shard_map and gossips
-                                     # with ppermute (left out of the repr,
-                                     # so checkpoint digests do not change)
+                                     # comm_backend="mesh": the device mesh
+                                     # whose first axis holds the nodes in
+                                     # contiguous blocks of n/d, and whose
+                                     # second axis, if any, splits V into
+                                     # blocks; train_steps then runs under
+                                     # shard_map and gossips with ppermute
+                                     # (left out of the repr, so checkpoint
+                                     # digests do not change)
 
     def __post_init__(self):
         if self.mode not in ("sync", "async"):
@@ -169,12 +171,23 @@ class DeledaConfig:
             if self.estep_backend == "dense":
                 object.__setattr__(self, "estep_backend", "pallas")
         if self.comm_backend == "mesh":
-            if self.mesh is None or len(self.mesh.axis_names) != 1:
-                raise ValueError("comm_backend='mesh' needs a 1-D device "
-                                 "mesh (DeledaConfig.mesh) to hold the nodes")
-            if self.vocab_shards > 1 or self.eval_every:
-                raise ValueError("the mesh backend carries dense [n, K, V] "
-                                 "statistics with no in-loop evaluation")
+            if self.mesh is None or len(self.mesh.axis_names) not in (1, 2):
+                raise ValueError("comm_backend='mesh' needs a 1-D node mesh "
+                                 "or a 2-D node x vocab mesh "
+                                 "(DeledaConfig.mesh)")
+            if self.vocab_shards > 1:
+                raise ValueError("the mesh backend carries [n, K, V] "
+                                 "statistics: split V over a second mesh "
+                                 "axis, not vocab_shards")
+            if len(self.mesh.axis_names) == 2:
+                blocks = self.mesh.shape[self.mesh.axis_names[1]]
+                if self.lda.vocab_size % blocks:
+                    raise ValueError(
+                        f"the mesh's vocab axis of {blocks} devices must "
+                        f"divide vocab_size={self.lda.vocab_size}")
+                if self.eval_every:
+                    raise ValueError("in-loop evaluation does not run on a "
+                                     "node x vocab mesh")
         elif self.mesh is not None:
             raise ValueError("DeledaConfig.mesh needs comm_backend='mesh'")
         elif self.comm_backend not in comm_mod.SIM_BACKENDS:
@@ -290,14 +303,21 @@ def _resolve_schedule_kind(schedule: jax.Array, n: int, kind: str) -> str:
                      f"[T, 2] edges nor [T, {n}] matchings")
 
 
-def _node_axis(config: DeledaConfig, n: int) -> str:
-    """The mesh's node axis; its devices must split n into equal blocks."""
-    (axis,) = config.mesh.axis_names
+def _mesh_axes(config: DeledaConfig, n: int) -> tuple[str, str | None]:
+    """The mesh's node axis, whose devices must split n into equal
+    blocks, and its vocab axis (None on a 1-D mesh)."""
+    axis, *vocab = config.mesh.axis_names
     n_dev = config.mesh.shape[axis]
     if n % n_dev:
         raise ValueError(f"n={n} nodes do not split over the mesh's "
                          f"{n_dev} devices")
-    return axis
+    return axis, (vocab[0] if vocab else None)
+
+
+def _stats_spec(axis: str, vocab_axis: str | None) -> P:
+    """How a mesh shards the [n, K, V] statistic: rows by node blocks,
+    and on a 2-D mesh columns by V blocks."""
+    return P(axis) if vocab_axis is None else P(axis, None, vocab_axis)
 
 
 def init_state(config: DeledaConfig, key: jax.Array, n: int) -> TrainState:
@@ -311,18 +331,30 @@ def init_state(config: DeledaConfig, key: jax.Array, n: int) -> TrainState:
 
     With ``config.mesh`` each device draws its own contiguous block of
     node rows (the same numbers), and ``stats``, ``steps`` and ``member``
-    come out sharded by node; ``key`` and the scalars are replicated.
+    come out sharded by node, ``stats`` by V blocks too on a 2-D mesh (a
+    device draws its rows whole and keeps its block); ``key`` and the
+    scalars are replicated.
     """
     k_init, k_run = jax.random.split(key)
     draw = jax.vmap(lambda k: init_stats(config.lda, k))
     if config.mesh is None:
         stats0 = draw(jax.random.split(k_init, n))      # [n, K, V]
     else:
-        axis = _node_axis(config, n)
+        axis, vocab_axis = _mesh_axes(config, n)
         node = NamedSharding(config.mesh, P(axis))
         rep = NamedSharding(config.mesh, P())
-        stats0 = jax.shard_map(draw, mesh=config.mesh, in_specs=P(axis),
-                               out_specs=P(axis))(jax.random.split(k_init, n))
+        if vocab_axis is None:
+            block = draw
+        else:
+            width = config.lda.vocab_size // config.mesh.shape[vocab_axis]
+
+            def block(keys):
+                lo = jax.lax.axis_index(vocab_axis) * width
+                return jax.lax.dynamic_slice_in_dim(draw(keys), lo, width,
+                                                    axis=-1)
+        stats0 = jax.shard_map(block, mesh=config.mesh, in_specs=P(axis),
+                               out_specs=_stats_spec(axis, vocab_axis))(
+            jax.random.split(k_init, n))
         return TrainState(
             stats=stats0,
             steps=jax.device_put(jnp.zeros((n,), jnp.int32), node),
@@ -379,8 +411,15 @@ def train_steps(config: DeledaConfig, state: TrainState, words: jax.Array,
     minibatches follow the GLOBAL node id, so a seed follows the
     one-device trajectory. The mix is one ppermute pass per pair of
     devices (:func:`comm.mix_matching_sharded`), the record reduces
-    across devices, ``history`` comes out sharded by node, and nothing
-    gathers the [n, K, V] statistic onto one device.
+    across devices, ``history`` comes out sharded like the statistic, and
+    nothing gathers the [n, K, V] statistic onto one device. In-loop
+    eval: each device evaluates its first min(probe_nodes, n/d) rows, and
+    the probe nodes' columns are kept, ``[E, probe_nodes]`` as on one
+    device.
+    A 2-D mesh also splits V into blocks over its second axis: the
+    E-step assembles its beta columns with ``psum``s over that axis
+    (:func:`estep.beta_w_from_stats`), each device scatters its block's
+    words, and the mix runs unchanged over the node axis.
     """
     t_seg = schedule.shape[0]
     if t_seg % record_every != 0:
@@ -390,8 +429,9 @@ def train_steps(config: DeledaConfig, state: TrainState, words: jax.Array,
     mesh = config.mesh
     if mesh is None:
         comm = comm_mod.get_communicator(config.comm_backend)
+        vocab_axis = None
     else:
-        axis = _node_axis(config, n)
+        axis, vocab_axis = _mesh_axes(config, n)
         if kind != "matching":
             raise ValueError("the mesh backend runs matching schedules only")
     unique = config.corpus_layout == "unique"
@@ -442,12 +482,15 @@ def train_steps(config: DeledaConfig, state: TrainState, words: jax.Array,
             # materialized path. In the unique layout bw/bm hold
             # (word_id, count) rows instead of (token, mask) rows and the
             # sweeps are count-weighted.
+            # On a 2-D mesh the rows hold this device's V block.
             if unique:
                 stats_hat = estep_mod.estep_batch_from_stats_unique(
-                    estep, config.lda, keys, bw, bm, stats_rows)
+                    estep, config.lda, keys, bw, bm, stats_rows,
+                    axis_name=vocab_axis)
             else:
                 stats_hat = estep_mod.estep_batch_from_stats(
-                    estep, config.lda, keys, bw, bm, stats_rows)  # [A,K,V]
+                    estep, config.lda, keys, bw, bm, stats_rows,
+                    axis_name=vocab_axis)                        # [A,K,V]
             stats_hat = stats_hat.reshape(stats_rows.shape)
         with jax.named_scope("deleda.blend"):
             t = steps_rows + 1
@@ -514,7 +557,8 @@ def train_steps(config: DeledaConfig, state: TrainState, words: jax.Array,
             node_ids = lo + jnp.arange(n_local, dtype=jnp.int32)
             mix = partial(comm_mod.mix_matching_sharded, axis_name=axis,
                           n_dev=mesh.shape[axis])
-            record = partial(gossip.consensus_distance, axis_name=axis)
+            record = partial(gossip.consensus_distance, axis_name=axis,
+                             vocab_axis=vocab_axis)
 
             def local(rows):
                 return jax.lax.dynamic_slice_in_dim(rows, lo, n_local)
@@ -601,10 +645,13 @@ def train_steps(config: DeledaConfig, state: TrainState, words: jax.Array,
         def eval_block(carry, inp):
             carry, (hist, cons) = jax.lax.scan(record_block, carry, inp)
             stats, _steps = carry
+            # the first `probe` rows; on a mesh, as many of the device's
+            # own rows, of which the caller keeps the probe nodes' rows
             lp = jax.vmap(lambda st: eval_mod.heldout_lp_from_stats(
                 spec.key, ew, em, st, config.lda.tau,
                 config.lda.alpha, spec.n_particles,
-                spec.layout, config.eval_backend))(stats[:probe])
+                spec.layout, config.eval_backend))(
+                    stats[:min(probe, stats.shape[0])])
             return carry, (hist, cons, lp)
 
         xs = jax.tree_util.tree_map(
@@ -620,15 +667,26 @@ def train_steps(config: DeledaConfig, state: TrainState, words: jax.Array,
             state.stats, state.steps, state.key, words, mask, xs)
     else:
         # one program over the node axis: each device carries its block
-        # of the statistic, and only the mix's ppermutes and the record's
-        # all-reduces cross devices
+        # of the statistic, and only the mix's ppermutes, the record's
+        # all-reduces and a 2-D mesh's vocab-axis psums cross devices
         node = P(axis)
-        (stats, steps), (history, consensus) = jax.shard_map(
-            lambda *a: run_segment(*a)[:2], mesh=mesh,
-            in_specs=(node, node, P(), node, node, P()),
-            out_specs=((node, node), (P(None, axis), P())))(
+        block = _stats_spec(axis, vocab_axis)
+        out_specs = ((block, node), (P(None, *block), P()))
+        if config.eval_every:
+            segment, out_specs = run_segment, out_specs + (P(None, axis),)
+        else:
+            def segment(*a):
+                return run_segment(*a)[:2]
+        # the evaluator's scans start from replicated zeros beside the
+        # device's own rows, which the varying-axes check refuses
+        (stats, steps), (history, consensus), *lp = jax.shard_map(
+            segment, mesh=mesh,
+            in_specs=(block, node, P(), node, node, P()),
+            out_specs=out_specs, check_vma=not config.eval_every)(
                 state.stats, state.steps, state.key, words, mask, xs)
-        eval_lp = None
+        # device d's first rows are columns d*r.. of lp, so the first
+        # `probe` columns are the probe nodes whether r = probe or n/d
+        eval_lp = lp[0][:, :probe] if lp else None
     if shards > 1:
         # externally the trace is always dense [.., K, V]; the shard axis
         # was contiguous layout only, so this reshape is free
@@ -722,6 +780,11 @@ def run_deleda(config: DeledaConfig, key: jax.Array,
     recorded in ``trace.eval_lp`` [n_steps/E, probe_nodes]. The training
     trajectory is unchanged (the evaluator has its own ``eval_spec.key``
     stream), asserted against the pinned goldens.
+
+    ``config.mesh`` (``comm_backend="mesh"``) runs every segment with the
+    nodes sharded over the mesh (:func:`train_steps`): a seed follows the
+    one-device trajectory, checkpoints hold the sharded state, and
+    ``restore_from`` places it as :func:`init_state` does.
     """
     if n_steps % record_every != 0:
         raise ValueError("n_steps must be divisible by record_every")
@@ -912,7 +975,9 @@ def restore_state(directory: str, like: TrainState,
     shapes); its key flavor (typed vs legacy uint32) decides how the
     stored key bits are rewrapped — both flavors derive bit-identical
     streams, so either resumes the exact trajectory. ``config`` enables
-    the sidecar digest check (restore warns when it differs).
+    the sidecar digest check (restore warns when it differs). Each array
+    is placed as ``like``'s is, so a mesh run's state comes back sharded
+    as :func:`init_state` shards it.
     """
     typed = _is_typed_key(like.key)
     flat_like = dataclasses.replace(
@@ -921,14 +986,11 @@ def restore_state(directory: str, like: TrainState,
               else None)
     flat = ckpt_mod.restore_checkpoint(directory, flat_like, step=step,
                                        expect_config_digest=digest)
-    key = jnp.asarray(flat.key)
     if typed:
-        key = jax.random.wrap_key_data(key)
-    return TrainState(
-        stats=jnp.asarray(flat.stats), steps=jnp.asarray(flat.steps),
-        key=key, t=jnp.asarray(flat.t),
-        stats_version=jnp.asarray(flat.stats_version),
-        member=jnp.asarray(flat.member), cursor=jnp.asarray(flat.cursor))
+        flat = dataclasses.replace(
+            flat, key=jax.random.wrap_key_data(jnp.asarray(flat.key)))
+    return jax.tree_util.tree_map(
+        lambda a, placed: jax.device_put(a, placed.sharding), flat, like)
 
 
 def make_run_inputs(graph: Graph, n_steps: int, seed: int = 0,

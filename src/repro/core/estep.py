@@ -347,7 +347,8 @@ def stats_from_per_pos(words: jax.Array, per_pos: jax.Array,
 
 
 def stats_per_node(words: jax.Array, per_pos: jax.Array, vocab_size: int,
-                   maskf: jax.Array) -> jax.Array:
+                   maskf: jax.Array,
+                   axis_name: str | None = None) -> jax.Array:
     """Node-batched :func:`stats_from_per_pos`: [A, K, V] statistics.
 
     words [A, B, L] (or unique ids [A, B, U]), per_pos [A, B, L, K],
@@ -358,7 +359,17 @@ def stats_per_node(words: jax.Array, per_pos: jax.Array, vocab_size: int,
     two loops (one per topic row, one per chunk of nodes) that cost several
     times the scatter itself. So the buffer's vocabulary axis is padded to
     the next multiple of 128, which no update touches, and sliced back to V.
+
+    With ``axis_name`` (inside ``shard_map``) that axis splits V into
+    equal blocks and the result is this device's [A, K, V/blocks] block:
+    each device scatters only the positions whose words it holds, at
+    their ids within the block, and the mean keeps the whole documents'
+    denominator.
     """
+    if axis_name is not None:
+        vocab_size //= jax.lax.axis_size(axis_name)
+        words, held = vocab_block(words, vocab_size, axis_name)
+        per_pos = jnp.where(held[..., None], per_pos, 0.0)
     width = -(-vocab_size // LANES) * LANES
     stats = jax.vmap(lambda w, p, m: stats_from_per_pos(w, p, width, m))(
         words, per_pos, maskf)
@@ -366,8 +377,19 @@ def stats_per_node(words: jax.Array, per_pos: jax.Array, vocab_size: int,
         return stats[..., :vocab_size]
 
 
+def vocab_block(words: jax.Array, width: int, axis_name: str
+                ) -> tuple[jax.Array, jax.Array]:
+    """Inside ``shard_map``: ``words``' ids within this device's block of
+    ``width`` V columns along ``axis_name`` (the blocks in device order),
+    clipped into it, and whether the block holds each word."""
+    local = words - jax.lax.axis_index(axis_name) * width
+    held = (local >= 0) & (local < width)
+    return jnp.clip(local, 0, width - 1), held
+
+
 def beta_w_from_stats(stats: jax.Array, words: jax.Array, tau: float,
-                      denom: jax.Array | None = None) -> jax.Array:
+                      denom: jax.Array | None = None,
+                      axis_name: str | None = None) -> jax.Array:
     """Likelihood rows beta[:, words] gathered straight from the statistic.
 
     The blocked-stats gather of the Scale layer: the E-step only ever
@@ -387,14 +409,27 @@ def beta_w_from_stats(stats: jax.Array, words: jax.Array, tau: float,
     stats: [K, V] or vocab-sharded [K, S, V/S] (trailing axes are flattened
     — the shard axis is a pure layout axis); words: [B, L] int32.
     Returns beta_w [B, L, K].
+
+    With ``axis_name`` (inside ``shard_map``) ``stats`` is this device's
+    block of V columns along that axis: one ``psum`` of the blocks' row
+    sums (``denom``, if given, is this block's) gives the denominator,
+    and one of [B, L, K] partials, each block
+    giving the columns of the words it holds and zeros elsewhere, the
+    rows. The dense [K, V] matrix exists on no device.
     """
     with jax.named_scope("estep.gather"):
         k = stats.shape[0]
         stats = stats.reshape(k, -1)
         if denom is None:
             denom = (stats + tau).sum(-1)                 # [K]
-        cols = jnp.moveaxis(stats[:, words], 0, -1)       # [B, L, K]
-        return (cols + tau) / denom
+        if axis_name is None:
+            cols = jnp.moveaxis(stats[:, words], 0, -1)   # [B, L, K]
+            return (cols + tau) / denom
+        local, held = vocab_block(words, stats.shape[-1], axis_name)
+        cols = jnp.moveaxis(stats[:, local], 0, -1)
+        part = jnp.where(held[..., None], cols + tau, 0.0)
+        return (jax.lax.psum(part, axis_name)
+                / jax.lax.psum(denom, axis_name))
 
 
 def theta_slab(key: jax.Array, doc_ids: jax.Array, beta_w: jax.Array,
@@ -692,10 +727,8 @@ def fused_sweeps(backend: _EStepBase, config: LDAConfig, keys: jax.Array,
 
     keys [A] per-node PRNG streams, beta_w [A, B, L, K] pre-gathered
     likelihood rows, maskf [A, B, L] float. Returns per-position statistics
-    [A, B, L, K]. Shared by :func:`estep_batch` (dense beta),
-    :func:`estep_batch_from_stats` (blocked gather) and the mesh
-    launcher's node x vocab grid (which psum-assembles beta_w across the
-    vocab axis before calling this).
+    [A, B, L, K]. Shared by :func:`estep_batch` (dense beta) and
+    :func:`estep_batch_from_stats` (blocked gather).
     """
     with jax.named_scope("estep.sweeps"):
         a, b, l, k = beta_w.shape
@@ -740,7 +773,8 @@ def estep_batch(backend: _EStepBase, config: LDAConfig, keys: jax.Array,
 def estep_batch_from_stats(backend: _EStepBase, config: LDAConfig,
                            keys: jax.Array, words: jax.Array,
                            mask: jax.Array, stats: jax.Array,
-                           rao_blackwell: bool = True) -> jax.Array:
+                           rao_blackwell: bool = True,
+                           axis_name: str | None = None) -> jax.Array:
     """Fused E-steps reading the topic matrix DIRECTLY from the statistic.
 
     The Scale layer's blocked-stats path: instead of materializing the
@@ -751,14 +785,18 @@ def estep_batch_from_stats(backend: _EStepBase, config: LDAConfig,
     to ``estep_batch(..., beta=eta_star(stats, config.tau))``.
 
     stats: [A, K, V] or vocab-sharded [A, K, S, V/S] per-node statistics.
-    Returns per-node statistics [A, K, V].
+    Returns per-node statistics [A, K, V]. With ``axis_name`` (inside
+    ``shard_map``) ``stats`` holds this device's block of V columns along
+    that axis, and so does the result (:func:`beta_w_from_stats`,
+    :func:`stats_per_node`).
     """
-    beta_w = jax.vmap(
-        lambda st, w: beta_w_from_stats(st, w, config.tau))(stats, words)
+    beta_w = jax.vmap(lambda st, w: beta_w_from_stats(
+        st, w, config.tau, axis_name=axis_name))(stats, words)
     maskf = mask.astype(beta_w.dtype)
     per_pos = fused_sweeps(backend, config, keys, beta_w, maskf,
                            rao_blackwell=rao_blackwell)
-    return stats_per_node(words, per_pos, config.vocab_size, maskf)
+    return stats_per_node(words, per_pos, config.vocab_size, maskf,
+                          axis_name)
 
 
 def fused_sweeps_sparse(backend: _SparseEStepBase, config: LDAConfig,
@@ -794,7 +832,9 @@ def estep_batch_from_stats_unique(backend: _SparseEStepBase,
                                   config: LDAConfig, keys: jax.Array,
                                   uw: jax.Array, counts: jax.Array,
                                   stats: jax.Array,
-                                  rao_blackwell: bool = True) -> jax.Array:
+                                  rao_blackwell: bool = True,
+                                  axis_name: str | None = None
+                                  ) -> jax.Array:
     """Fused CSR E-steps reading beta straight from the statistic.
 
     The unique-layout twin of :func:`estep_batch_from_stats`: uw/counts
@@ -803,10 +843,13 @@ def estep_batch_from_stats_unique(backend: _SparseEStepBase,
     ``beta_w_from_stats`` gather now touches only O(A*B*U*K) columns —
     the sparse layer's win compounds with the Scale layer's — and the
     segmented scatter assembles per-node [A, K, V] statistics back out.
+    ``axis_name`` splits V over a mesh axis as in
+    :func:`estep_batch_from_stats`.
     """
-    beta_w = jax.vmap(
-        lambda st, w: beta_w_from_stats(st, w, config.tau))(stats, uw)
+    beta_w = jax.vmap(lambda st, w: beta_w_from_stats(
+        st, w, config.tau, axis_name=axis_name))(stats, uw)
     countf = counts.astype(beta_w.dtype)
     per_unique = fused_sweeps_sparse(backend, config, keys, beta_w,
                                      countf, rao_blackwell=rao_blackwell)
-    return stats_per_node(uw, per_unique, config.vocab_size, countf)
+    return stats_per_node(uw, per_unique, config.vocab_size, countf,
+                          axis_name)

@@ -41,9 +41,9 @@ This module is the fourth first-class layer next to comm/estep/scenario
   un-sharding and without the dense topic-matrix temporary.
 
 In-loop evaluation: :class:`EvalSpec` + ``DeledaConfig.eval_every`` thread
-a held-out set through ``run_deleda`` / ``run_mesh_deleda`` so the LP
-trajectory is recorded on-device as the training scan runs (no host-side
-replay of ``trace.history``).
+a held-out set through ``run_deleda``, on one device or a node mesh, so
+the LP trajectory is recorded on-device as the training scan runs (no
+host-side replay of ``trace.history``).
 
 The paper reports the *relative* log-perplexity error LP/LP* - 1 where
 LP = -log p(X | eta) averaged over (non-empty) test documents and LP*

@@ -195,7 +195,8 @@ def mixing_matrix_matching(partners: np.ndarray) -> np.ndarray:
 
 def consensus_distance(stats: jax.Array,
                        member: jax.Array | None = None,
-                       axis_name: str | None = None) -> jax.Array:
+                       axis_name: str | None = None,
+                       vocab_axis: str | None = None) -> jax.Array:
     """||S - mean(S) 1^T||_F — the left side of paper eq. (3).
 
     ``member`` ([n] bool, lifecycle layer) restricts both the mean and
@@ -207,10 +208,13 @@ def consensus_distance(stats: jax.Array,
     With ``axis_name`` it runs inside ``shard_map`` on one device's
     contiguous block of node rows (``member`` stays the whole [n] row):
     one all-reduce of the node sum gives the mean, and one of a scalar
-    the squared norm.
+    the squared norm. ``vocab_axis`` names a second mesh axis that splits
+    the rows' last (V) axis into blocks; the squared norm then sums over
+    both axes.
     """
     if axis_name is not None:
-        return _consensus_distance_across(stats, member, axis_name)
+        return _consensus_distance_across(stats, member, axis_name,
+                                          vocab_axis)
     if member is None:
         mean = stats.mean(axis=0, keepdims=True)
         return jnp.linalg.norm((stats - mean).reshape(stats.shape[0], -1))
@@ -221,7 +225,7 @@ def consensus_distance(stats: jax.Array,
     return jnp.linalg.norm(((stats - mean) * w).reshape(stats.shape[0], -1))
 
 
-def _consensus_distance_across(stats, member, axis_name):
+def _consensus_distance_across(stats, member, axis_name, vocab_axis):
     n_local = stats.shape[0]
     if member is None:
         w = None
@@ -236,7 +240,8 @@ def _consensus_distance_across(stats, member, axis_name):
     dev = stats - total / count
     if w is not None:
         dev = dev * w
-    return jnp.sqrt(jax.lax.psum(jnp.sum(dev * dev), axis_name))
+    axes = axis_name if vocab_axis is None else (axis_name, vocab_axis)
+    return jnp.sqrt(jax.lax.psum(jnp.sum(dev * dev), axes))
 
 
 def consensus_envelope(lambda2: float, rhos: np.ndarray,

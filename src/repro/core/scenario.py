@@ -28,7 +28,7 @@ Three composable ingredients:
   pair is reset to self-partners (the Communicator layer's existing idle
   encoding) and a dropped edge event becomes the sentinel ``(i, i)``.
   Dense, Pallas and mesh comm backends therefore stay interchangeable —
-  MeshComm simply routes no ppermute for a masked pair. Churn additionally
+  on a mesh a masked pair mixes with itself. Churn additionally
   produces an ``alive [T, n]`` mask consumed by ``run_deleda``: a down node
   neither mixes nor updates, and its step counter stays frozen.
 

@@ -102,7 +102,8 @@ def main(argv=None):
                     help="checkpoint dir to restore instead of training")
     ap.add_argument("--restore-train", default=None, metavar="DIR",
                     help="restore a DELEDA TrainState checkpoint "
-                         "(run_deleda/gossip_sim save_every) and serve "
+                         "(run_deleda's save_every, as gossip_sim "
+                         "--save-every writes it) and serve "
                          "one node's statistic")
     ap.add_argument("--restore-node", type=int, default=0,
                     help="which node's statistic to serve (--restore-train)")

@@ -139,11 +139,10 @@ def test_single_device_entry_points_pass_and_match_golden():
 def test_golden_covers_mesh_rows_too():
     golden = ta.load_golden(GOLDEN)
     assert set(golden) == set(ta.ENTRY_POINTS)
-    assert golden["mesh_pass_1d"]["collectives"] == {"collective-permute": 1}
-    assert golden["grid_estep_2d"]["collectives"] == {"all-reduce": 2}
-    assert golden["update_step_1d"]["collectives"] == {}
     assert golden["deleda_scan_mesh_1d"]["collectives"] == {
         "collective-permute": 3, "all-reduce": 2}
+    assert golden["deleda_scan_grid_2d"]["collectives"] == {
+        "collective-permute": 1, "all-reduce": 3}
 
 
 # ---------------------------------------------------------------------------

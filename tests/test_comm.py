@@ -1,22 +1,23 @@
 """Unified communicator layer: schedules, backend equivalence, routing.
 
 The contract under test: DenseSimComm (pure-jnp oracle), PallasSimComm
-(gossip_mix kernel, interpret mode off-TPU) and MeshComm (ppermute routing
-over a device mesh) implement the SAME averaging map for the same matching
-schedule, and run_deleda replays an edge schedule identically through its
-one-pair-per-round matching view.
+(gossip_mix kernel, interpret mode off-TPU) and mix_matching_sharded
+(ppermute passes inside shard_map over a device mesh) implement the SAME
+averaging map for the same matching schedule, and run_deleda replays an
+edge schedule identically through its one-pair-per-round matching view.
 """
 
 import os
 import subprocess
 import sys
 import textwrap
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import Mesh
+from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import comm, deleda, gossip
 from repro.core.graph import complete_graph, watts_strogatz_graph
@@ -72,9 +73,15 @@ BACKENDS = ["dense", "pallas", "mesh"]
 
 
 def _mix_trajectory(backend, stats, schedule):
-    c = comm.get_communicator(backend)
+    if backend == "mesh":      # the sharded round on a one-device mesh
+        mix = jax.jit(jax.shard_map(
+            partial(comm.mix_matching_sharded, axis_name="nodes", n_dev=1),
+            mesh=Mesh(np.asarray(jax.devices()[:1]), ("nodes",)),
+            in_specs=(P("nodes"), P()), out_specs=P("nodes")))
+    else:
+        mix = comm.get_communicator(backend).mix_matching
     for t in range(schedule.n_rounds):
-        stats = c.mix_matching(stats, schedule.data[t])
+        stats = mix(stats, jnp.asarray(schedule.data[t]))
     return np.asarray(stats)
 
 
@@ -105,7 +112,7 @@ def test_backends_preserve_mean_and_contract():
 def test_mix_edge_equivalent_across_backends():
     stats = jax.random.normal(jax.random.key(6), (6, 3, 32))
     ref = np.asarray(comm.DenseSimComm().mix_edge(stats, 1, 4))
-    for backend in BACKENDS[1:]:
+    for backend in comm.SIM_BACKENDS[1:]:
         out = np.asarray(comm.get_communicator(backend).mix_edge(stats, 1,
                                                                  4))
         np.testing.assert_allclose(out, ref, atol=1e-6)
@@ -118,10 +125,6 @@ def test_bytes_model_sane():
     pair_block = k * v * itemsize
     dense = comm.DenseSimComm().bytes_per_round(shape, itemsize, p)
     assert dense == 8 * pair_block           # every matched node sends once
-    mesh = comm.MeshComm()
-    got = mesh.bytes_per_round(shape, itemsize, p)
-    if mesh.n_devices == 1:
-        assert got == 0                      # all pairs intra-device
     idle = np.arange(n, dtype=np.int32)
     assert comm.DenseSimComm().bytes_per_round(shape, itemsize, idle) == 0
 
@@ -132,40 +135,6 @@ def test_interpret_autodetect():
     assert ops.resolve_interpret(False) is False
     expected = jax.default_backend() != "tpu"
     assert ops.resolve_interpret(None) is expected
-
-
-# ---------------------------------------------------------------------------
-# Matching-round routing decomposition
-# ---------------------------------------------------------------------------
-
-def test_route_matching_single_node_per_device_is_one_pass():
-    p = np.array([1, 0, 3, 2, 5, 4, 7, 6], np.int32)
-    (intra_src, intra_active), passes = comm._route_matching(p, 8)
-    assert not intra_active.any()
-    assert len(passes) == 1                  # ONE bidirectional ppermute
-    perm, remote_src, active = passes[0]
-    assert sorted(perm) == [(0, 1), (1, 0), (2, 3), (3, 2), (4, 5), (5, 4),
-                            (6, 7), (7, 6)]
-    assert active.all()
-    np.testing.assert_array_equal(remote_src, np.zeros(8, np.int32))
-
-
-def test_route_matching_mixed_intra_cross():
-    # 8 nodes on 4 devices (2 per device): (0,1) intra; (2,4),(3,6) cross
-    p = np.array([1, 0, 4, 6, 2, 5, 3, 7], np.int32)
-    (intra_src, intra_active), passes = comm._route_matching(p, 4)
-    assert intra_active[0] and intra_active[1] and not intra_active[2:].any()
-    assert intra_src[0] == 1 and intra_src[1] == 0
-    # devices 1<->2 and 1<->3 conflict on device 1 -> two passes
-    assert len(passes) == 2
-    for perm, remote_src, active in passes:
-        devs = [a for a, _ in perm]
-        assert len(devs) == len(set(devs))   # each pass is a device matching
-
-
-def test_route_matching_rejects_indivisible():
-    with pytest.raises(ValueError):
-        comm._route_matching(np.arange(6, dtype=np.int32), 4)
 
 
 # ---------------------------------------------------------------------------
@@ -273,7 +242,7 @@ def test_run_deleda_passes_the_mesh_through():
 
 def test_deleda_config_rejects_mesh_backend():
     """What the mesh backend still refuses: no mesh to hold the nodes, a
-    mesh without the backend, a 2-D mesh, and edge schedules."""
+    mesh without the backend, a 3-D mesh, and edge schedules."""
     with pytest.raises(ValueError):
         deleda.DeledaConfig(lda=CFG, comm_backend="mesh")
     mesh = Mesh(np.asarray(jax.devices()[:1]), ("nodes",))
@@ -282,7 +251,7 @@ def test_deleda_config_rejects_mesh_backend():
     with pytest.raises(ValueError):
         deleda.DeledaConfig(lda=CFG, comm_backend="mesh",
                             mesh=Mesh(np.asarray(jax.devices()[:1]).reshape(
-                                1, 1), ("nodes", "vocab")))
+                                1, 1, 1), ("nodes", "vocab", "extra")))
     cfg = deleda.DeledaConfig(lda=CFG, mode="sync", batch_size=2,
                               comm_backend="mesh", mesh=mesh)
     n, t = 4, 2
@@ -299,64 +268,48 @@ def test_deleda_config_rejects_mesh_backend():
 
 
 # ---------------------------------------------------------------------------
-# Cross-device MeshComm (subprocess: needs XLA_FLAGS before jax init).
-# Asserts backend equivalence AND the acceptance property: the compiled
-# gossip path is collective-permute only — no all-gather.
+# Cross-device mix_matching_sharded (subprocess: needs XLA_FLAGS before jax
+# init). Asserts the dense oracle's map AND the acceptance property: the
+# compiled gossip round is collective-permute only — no all-gather.
 # ---------------------------------------------------------------------------
 
 MESH_SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    from functools import partial
     import numpy as np, jax, jax.numpy as jnp
+    from jax.sharding import Mesh, PartitionSpec as P
+    from repro.analysis import trace_audit as ta
     from repro.core import comm
     from repro.core.graph import complete_graph
 
+    mesh = Mesh(np.asarray(jax.devices()), ("data",))
+    mix = jax.jit(jax.shard_map(
+        partial(comm.mix_matching_sharded, axis_name="data", n_dev=8),
+        mesh=mesh, in_specs=(P("data"), P()), out_specs=P("data")))
     for n in (8, 16):                       # 1 and 2 nodes per device
         g = complete_graph(n)
         sched = comm.GossipSchedule.draw_matchings(
             g, 5, np.random.default_rng(1))
         stats = jax.random.uniform(jax.random.key(0), (n, 4, 64))
-        dense, mesh = comm.DenseSimComm(), comm.MeshComm()
         s_d, s_m = stats, stats
         for t in range(5):
-            s_d = dense.mix_matching(s_d, sched.data[t])
-            s_m = mesh.mix_matching(s_m, sched.data[t])
+            s_d = comm.DenseSimComm().mix_matching(s_d, sched.data[t])
+            s_m = mix(s_m, jnp.asarray(sched.data[t]))
         err = float(jnp.abs(s_d - s_m).max())
         assert err < 1e-6, (n, err)
 
-    # gossip-is-ppermute-only, via the one shared invariant implementation
-    from repro.analysis import trace_audit as ta
-    mesh = comm.MeshComm()
-    p = np.array([1, 0, 3, 2, 5, 4, 7, 6], np.int32)
-    _, passes = comm._route_matching(p, 8)
-    perm, _, _ = passes[0]
-    compiled = mesh._get_pass_fn(perm).lower(
-        jax.ShapeDtypeStruct((8, 4, 64), jnp.float32),
-        jax.ShapeDtypeStruct((8,), jnp.int32),
-        jax.ShapeDtypeStruct((8,), bool)).compile()
-    report = ta.audit_compiled(compiled, ta.InvariantSpec(
-        "gossip_pass", allowed_collectives=ta.GOSSIP_ALLOWED,
-        max_counts=(("collective-permute", 1),)))
-    assert report.ok, report.summary()
-    assert report.inventory == {"collective-permute": 1}, report.inventory
-
-    # the round's named scopes reach the shard-mapped programs' HLO
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.core.lda import LDAConfig
-    from repro.launch.gossip_sim import build_update_step
-    lda = LDAConfig(n_topics=4, vocab_size=64, doc_len_max=8, n_gibbs=2,
-                    n_gibbs_burnin=1)
-    node = NamedSharding(mesh.mesh, P("data"))
-    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
-                                                    sharding=node)
-    step_text = build_update_step(lda, 2, mesh.mesh).lower(
-        sds((8, 4, 64), jnp.float32), sds((8,), jnp.int32),
-        jax.random.key(0), sds((8, 3, 8), jnp.int32),
-        sds((8, 3, 8), jnp.bool_), sds((8,), jnp.bool_)).compile().as_text()
-    for scope in ("deleda.estep", "estep.gather", "estep.sweeps",
-                  "estep.scatter", "deleda.blend"):
-        assert scope in step_text, scope
-    assert "deleda.mix" in compiled.as_text()
+        # gossip-is-ppermute-only, via the one shared invariant
+        # implementation: one pass a pair of the 8 devices' 1-factorization
+        compiled = mix.lower(
+            jax.ShapeDtypeStruct((n, 4, 64), jnp.float32),
+            jax.ShapeDtypeStruct((n,), jnp.int32)).compile()
+        report = ta.audit_compiled(compiled, ta.InvariantSpec(
+            "gossip_round", allowed_collectives=ta.GOSSIP_ALLOWED,
+            max_counts=(("collective-permute", 7),)))
+        assert report.ok, report.summary()
+        assert report.inventory == {"collective-permute": 7}, \
+            report.inventory
     print("COMM_MESH_OK")
 """)
 

@@ -150,24 +150,23 @@ def test_pallas_non_rao_blackwell_falls_back_with_warning(doc_batch):
 
 @pytest.mark.parametrize("backend", estep.ESTEP_BACKENDS)
 def test_mesh_update_step_traces(backend):
-    """The mesh launcher's local update traces under shard_map's varying-
+    """The node-sharded ``train_steps`` traces under shard_map's varying-
     axes check with either E-step backend: its scan carries and kernel
     outputs vary over the node axis like its inputs."""
-    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
+    from jax.sharding import Mesh
 
-    from repro.launch.gossip_sim import build_update_step
-    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,),
-                         devices=jax.devices()[:1])
-    step = build_update_step(CFG, 2, mesh, estep_backend=backend)
-    n = 3
-    node = NamedSharding(mesh, P("data"))
-    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
-                                                     sharding=node)
-    jaxpr = str(step.trace(
-        spec((n, CFG.n_topics, CFG.vocab_size), jnp.float32),
-        spec((n,), jnp.int32), jax.random.key(0),
-        spec((n, 4, 16), jnp.int32), spec((n, 4, 16), jnp.bool_),
-        spec((n,), jnp.bool_)).jaxpr)
+    mesh = Mesh(np.asarray(jax.devices()[:1]), ("nodes",))
+    cfg = deleda.DeledaConfig(lda=CFG, mode="sync", batch_size=2,
+                              comm_backend="mesh", mesh=mesh,
+                              estep_backend=backend)
+    n, t = 4, 2
+    state = deleda.init_state(cfg, jax.random.key(0), n)
+    words = jnp.zeros((n, 4, 16), jnp.int32)
+    jaxpr = str(deleda.train_steps.trace(
+        cfg, state, words, words >= 0,
+        jnp.tile(jnp.asarray([1, 0, 3, 2], jnp.int32), (t, 1)),
+        jnp.ones((t, n), jnp.float32), jnp.ones((t, n), bool),
+        record_every=t).jaxpr)
     assert ("pallas_call" in jaxpr) == (backend == "pallas")
 
 

@@ -471,25 +471,32 @@ def test_inloop_eval_sharded_carry(inloop_setup):
 
 
 def test_mesh_launcher_records_eval_trajectory(inloop_setup):
-    """run_mesh_deleda(eval_every=, eval_spec=) returns the in-loop LP
-    trajectory as a fourth element (3-tuple unchanged without eval)."""
-    from repro.core.graph import complete_graph
-    from repro.launch.gossip_sim import run_mesh_deleda
-    cfg_lda, corpus, _sched, _degs, spec = inloop_setup
-    words, mask = corpus.words[:4], corpus.mask[:4]
-    g = complete_graph(4)
-    out = run_mesh_deleda(cfg_lda, words, mask, g, 4, 2, seed=0,
-                          eval_every=2, eval_spec=spec)
-    assert len(out) == 4
-    _stats, _cons, _sec, lp = out
-    assert lp.shape == (2, 2)
-    assert np.isfinite(lp).all() and (lp > 0).all()
+    """run_deleda on a one-device node mesh records the one-device run's
+    in-loop LP trajectory; eval_every still needs an eval_spec and a
+    horizon it divides."""
+    from jax.sharding import Mesh
+    cfg_lda, corpus, sched, degs, spec = inloop_setup
+    mesh = Mesh(np.asarray(jax.devices()[:1]), ("nodes",))
+    one, many = (
+        deleda.run_deleda(
+            deleda.DeledaConfig(lda=cfg_lda, mode="sync", batch_size=2,
+                                eval_every=10, **kw),
+            jax.random.key(1), corpus.words, corpus.mask, sched, degs, 20,
+            record_every=10, eval_spec=spec)
+        for kw in ({}, dict(comm_backend="mesh", mesh=mesh)))
+    assert many.eval_lp.shape == (2, 2)
+    # as close as the trajectories themselves (tests/test_mesh_train.py)
+    np.testing.assert_allclose(np.asarray(many.eval_lp),
+                               np.asarray(one.eval_lp), rtol=1e-6)
+    cfg = deleda.DeledaConfig(lda=cfg_lda, mode="sync", batch_size=2,
+                              eval_every=10, comm_backend="mesh", mesh=mesh)
     with pytest.raises(ValueError, match="needs an eval_spec"):
-        run_mesh_deleda(cfg_lda, words, mask, g, 4, 2, seed=0,
-                        eval_every=2)
+        deleda.run_deleda(cfg, jax.random.key(1), corpus.words, corpus.mask,
+                          sched, degs, 20, record_every=10)
     with pytest.raises(ValueError, match="divisible by"):
-        run_mesh_deleda(cfg_lda, words, mask, g, 5, 2, seed=0,
-                        eval_every=2, eval_spec=spec)
+        deleda.run_deleda(cfg, jax.random.key(1), corpus.words, corpus.mask,
+                          sched[:15], degs, 15, record_every=5,
+                          eval_spec=spec)
 
 
 def test_eval_every_validation(inloop_setup):

@@ -218,21 +218,30 @@ def test_roundtrip_bitwise_across_comm_backends(corpus, inputs, tmp_path,
         np.asarray(jax.random.key_data(tr.state.key)))
 
 
-def test_mesh_kill_restore_bitwise(corpus):
-    """The mesh launcher's (stats, steps, t) carry resumes bitwise too:
-    its per-step keys were already absolute-indexed."""
-    from repro.launch.gossip_sim import run_mesh_deleda
-    import tempfile
-    g = complete_graph(8)
-    words, mask = corpus.words[:8], corpus.mask[:8]
-    full, _, _ = run_mesh_deleda(CFG, words, mask, g, 10, 2, seed=0)
-    with tempfile.TemporaryDirectory() as d:
-        run_mesh_deleda(CFG, words, mask, g, 10, 2, seed=0,
-                        save_every=5, checkpoint_dir=d)
-        shutil.rmtree(os.path.join(d, "step_00000010"))
-        resumed, _, _ = run_mesh_deleda(CFG, words, mask, g, 10, 2, seed=0,
-                                        restore_from=d)
-    np.testing.assert_array_equal(np.asarray(full), np.asarray(resumed))
+def test_mesh_kill_restore_bitwise(corpus, inputs, tmp_path):
+    """A node mesh run saves its sharded TrainState, restores it into
+    init_state's shardings and resumes bitwise."""
+    from jax.sharding import Mesh
+    sched, degs = inputs
+    mesh = Mesh(np.asarray(jax.devices()[:1]), ("nodes",))
+    cfg = _cfg(mode="sync", comm_backend="mesh", mesh=mesh)
+    full = deleda.run_deleda(cfg, jax.random.key(5), corpus.words,
+                             corpus.mask, sched, degs, T, record_every=REC)
+    d = str(tmp_path / "mesh")
+    deleda.run_deleda(cfg, jax.random.key(5), corpus.words, corpus.mask,
+                      sched, degs, T, record_every=REC, save_every=T // 2,
+                      checkpoint_dir=d)
+    shutil.rmtree(os.path.join(d, f"step_{T:08d}"))
+    like = deleda.init_state(cfg, jax.random.key(5), N)
+    restored = deleda.restore_state(d, like, config=cfg)
+    assert restored.stats.sharding == like.stats.sharding
+    resumed = deleda.run_deleda(cfg, jax.random.key(5), corpus.words,
+                                corpus.mask, sched, degs, T,
+                                record_every=REC, restore_from=d)
+    _assert_trace_equal(full, resumed, tail_only=True)
+    for f in ("stats", "steps", "t", "stats_version", "member", "cursor"):
+        np.testing.assert_array_equal(np.asarray(getattr(resumed.state, f)),
+                                      np.asarray(getattr(full.state, f)))
 
 
 def test_legacy_uint32_key_flavor_roundtrips(corpus, inputs, tmp_path):

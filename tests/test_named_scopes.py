@@ -3,16 +3,18 @@
 The chip benchmark reads per-layer device times through the ``op_name``
 metadata of the compiled HLO (``bench/scopes.py``), so every scope has to
 survive lowering and XLA's passes: on both corpus layouts and both gossip
-kinds of ``train_steps``, and in the mesh path's shard-mapped programs.
+kinds of ``train_steps``, and under its node mesh's ``shard_map``.
 """
 
 import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
+from jax.sharding import Mesh
 
-from repro.core import comm, deleda
+from repro.core import deleda
 from repro.core.lda import LDAConfig
 
 CFG = LDAConfig(n_topics=4, vocab_size=32, alpha=0.5, doc_len_max=8,
@@ -58,25 +60,18 @@ def test_train_steps_scopes_reach_compiled_hlo(layout, kind):
 
 
 def test_mesh_update_and_mix_scopes_reach_compiled_hlo():
-    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
-
-    from repro.launch.gossip_sim import build_update_step
-    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,),
-                         devices=jax.devices()[:1])
-    node = NamedSharding(mesh, P("data"))
-    n = 3
-
-    def spec(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=node)
-
-    stats = spec((n, CFG.n_topics, CFG.vocab_size), jnp.float32)
-    step = build_update_step(CFG, 2, mesh)
-    text = step.lower(stats, spec((n,), jnp.int32), jax.random.key(0),
-                      spec((n, 4, 8), jnp.int32), spec((n, 4, 8), jnp.bool_),
-                      spec((n,), jnp.bool_)).compile().as_text()
-    assert_nested(scope_paths(text), ("deleda.estep", "deleda.blend") + ESTEP)
-
-    mix = comm.MeshComm(mesh=mesh)._get_local_fn(3)
-    text = mix.lower(stats, spec((n,), jnp.int32),
-                     spec((n,), jnp.bool_)).compile().as_text()
-    assert any("deleda.mix" in p for p in scope_paths(text))
+    """The node-sharded ``train_steps`` round keeps every scope under
+    ``shard_map`` (a one-device mesh: its mix is the in-block pass)."""
+    n, seg = 4, 4
+    mesh = Mesh(np.asarray(jax.devices()[:1]), ("nodes",))
+    cfg = deleda.DeledaConfig(lda=CFG, mode="sync", batch_size=2,
+                              comm_backend="mesh", mesh=mesh)
+    state = deleda.init_state(cfg, jax.random.key(0), n)
+    words = jax.random.randint(jax.random.key(1), (n, 5, CFG.doc_len_max),
+                               0, CFG.vocab_size)
+    sched = jnp.tile(jnp.asarray([1, 0, 3, 2], jnp.int32), (seg, 1))
+    text = deleda.train_steps.lower(
+        cfg, state, words, words >= 0, sched,
+        jnp.ones((seg, n), jnp.float32), jnp.ones((seg, n), bool),
+        record_every=seg).compile().as_text()
+    assert_nested(scope_paths(text), ROUND + ESTEP)

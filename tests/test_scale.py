@@ -5,8 +5,8 @@ NOTHING about the trajectory — gossip is row-linear so per-shard mixing
 composes to the dense averaging map, and the blocked-stats E-step gathers
 the identical beta columns the dense path would materialize. Sharded runs
 are asserted (near-bit) equal to the dense oracle across comm x estep
-backend combos, and the node x vocab mesh grid is asserted against the
-1-D mesh in a forced-multi-device subprocess.
+backend combos, and ``train_steps`` on a node x vocab mesh grid is
+asserted against the 1-D mesh in a forced-multi-device subprocess.
 
 Also here: regression tests for the PR's bugfix batch — legacy
 `jax.random.PRNGKey` through `run_deleda` / `left_to_right_log_likelihood`,
@@ -83,7 +83,7 @@ def test_estep_batch_from_stats_matches_materialized_beta():
 # Vocab-sharded mixing across comm backends
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("backend", ["dense", "pallas", "mesh"])
+@pytest.mark.parametrize("backend", ["dense", "pallas"])
 def test_sharded_mixing_matches_dense_per_shard(backend):
     """[n, K, S, V/S] mixing == dense [n, K, V] mixing, per shard."""
     n, k, v, s = 8, 3, 32, 4
@@ -104,7 +104,7 @@ def test_sharded_mixing_matches_dense_per_shard(backend):
 def test_sharded_mix_edge_matches_dense():
     n, k, v, s = 6, 3, 24, 3
     stats = jax.random.normal(jax.random.key(7), (n, k, v))
-    for backend in ["dense", "pallas", "mesh"]:
+    for backend in ["dense", "pallas"]:
         cx = comm.get_communicator(backend)
         dense_out = np.asarray(cx.mix_edge(stats, 1, 4))
         sharded = cx.mix_edge(stats.reshape(n, k, s, v // s), 1, 4)
@@ -120,9 +120,6 @@ def test_sharded_bytes_per_round_accounting():
     sharded = comm.DenseSimComm().bytes_per_round((n, k, 4, v // 4),
                                                   itemsize, p)
     assert sharded == dense            # same wire total, spread over shards
-    mesh = comm.MeshComm()
-    assert (mesh.bytes_per_round((n, k, 4, v // 4), itemsize, p)
-            == mesh.bytes_per_round((n, k, v), itemsize, p))
 
 
 # ---------------------------------------------------------------------------
@@ -318,56 +315,41 @@ def test_estep_call_padded_batch_matches_unpadded(doc_len=12):
 GRID_SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    import numpy as np, jax, jax.numpy as jnp
-    from repro.core import comm
+    import numpy as np, jax
+    from jax.sharding import Mesh
+    from repro.core import deleda
     from repro.core.graph import complete_graph
     from repro.core.lda import LDAConfig
     from repro.data.lda_synthetic import CorpusSpec, make_corpus
-    from repro.launch.gossip_sim import run_mesh_deleda
 
-    # -- vocab-sharded MeshComm mixing on a real 2-D grid == dense oracle
-    n, k, v = 8, 3, 32
-    mesh = comm.make_grid_mesh(4, 2)
-    mc = comm.MeshComm(mesh=mesh, axis_name="data", vocab_axis="vocab")
-    assert mc.n_devices == 4 and mc.n_vocab_shards == 2
-    sched = comm.GossipSchedule.draw_matchings(
-        complete_graph(n), 5, np.random.default_rng(1))
-    stats = jax.random.uniform(jax.random.key(0), (n, k, v))
-    s_d, s_m = stats, stats
-    dense = comm.DenseSimComm()
-    for t in range(5):
-        s_d = dense.mix_matching(s_d, sched.data[t])
-        s_m = mc.mix_matching(s_m, sched.data[t])
-    err = float(jnp.abs(s_d - jnp.asarray(np.asarray(s_m))).max())
-    assert err < 1e-6, err
-    # sharded [n, K, S, V/S] layout through the same grid
-    s_m4 = stats.reshape(n, k, 4, v // 4)
-    for t in range(5):
-        s_m4 = mc.mix_matching(s_m4, sched.data[t])
-    err = np.abs(np.asarray(s_m4).reshape(n, k, v) - np.asarray(s_d)).max()
-    assert err < 1e-6, err
-    # per-shard payload accounting: total unchanged, per-link 1/S
-    b_grid = mc.bytes_per_round((n, k, v), 4, sched.data[0])
-    b_flat = comm.MeshComm(mesh=comm.make_grid_mesh(4, 1),
-                           axis_name="data").bytes_per_round(
-        (n, k, v), 4, sched.data[0])
-    assert b_grid == b_flat, (b_grid, b_flat)
-
-    # -- run_mesh_deleda on the node x vocab grid == 1-D node mesh
+    # -- run_deleda on the node x vocab grid == 1-D node mesh
     lda = LDAConfig(n_topics=3, vocab_size=24, alpha=0.5, doc_len_max=8,
                     n_gibbs=4, n_gibbs_burnin=2)
     corpus = make_corpus(lda, jax.random.key(0),
                          CorpusSpec(n_nodes=8, docs_per_node=4, n_test=4))
-    g = complete_graph(8)
-    s_flat, c_flat, _ = run_mesh_deleda(
-        lda, corpus.words, corpus.mask, g, 6, 2, seed=0,
-        mesh=comm.make_grid_mesh(4, 1))
-    s_grid, c_grid, _ = run_mesh_deleda(
-        lda, corpus.words, corpus.mask, g, 6, 2, seed=0,
-        mesh_shape=(4, 2))
-    err = np.abs(np.asarray(s_flat) - np.asarray(s_grid)).max()
+    sched, degs = deleda.make_run_inputs(complete_graph(8), 6, seed=0,
+                                         kind="matching")
+    devices = np.asarray(jax.devices())
+    runs = {}
+    for name, mesh in (("flat", Mesh(devices[:4], ("data",))),
+                       ("grid", Mesh(devices.reshape(4, 2),
+                                     ("data", "vocab")))):
+        cfg = deleda.DeledaConfig(lda=lda, mode="sync", batch_size=2,
+                                  comm_backend="mesh", mesh=mesh)
+        runs[name] = deleda.run_deleda(cfg, jax.random.key(0), corpus.words,
+                                       corpus.mask, sched, degs, 6,
+                                       record_every=2)
+    grid = runs["grid"]
+    # each device holds its own [2, K, 12] block of nodes and vocabulary
+    assert grid.state.stats.sharding.spec == ("data", None, "vocab"), \
+        grid.state.stats.sharding.spec
+    assert {s.data.shape for s in grid.state.stats.addressable_shards} \
+        == {(2, 3, 12)}
+    err = np.abs(np.asarray(runs["flat"].stats)
+                 - np.asarray(grid.stats)).max()
     assert err < 1e-5, err
-    np.testing.assert_allclose(c_flat, c_grid, rtol=1e-4)
+    np.testing.assert_allclose(runs["flat"].consensus, grid.consensus,
+                               rtol=1e-4)
     print("SCALE_GRID_OK")
 """)
 

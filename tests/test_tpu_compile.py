@@ -30,7 +30,6 @@ from repro.kernels.lda_gibbs import ops as gibbs_ops
 from repro.kernels.lda_gibbs.lda_gibbs import gibbs_sweeps_pallas
 from repro.kernels.lda_l2r.lda_l2r import l2r_scores_pallas
 from repro.kernels.lda_sparse.lda_sparse import sparse_sweeps_pallas
-from repro.launch.gossip_sim import build_update_step
 
 N_NODES, V, K, B, L, S, BURNIN = 1024, 50_000, 4, 2, 16, 4, 2
 DOCS = N_NODES * B          # one fused E-step over every node's minibatch
@@ -67,7 +66,11 @@ def _spec(sharding, shape, dtype=jnp.float32):
 
 def _assert_kernel(fn, kernel: str, *args):
     """``fn`` compiles for the chip, with ``kernel`` as a Mosaic call."""
-    text = fn.lower(*args).compile().as_text()
+    _assert_lowered_kernel(fn.lower(*args), kernel)
+
+
+def _assert_lowered_kernel(lowered, kernel: str):
+    text = lowered.compile().as_text()
     assert any("tpu_custom_call" in ln and kernel in ln
                for ln in text.splitlines()), f"{kernel} is not compiled"
 
@@ -114,28 +117,16 @@ def test_gossip_mix_compiles(one_chip, v):
 
 @pytest.mark.parametrize("grid", [False, True], ids=["4x1", "2x2"])
 def test_mesh_update_step_compiles(topo, one_chip, monkeypatch, grid):
-    """The mesh launcher's local update, lda_gibbs inside shard_map, for
-    four chips as a 1-D node mesh and as the node x vocab grid."""
+    """The node-sharded ``train_steps`` with lda_gibbs inside shard_map,
+    for four chips as a 1-D node mesh and as the node x vocab grid."""
     # the E-step backend asks the platform, which is the CPU here
     monkeypatch.setattr(gibbs_ops, "resolve_interpret", lambda _: False)
     devices = np.asarray(topo.devices)
-    vocab_axis = "vocab" if grid else None
     mesh = (Mesh(devices.reshape(2, 2), ("data", "vocab")) if grid
             else Mesh(devices, ("data",)))
-    lda = LDAConfig(n_topics=K, vocab_size=V, alpha=0.5, doc_len_max=L,
-                    n_gibbs=S, n_gibbs_burnin=BURNIN)
-    step = build_update_step(lda, B, mesh, vocab_axis=vocab_axis,
-                             estep_backend="pallas")
-    node = NamedSharding(mesh, P("data"))
-    _assert_kernel(
-        step, "lda_gibbs",
-        _spec(NamedSharding(mesh, P("data", None, vocab_axis)),
-              (N_NODES, K, V)),
-        _spec(node, (N_NODES,), jnp.int32),
-        _spec(NamedSharding(mesh, P()), (), jax.random.key(0).dtype),
-        _spec(node, (N_NODES, 8, L), jnp.int32),
-        _spec(node, (N_NODES, 8, L), jnp.bool_),
-        _spec(node, (N_NODES,), jnp.bool_))
+    _assert_lowered_kernel(
+        _mesh_train_steps(mesh, N_NODES, estep_backend="pallas"),
+        "lda_gibbs")
 
 
 # an f32 row gather under the gossip mix's scope: the node-axis gather
@@ -197,28 +188,36 @@ def test_train_steps_mix_is_one_highest_contraction(one_chip):
     _assert_no_reduce_with_contraction(text)
 
 
-def _mesh_train_steps_text(topo, n_nodes: int) -> str:
-    """The node-sharded ``train_steps`` segment for four chips, compiled."""
-    mesh = Mesh(np.asarray(topo.devices), ("data",))
+def _mesh_train_steps(mesh, n_nodes: int, **cfg_kw):
+    """The ``train_steps`` segment on ``mesh`` (its first axis nodes, a
+    second one V blocks), lowered."""
     lda = LDAConfig(n_topics=K, vocab_size=V, alpha=0.5, doc_len_max=L,
                     n_gibbs=S, n_gibbs_burnin=BURNIN)
     cfg = deleda.DeledaConfig(lda=lda, mode="sync", batch_size=B,
-                              comm_backend="mesh", mesh=mesh)
-    node, rep = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+                              comm_backend="mesh", mesh=mesh, **cfg_kw)
+    axis, *vocab = mesh.axis_names
+    node, rep = NamedSharding(mesh, P(axis)), NamedSharding(mesh, P())
+    block = NamedSharding(mesh, P(axis, None, *vocab)) if vocab else node
     seg = 4
     state = deleda.TrainState(
-        stats=_spec(node, (n_nodes, K, V)),
+        stats=_spec(block, (n_nodes, K, V)),
         steps=_spec(node, (n_nodes,), jnp.int32),
         key=_spec(rep, (), jax.random.key(0).dtype),
         t=_spec(rep, (), jnp.int32), stats_version=_spec(rep, (), jnp.int32),
         member=_spec(node, (n_nodes,), jnp.bool_),
         cursor=_spec(rep, (), jnp.int32))
-    text = deleda.train_steps.lower(
+    return deleda.train_steps.lower(
         cfg, state, _spec(node, (n_nodes, 8, L), jnp.int32),
         _spec(node, (n_nodes, 8, L), jnp.bool_),
         _spec(rep, (seg, n_nodes), jnp.int32),
         _spec(rep, (seg, n_nodes)), _spec(rep, (seg, n_nodes), jnp.bool_),
-        record_every=seg).compile().as_text()
+        record_every=seg)
+
+
+def _mesh_train_steps_text(topo, n_nodes: int) -> str:
+    """The node-sharded ``train_steps`` segment for four chips, compiled."""
+    text = _mesh_train_steps(Mesh(np.asarray(topo.devices), ("data",)),
+                             n_nodes).compile().as_text()
     assert text.count("collective-permute-start(") == 3
     assert text.count(" all-reduce(") == 2
     assert "all-gather" not in text and "all-to-all" not in text
